@@ -43,7 +43,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from deeplearning4j_tpu.analysis.findings import ERROR, Finding
 

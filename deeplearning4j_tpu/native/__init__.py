@@ -4,13 +4,18 @@ The compute path is JAX/XLA/Pallas; these are the host-side runtime
 pieces the reference also kept native (SURVEY §2.11) — currently the
 corpus pipeline (corpus.cpp: tokenize + vocab count + index, the
 VocabConstructor/text-pipeline hot loop). The shared library is built
-from source on first use with g++ and cached next to this file; when no
-toolchain exists the callers fall back to their pure-Python paths.
+from corpus.cpp on first use with g++ and kept next to this file under a
+name that carries the source's hash, so a binary is only ever loaded if
+it was built from the source beside it (a checkout has none: `*.so` is
+git-ignored). With no toolchain the callers fall back to their
+pure-Python paths, with a warning; a source that does not compile is a
+bug and raises.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -22,7 +27,6 @@ import numpy as np
 logger = logging.getLogger("deeplearning4j_tpu")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_HERE, "libdl4jcorpus.so")
 _SRC = os.path.join(_HERE, "corpus.cpp")
 _lock = threading.Lock()
 _lib = None
@@ -36,19 +40,28 @@ def _load() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        if not os.path.exists(_SO) or (
-                os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+        with open(_SRC, "rb") as f:
+            tag = hashlib.sha256(f.read()).hexdigest()[:12]
+        so = os.path.join(_HERE, f"libdl4jcorpus-{tag}.so")
+        if not os.path.exists(so):
+            # built under a private name and renamed into place: several
+            # processes (test workers) may get here at once
+            tmp = f"{so}.{os.getpid()}.tmp"
             try:
                 subprocess.run(
                     ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                     _SRC, "-o", _SO],
+                     _SRC, "-o", tmp],
                     check=True, capture_output=True, text=True, timeout=120)
-            except (OSError, subprocess.SubprocessError) as e:
-                logger.warning("native corpus build failed (%s); "
+                os.replace(tmp, so)
+            except subprocess.CalledProcessError as e:
+                raise RuntimeError(
+                    f"native/corpus.cpp does not compile:\n{e.stderr}") from e
+            except (OSError, subprocess.TimeoutExpired) as e:
+                logger.warning("no native corpus library (%s); "
                                "falling back to Python paths", e)
                 _build_failed = True
                 return None
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
         lib.corpus_open.restype = ctypes.c_void_p
         lib.corpus_open.argtypes = [ctypes.c_char_p, ctypes.c_int]
         lib.corpus_close.argtypes = [ctypes.c_void_p]

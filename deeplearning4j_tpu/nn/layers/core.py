@@ -16,12 +16,7 @@ from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.layers.registry import LayerContext, register_layer
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.activations import apply_activation
-
-try:  # deferred-ReLU hook of the Pallas conv/BN fusion; pallas may be
-    # unavailable on this backend — resolve ONCE, not per forward call
-    from deeplearning4j_tpu.ops.pallas_conv_bn import take_fused_relu
-except Exception:  # pragma: no cover - pallas unavailable
-    take_fused_relu = None
+from deeplearning4j_tpu.ops.pallas_conv_bn import take_fused_relu
 
 
 def apply_dropout(x, retain_prob, ctx: LayerContext):
@@ -93,7 +88,7 @@ def _no_params(key, conf, dtype):
 
 
 def activation_forward(conf, params, x, ctx: LayerContext):
-    if conf.activation == "relu" and take_fused_relu is not None:
+    if conf.activation == "relu":
         # deferred-ReLU hook of the Pallas conv/BN epilogue fusion: when x
         # is a stashed fused-BN output, swap in the normalize+ReLU variant
         # of that kernel (the plain-normalize call is then dead code and

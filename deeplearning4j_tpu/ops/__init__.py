@@ -15,12 +15,6 @@ from deeplearning4j_tpu.ops.helpers import (
     set_helper_enabled,
 )
 
-try:  # vendor kernels register themselves; absence must never break ops/
-    from deeplearning4j_tpu.ops import pallas_lstm  # noqa: F401
-except Exception:  # pragma: no cover - pallas unavailable on this backend
-    pass
-
-try:
-    from deeplearning4j_tpu.ops import pallas_conv_bn  # noqa: F401
-except Exception:  # pragma: no cover - pallas unavailable on this backend
-    pass
+# vendor kernels register themselves on import; with one installation a
+# kernel module that cannot be imported is a bug, so this raises
+from deeplearning4j_tpu.ops import pallas_conv_bn, pallas_lstm  # noqa: F401,E402

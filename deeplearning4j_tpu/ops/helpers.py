@@ -12,9 +12,11 @@ like the reference's cuDNN fallback.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import logging
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Iterator, List, Optional, Sequence
 
 from deeplearning4j_tpu.utils import faultpoints as _faults
 from deeplearning4j_tpu.utils import metrics as _metrics
@@ -73,6 +75,27 @@ class Helper:
 
 _HELPERS: Dict[str, Helper] = {}
 
+# Device count of the program being traced. Set by the one place that
+# builds a multi-device step program (parallel/sharded.MeshPlan.jit_step);
+# anything traced outside it is a one-device program.
+_PROGRAM_DEVICES: contextvars.ContextVar = contextvars.ContextVar(
+    "dl4j_program_devices", default=1)
+
+
+@contextlib.contextmanager
+def partitioned_program(n_devices: int) -> Iterator[None]:
+    """Mark the trace inside as a program GSPMD partitions over
+    `n_devices`. A helper's kernel is an opaque custom call that the
+    partitioner cannot split: left inside, every device would gather the
+    whole batch and run the kernel on all of it. So under n_devices > 1
+    `get_helper` declines (reason "partitioned_program") and the layer's
+    XLA lowering, which the partitioner does split, runs instead."""
+    token = _PROGRAM_DEVICES.set(int(n_devices))
+    try:
+        yield
+    finally:
+        _PROGRAM_DEVICES.reset(token)
+
 
 def register_helper(op: str, fn: Callable,
                     supported: Optional[Callable[..., bool]] = None,
@@ -115,6 +138,10 @@ def get_helper(op: str, **ctx) -> Optional[Callable]:
     fam = _family_of(op, h, ctx)
     if not h.enabled:
         _count("helper_fallback_total", op, h.name, fam, "disabled")
+        return None
+    if _PROGRAM_DEVICES.get() > 1:
+        _count("helper_fallback_total", op, h.name, fam,
+               "partitioned_program")
         return None
     try:
         if not h.supported(**ctx):
@@ -162,5 +189,88 @@ def helper_enabled(op: str) -> Optional[bool]:
     return None if h is None else h.enabled
 
 
+def interpret_mode(flag: bool) -> bool:
+    """A kernel module's interpret flag, as its call sites and probes may
+    use it. Interpret mode runs a kernel through the Pallas interpreter
+    for CPU tests; on a TPU it would silently measure the interpreter, so
+    there it is an error and not a mode."""
+    if flag:
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                "Pallas interpret mode is on with a TPU backend: it is "
+                "for CPU tests only (unset DL4J_PALLAS_INTERPRET, leave "
+                "the kernel modules' _INTERPRET flags False)")
+    return bool(flag)
+
+
 def helper_names() -> Dict[str, str]:
     return {op: h.name for op, h in _HELPERS.items()}
+
+
+def count_fallback_raised(op: str, family: str) -> None:
+    """Book a fallback taken OUTSIDE `get_helper`'s guard (a kernel
+    shortcut that caught its own exception) under the same
+    `helper_fallback_total{reason="raised"}` series, so that
+    `hidden_fallbacks` sees it."""
+    h = _HELPERS.get(op)
+    _count("helper_fallback_total", op, h.name if h else op, family,
+           "raised")
+
+
+def helper_books(since: Optional[dict] = None) -> dict:
+    """The helper counters of this process, read back from the shared
+    registry and summed by kernel family: {"hits": {family: n},
+    "auto_disable": {family: n}, "fallbacks": {reason: {family: n}}}.
+    With `since` (an earlier `helper_books()`), what moved since then;
+    families and reasons that did not move are left out."""
+    reg = _metrics.get_registry()
+    books: dict = {"hits": {}, "auto_disable": {}, "fallbacks": {}}
+
+    def add(into: dict, family: str, value: float) -> None:
+        into[family] = into.get(family, 0) + int(value)
+
+    for name, key in (("helper_hit_total", "hits"),
+                      ("helper_auto_disable_total", "auto_disable")):
+        fam = reg.get(name)
+        for labels, child in (fam.children() if fam else ()):
+            add(books[key], labels[2], child.value)
+    fam = reg.get("helper_fallback_total")
+    for labels, child in (fam.children() if fam else ()):
+        add(books["fallbacks"].setdefault(labels[3], {}), labels[2],
+            child.value)
+    if since is None:
+        return books
+
+    def minus(new: dict, old: dict) -> dict:
+        return {k: v - old.get(k, 0) for k, v in sorted(new.items())
+                if v > old.get(k, 0)}
+
+    moved = {reason: minus(fams, since["fallbacks"].get(reason, {}))
+             for reason, fams in sorted(books["fallbacks"].items())}
+    return {"hits": minus(books["hits"], since["hits"]),
+            "auto_disable": minus(books["auto_disable"],
+                                  since["auto_disable"]),
+            "fallbacks": {r: d for r, d in moved.items() if d}}
+
+
+def hidden_fallbacks(since: dict, expect_enabled: Sequence[str] = ()
+                     ) -> List[str]:
+    """What a measured path may not hide: the auto-disables, raised
+    helper fns and raised probes booked since the `helper_books()`
+    snapshot `since`, and every op of `expect_enabled` whose helper is
+    not enabled now. The fallback mechanism stays (a broken kernel must
+    not kill a user's fit); a benchmark or smoke phase that measured
+    through it has measured the built-in path and must fail. Returns
+    the problems, empty when there are none."""
+    moved = helper_books(since)
+    problems = [f"helper auto-disabled {family}: {n}"
+                for family, n in moved["auto_disable"].items()]
+    for reason in ("raised", "probe_error"):
+        problems += [f"helper fallback ({reason}) {family}: {n}"
+                     for family, n in
+                     moved["fallbacks"].get(reason, {}).items()]
+    problems += [f"helper for {op} not enabled ({helper_enabled(op)})"
+                 for op in expect_enabled if helper_enabled(op) is not True]
+    return problems

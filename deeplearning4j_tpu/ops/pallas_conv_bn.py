@@ -1,9 +1,10 @@
 """Pallas conv + BN-statistics epilogue fusion — the CudnnConvolutionHelper/
 CudnnBatchNormalizationHelper pair for the ResNet trunk.
 
-Why: PROFILE_resnet50.md shows the train step is bandwidth-bound, with
-16.4 ms of a 48.8 ms step spent on batch-norm statistics/normalization
-traffic over the residual trunk (`convert_reduce_fusion` = 25.8 ms/step).
+Why: the round-9 profile (older than the code, not re-measured) showed
+the train step bandwidth-bound, with 16.4 ms of a 48.8 ms step spent on
+batch-norm statistics/normalization traffic over the residual trunk
+(`convert_reduce_fusion` = 25.8 ms/step).
 XLA materializes each conv output to HBM, then re-reads the full tensor
 for the per-channel statistics reduction, then re-reads it AGAIN for the
 normalize. This module closes one of those reads: the conv kernel computes
@@ -26,12 +27,16 @@ Two helper slots (ops/helpers.py), mirroring the reference's plugin pair
   ActivationLayer, it swaps in the normalize+ReLU variant of the kernel
   and the plain-normalize pallas_call is dead-code-eliminated by XLA.
 
-Scope (checked by the probes; everything else falls back silently to the
-XLA lowering, exactly like the cuDNN checkSupported fallback): NHWC,
-bf16 on real TPU, training mode, bias-free identity-activation convs with
-SAME padding, no dilation, and kernel/stride in {1x1 (stride 1 or 2),
-3x3 (stride 1 or 2), 7x7 (stride 2)} — every conv instance of the
-ResNet-50 trunk, stem included (53/53). Structural support is necessary
+Scope (checked by the probes; everything else takes the XLA lowering,
+like the cuDNN checkSupported fallback): NHWC, bf16 on real TPU,
+training mode, bias-free identity-activation convs with SAME padding, no
+dilation, and kernel/stride in {1x1 (stride 1 or 2), 3x3 (stride 1 or
+2), 7x7 (stride 2)}. On the TPU the kxk kernel is narrower than in
+interpret mode, because the chip's compiler refuses part of it
+(`_ck_chip_refusal`): strided taps (3x3/s2, the 7x7/s2 stem), inputs
+that do not fill the 128 lanes, and images too large to compile. Those
+shapes are "unsupported" by shape; tests/test_tpu_compile.py compiles
+what is left for the described chip. Structural support is necessary
 but not sufficient: `conv_decision` then consults the per-instance
 roofline (`analysis/costmodel.instance_roofline`) and DECLINES
 compute-bound instances — an MXU-saturating conv gains nothing from the
@@ -69,21 +74,31 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.ops.helpers import (
+    count_fallback_raised,
+    interpret_mode,
+)
+
 logger = logging.getLogger("deeplearning4j_tpu")
 
-# Interpret mode runs the kernels as a jaxpr interpreter on any backend —
-# the CPU-correctness/bench configuration (same pattern as pallas_lstm).
-# Tests flip the module flag directly; bench flips it via set_interpret;
-# DL4J_PALLAS_INTERPRET=1 forces it from the environment.
+# Interpret mode runs the kernels as a jaxpr interpreter on the CPU
+# backend — the CPU-correctness/bench configuration (same pattern as
+# pallas_lstm). Tests flip the module flag directly; bench flips it via
+# set_interpret; DL4J_PALLAS_INTERPRET=1 forces it from the environment.
+# Every reader goes through `_interpret()`, which refuses it on a TPU.
 _INTERPRET = os.environ.get("DL4J_PALLAS_INTERPRET", "0") == "1"
 
 
 def set_interpret(on: bool) -> None:
-    """Run the Pallas kernels in interpret mode (any backend). Used by
+    """Run the Pallas kernels in interpret mode (CPU backend). Used by
     bench.py for the CPU-interpret helper A/B; tests set the module flag
     directly through their fixture."""
     global _INTERPRET
     _INTERPRET = bool(on)
+
+
+def _interpret() -> bool:
+    return interpret_mode(_INTERPRET)
 
 _DIMS2D = ("NHWC", "HWIO", "NHWC")
 
@@ -142,6 +157,7 @@ def take_fused_relu(x):
     except Exception as e:  # never let the fusion shortcut kill a layer
         logger.warning("fused BN+ReLU thunk failed (%s); applying "
                        "plain ReLU instead", e)
+        count_fallback_raised("batch_norm", "bn_apply")
         return None
 
 
@@ -155,6 +171,15 @@ def _row_tile(m: int, cap: int = 512) -> int:
     while t > 1 and m % t:
         t //= 2
     return t
+
+
+def _dot_precision(dtype):
+    """Precision of an in-kernel dot. bf16 operands take one MXU pass
+    whatever the process-wide `jax_default_matmul_precision` asks, and
+    the chip's compiler refuses "highest" on them ("Bad lhs type"), so
+    they are pinned to DEFAULT; f32/f64 operands (interpret-mode parity
+    tests) keep the configured precision."""
+    return lax.Precision.DEFAULT if dtype == jnp.bfloat16 else None
 
 
 def _acc_dtype(dtype):
@@ -174,7 +199,8 @@ def _mm_stats_kernel(x_ref, w_ref, y_ref, s1_ref, s2_ref):
         s2_ref[:] = jnp.zeros_like(s2_ref)
 
     acc_dt = s1_ref.dtype
-    y = jnp.dot(x_ref[:], w_ref[:], preferred_element_type=acc_dt)
+    y = jnp.dot(x_ref[:], w_ref[:], preferred_element_type=acc_dt,
+                precision=_dot_precision(x_ref.dtype))
     yb = y.astype(y_ref.dtype)
     y_ref[:] = yb
     # Epilogue over the tile while it is still in VMEM. Statistics are of
@@ -213,7 +239,7 @@ def _mm_stats_call(x2, w2):
             jax.ShapeDtypeStruct((1, cout), acc),
             jax.ShapeDtypeStruct((1, cout), acc),
         ],
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )(x2, w2)
     return y2, s1, s2
 
@@ -284,6 +310,7 @@ def _ck_stats_kernel(x_ref, w_ref, y_ref, s1_ref, s2_ref, *, taps, sh, sw):
             xs, w_ref[a, b],
             (((2,), (0,)), ((), ())),
             preferred_element_type=acc_dt,
+            precision=_dot_precision(x_ref.dtype),
         )
         # zero-extend the clipped partial back to (ho, wo) and add —
         # in-register pad; .at[...].add would capture index constants
@@ -326,7 +353,7 @@ def _ck_stats_call(x, w, strides):
             jax.ShapeDtypeStruct((1, cout), acc),
             jax.ShapeDtypeStruct((1, cout), acc),
         ],
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )(x, w)
     return y, s1, s2
 
@@ -432,7 +459,7 @@ def _norm_call(x2, mean_b, scale, shift, relu):
         out_specs=pl.BlockSpec((tm, c), lambda t: (t, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, c), x2.dtype),
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )(x2, mean_b, scale, shift)
 
 
@@ -495,7 +522,7 @@ def _bnb_reduce_call(g2, x2):
             jax.ShapeDtypeStruct((1, c), acc),
             jax.ShapeDtypeStruct((1, c), acc),
         ],
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )(g2, x2)
 
 
@@ -515,7 +542,7 @@ def _bnb_apply_call(g2, x2, c1, c3, c0):
         out_specs=pl.BlockSpec((tm, c), lambda t: (t, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, c), g2.dtype),
-        interpret=_INTERPRET,
+        interpret=_interpret(),
     )(g2, x2, c1, c3, c0)
 
 
@@ -651,7 +678,26 @@ bn_apply.defvjp(_bn_fwd, _bn_bwd)
 
 # -- Helper SPI wiring -------------------------------------------------------
 
-_VMEM_BUDGET = 12 * 1024 * 1024  # leave headroom under the ~16MB/core VMEM
+# What one kernel may take of the chip's fast memory. The v5e compiler
+# allows a kernel 16 MiB of scoped VMEM by default and refuses above it
+# ("Scoped allocation with size 19.03M and limit 16.00M", the 1x1 kernel
+# at 2048->4096 compiled by itself for the described v5e:2x2; inside a
+# larger program XLA may place the operands otherwise and let the same
+# kernel pass); the estimates of `_conv_vmem_ok` are held 4 MiB under
+# that limit.
+_VMEM_BUDGET = 12 * 1024 * 1024
+
+# The kxk kernel holds one whole image per grid step and unrolls its taps
+# over it, so the chip compiler's time and memory grow with the image:
+# 40x40x128 compiles in 14 s, 48x48x128 in 46 s, 56x56x128 was stopped
+# after 40 minutes and 28 GB. Images above this many output rows are
+# refused.
+_CK_MAX_ROWS = 40 * 40
+
+# "unsupported" reasons that are the chip compiler's verdict on a shape
+# (tests/test_tpu_compile.py holds each to that compiler), as opposed to
+# a conv the kernel family was never written for
+CHIP_REFUSALS = ("strided_taps", "lane_alignment", "image_rows", "vmem")
 
 # the structural whitelist: every ResNet-50 trunk conv is one of these
 _KERNEL_STRIDES = {
@@ -672,6 +718,13 @@ def conv_family(*, kernel=None, stride=None, **_):
 
 
 def _conv_vmem_ok(kernel, stride, x_shape, n_in, n_out, itemsize) -> bool:
+    """Estimate of the kernel's scoped VMEM against `_VMEM_BUDGET`, each
+    form checked against what the described v5e compiler reports: the 1x1
+    kernel takes the weights once plus double-buffered row tiles (19.03M
+    reported, 19.0M estimated, at 2048->4096); the kxk kernel takes
+    image, output and weights twice plus about seven image-sized f32
+    temporaries of its unrolled taps (23.26M reported, 23.8M estimated,
+    at 3x3 28x28x512->512)."""
     kh, kw = kernel
     if (kh, kw) == (1, 1):
         wgt = n_in * n_out * itemsize
@@ -685,7 +738,28 @@ def _conv_vmem_ok(kernel, stride, x_shape, n_in, n_out, itemsize) -> bool:
     out = ho * wo * n_out * itemsize
     accf = ho * wo * n_out * 4
     wgt = kh * kw * n_in * n_out * itemsize
-    return 2 * (slab + out) + accf + wgt <= _VMEM_BUDGET
+    return 2 * (slab + out + wgt) + 7 * accf <= _VMEM_BUDGET
+
+
+def _ck_chip_refusal(stride, x_shape, n_in):
+    """Why the chip's compiler refuses this kxk instance, from its shape,
+    or None. Each reason is a refusal seen when compiling for the
+    described v5e:2x2 (interpret mode has none of these limits):
+    stride > 1 needs a strided vector slice per tap ("'vector.
+    extract_strided_slice' op expected strides to be confined to
+    [1, 2)"); an input of fewer than 128 channels leaves the tap's
+    (rows, cols, cin) -> (rows*cols, cin) reshape with partly filled
+    lanes ("infer-vector-layout: unsupported shape cast"); and see
+    `_CK_MAX_ROWS`."""
+    if tuple(stride) != (1, 1):
+        return "strided_taps"
+    if n_in % 128:
+        return "lane_alignment"
+    ho = -(-x_shape[1] // stride[0])
+    wo = -(-x_shape[2] // stride[1])
+    if ho * wo > _CK_MAX_ROWS:
+        return "image_rows"
+    return None
 
 
 def conv_decision(*, kernel, stride, dilation, same, has_bias, activation,
@@ -694,10 +768,11 @@ def conv_decision(*, kernel, stride, dilation, same, has_bias, activation,
     """Routing decision for the "conv2d" slot, in two stages:
 
     1. structural: the kernel must EXIST for the shape (bias-free SAME
-       identity conv, kernel/stride in `_KERNEL_STRIDES`, channels that
-       tile the 128-lane registers, the whole image inside the VMEM
-       budget) — failures are "unsupported", the cuDNN checkSupported
-       pattern;
+       identity conv, kernel/stride in `_KERNEL_STRIDES`) and, on the
+       TPU, COMPILE for it (channels that tile the 128-lane registers,
+       no `_ck_chip_refusal`, the kernel inside the VMEM budget) —
+       failures are "unsupported", the cuDNN checkSupported pattern,
+       decided from the shape and never by catching the compiler;
     2. economic: the per-instance roofline verdict
        (analysis/costmodel.instance_roofline). The stats epilogue saves
        an HBM read — worth exactly nothing on an MXU-saturating conv, so
@@ -730,7 +805,7 @@ def conv_decision(*, kernel, stride, dilation, same, has_bias, activation,
         return uns("kernel_shape")
     if planning:
         pass  # model the TPU decision for any local backend/dtype
-    elif _INTERPRET:
+    elif _interpret():
         # CPU correctness/bench mode: any float dtype, tiny channels
         if not jnp.issubdtype(dtype, jnp.floating):
             return uns("dtype")
@@ -739,10 +814,13 @@ def conv_decision(*, kernel, stride, dilation, same, has_bias, activation,
             return uns("backend")
         if dtype != jnp.bfloat16:
             return uns("dtype")
-    if planning or not _INTERPRET:
-        # trunk channel counts tile the 128-lane registers cleanly; the
-        # 7x7 stem's 3 input channels ride the (padded) contraction dim
-        if (n_in % 64 and not (k == (7, 7) and n_in <= 4)) or n_out % 64:
+    if planning or not _interpret():
+        if k != (1, 1):
+            refusal = _ck_chip_refusal(s, x_shape, n_in)
+            if refusal is not None:
+                return uns(refusal)
+        # trunk channel counts tile the 128-lane registers cleanly
+        if n_in % 64 or n_out % 64:
             return uns("channel_alignment")
         if not _conv_vmem_ok(k, s, x_shape, n_in, n_out,
                              jnp.dtype(dtype).itemsize):
@@ -784,7 +862,7 @@ def bn_supported(*, x, training, **_):
     runs anyway so the routing stays cost-model-driven by construction."""
     if not training or not hasattr(x, "ndim") or x.ndim != 4:
         return False
-    if not _INTERPRET:
+    if not _interpret():
         if jax.default_backend() != "tpu" or x.dtype != jnp.bfloat16:
             return False
     if not peek_stats(x):
@@ -808,7 +886,7 @@ def bn_bwd_supported(*, x_shape, dtype, training, **_):
     keeps that a checked fact rather than an assumption."""
     if not training or len(x_shape) < 2:
         return False
-    if not _INTERPRET:
+    if not _interpret():
         if jax.default_backend() != "tpu" or dtype != jnp.bfloat16:
             return False
         if x_shape[-1] % 64:

@@ -24,21 +24,6 @@ DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
 
-def shard_map_fn():
-    """The shard_map entry point across jax versions: top-level
-    `jax.shard_map` where the installed jax exposes it, else
-    `jax.experimental.shard_map.shard_map` (the only home in the 0.4.x
-    line installed here — the bare `jax.shard_map` access was what kept
-    the whole sequence/pipeline parallel stack import-broken on this
-    container, 11 of the seed baseline failures)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
-
-
 def data_parallel_mesh(devices: Optional[Sequence] = None) -> Mesh:
     """1-D mesh over all (or the given) devices with a single "data" axis —
     the topology of the reference's ParallelWrapper (one replica per

@@ -130,9 +130,7 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x, *, mesh: Mesh,
                    n_stages=S)
     p_spec = jax.tree_util.tree_map(
         lambda _: PartitionSpec(axis_name), stacked_params)
-    from deeplearning4j_tpu.parallel.mesh import shard_map_fn
-
-    out = shard_map_fn()(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_spec, PartitionSpec()),
         out_specs=PartitionSpec(),

@@ -159,12 +159,8 @@ def ring_self_attention(x, wq, wk, wv, wo, *, mesh: Mesh,
                                    causal=causal)
         return o.reshape(B, Tl, E) @ wo
 
-    from deeplearning4j_tpu.parallel.mesh import shard_map_fn
-
-    shard_map = shard_map_fn()
-
     spec_x = PartitionSpec(None, axis_name, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_x,),
         out_specs=spec_x,

@@ -52,6 +52,7 @@ that need them.
 
 from __future__ import annotations
 
+import functools
 import inspect
 import os
 import time
@@ -364,7 +365,10 @@ class MeshPlan:
         uses (`netbase._step_donate_argnums`, recorded on the net for the
         JX006 audit); donated in/out layouts match because the step body
         constrains its gradient (and hence its outputs) back to the
-        parameter shardings."""
+        parameter shardings. The body is traced as a partitioned program
+        (ops/helpers.partitioned_program): a Pallas helper is an opaque
+        custom call the partitioner cannot split, so on a mesh of more
+        than one device the layers keep their XLA lowering."""
         jax = _jax()
         n_args = len(inspect.signature(step).parameters)
         data_sh = self.batch_stacked if stacked_data else self.batch
@@ -378,7 +382,18 @@ class MeshPlan:
                 in_shardings.append(data_sh)
             else:
                 in_shardings.append(self.replicated)
-        return jax.jit(step, in_shardings=tuple(in_shardings),
+        from deeplearning4j_tpu.ops.helpers import partitioned_program
+
+        n_devices = int(self.mesh.devices.size)
+
+        @functools.wraps(step)
+        def partitioned(*args):
+            # the body runs while jit traces: kernel helpers see that
+            # this program is split over the mesh and decline
+            with partitioned_program(n_devices):
+                return step(*args)
+
+        return jax.jit(partitioned, in_shardings=tuple(in_shardings),
                        donate_argnums=donate_argnums)
 
     def grad_shardings(self, net):

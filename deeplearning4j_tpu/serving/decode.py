@@ -203,7 +203,7 @@ class DecodeEngine:
         # -- device-resident state -------------------------------------------
         self._params = model.params_list         # the version the step reads
         self._states = model.state_list
-        self._carry = model.rnn_zero_carry(self.n_slots)
+        self._carry = self._zero_carry()
         self._version = 0
         self._pending_swap = None                # (version, placed params)
         self._swaps = 0
@@ -480,6 +480,23 @@ class DecodeEngine:
     @property
     def version(self) -> int:
         return self._version
+
+    def _zero_carry(self):
+        """A zero carry, committed where the params are committed. The
+        step's output carry inherits the params' placement, and jit keys
+        its cache on placement and committedness: behind an
+        InferenceServer (whose ParallelInference commits the model's
+        params to its mesh) an uncommitted first carry cost a second
+        trace of both programs."""
+        carry = self.model.rnn_zero_carry(self.n_slots)
+        leaf = next(iter(jax.tree_util.tree_leaves(self._params)), None)
+        if leaf is None or not getattr(leaf, "committed", False):
+            return carry
+        sharding = leaf.sharding
+        if isinstance(sharding, jax.sharding.NamedSharding):
+            sharding = jax.sharding.NamedSharding(
+                sharding.mesh, jax.sharding.PartitionSpec())
+        return jax.device_put(carry, sharding)
 
     def program_cache_size(self) -> int:
         """Total jit-cache entries behind the engine (step + slot-reset
@@ -960,7 +977,7 @@ class DecodeEngine:
                        slot.req.tenant)
         # the carry may hold donated/poisoned buffers after a failed
         # dispatch: rebuild it so the next admission starts clean
-        self._carry = self.model.rnn_zero_carry(self.n_slots)
+        self._carry = self._zero_carry()
         logger.warning("decode step failed (%s); %d active sequence(s) "
                        "failed, engine continues", exc, len(victims))
 

@@ -28,15 +28,9 @@ logger = logging.getLogger("deeplearning4j_tpu")
 
 
 def enable_x64():
-    """f64 context manager across jax versions: `jax.enable_x64` was
-    removed in favor of `jax.experimental.enable_x64` in the jax this
-    image ships — the check is useless without it (f64 is the whole
-    point, see module docstring)."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64()
-    from jax.experimental import enable_x64 as _e64
-
-    return _e64()
+    """The f64 context manager — the check is useless without it (f64 is
+    the whole point, see module docstring)."""
+    return jax.enable_x64()
 
 
 def numeric_gradient(f: Callable, flat: np.ndarray, epsilon: float = 1e-6,

@@ -200,10 +200,29 @@ class _helpers_disabled:
         return False
 
 
-# bf16 peak matmul throughput per chip, for MFU. v5e: 197 TFLOP/s.
+# jax's `device_kind` of each chip generation the tables below carry
+# (the names of jax/_src/mesh_utils.py and pallas/mosaic/tpu_info.py).
+# "TPU v5 lite" is the one this repo has run on; a TPU whose kind is not
+# here is an error, never a default.
+DEVICE_KINDS = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+    "TPU v6e": "v6e",
+}
+
+# Off the TPU (the CPU hosts that run the cost model, `cli perf` and the
+# kernel-coverage table) the roofline is a PLANNING model of the chip the
+# kernels target: these are the v5e rows, named as what they are. There
+# is no HBM off-TPU — a CPU host's RAM is not the ceiling JX008 is about.
+PLANNING_CHIP = "v5e"
+
+# bf16 peak matmul throughput per chip, for MFU (Google Cloud TPU docs).
 TPU_PEAK_FLOPS = {
     "v5e": 197e12,
-    "v5litepod": 197e12,
     "v4": 275e12,
     "v5p": 459e12,
     "v6e": 918e12,
@@ -212,7 +231,6 @@ TPU_PEAK_FLOPS = {
 # HBM capacity per chip — the JX008 residency ceiling.
 TPU_HBM_BYTES = {
     "v5e": 16e9,
-    "v5litepod": 16e9,
     "v4": 32e9,
     "v5p": 95e9,
     "v6e": 32e9,
@@ -221,7 +239,6 @@ TPU_HBM_BYTES = {
 # HBM bandwidth per chip — the roofline ridge denominator.
 TPU_HBM_BANDWIDTH = {
     "v5e": 819e9,
-    "v5litepod": 819e9,
     "v4": 1228e9,
     "v5p": 2765e9,
     "v6e": 1640e9,
@@ -234,52 +251,60 @@ TPU_HBM_BANDWIDTH = {
 # labeled as such wherever it surfaces.
 TPU_ICI_BANDWIDTH = {
     "v5e": 200e9,
-    "v5litepod": 200e9,
     "v4": 300e9,
     "v5p": 600e9,
     "v6e": 448e9,
 }
 
 
-def _chip_lookup(table: dict, env_var: str, default):
+def _chip_lookup(table: dict, env_var: str, off_tpu):
+    """The table's row for the attached chip, matched on the device kind
+    jax reports. `env_var` overrides; off the TPU the answer is `off_tpu`
+    (a planning constant, see PLANNING_CHIP); a TPU kind that is not in
+    DEVICE_KINDS raises."""
     import os
 
     env = os.environ.get(env_var)
     if env:
         return float(env)
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower().replace(" ", "")
-        for key, val in table.items():
-            if key in kind:
-                return val
-    except Exception:
-        pass
-    return default
-
-
-def peak_flops_per_chip(default: float = 197e12) -> float:
-    """Best-effort peak bf16 FLOP/s of the current chip."""
-    return _chip_lookup(TPU_PEAK_FLOPS, "BENCH_PEAK_FLOPS", default)
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return off_tpu
+    gen = DEVICE_KINDS.get(dev.device_kind)
+    if gen is None:
+        raise ValueError(
+            f"TPU device_kind {dev.device_kind!r} is not in "
+            f"utils/flops.DEVICE_KINDS ({sorted(DEVICE_KINDS)}): add its "
+            f"row to the peak tables, or set {env_var}")
+    return table[gen]
 
 
-def peak_hbm_bytes_per_chip(default: Optional[float] = None
-                            ) -> Optional[float]:
-    """HBM capacity of the current chip; None off-TPU (a CPU host's RAM
+def peak_flops_per_chip() -> float:
+    """Peak bf16 FLOP/s of the attached chip (the v5e planning figure
+    off-TPU)."""
+    return _chip_lookup(TPU_PEAK_FLOPS, "BENCH_PEAK_FLOPS",
+                        TPU_PEAK_FLOPS[PLANNING_CHIP])
+
+
+def peak_hbm_bytes_per_chip() -> Optional[float]:
+    """HBM capacity of the attached chip; None off-TPU (a CPU host's RAM
     is not the ceiling the JX008 check is about) unless BENCH_HBM_BYTES
     forces one."""
-    return _chip_lookup(TPU_HBM_BYTES, "BENCH_HBM_BYTES", default)
+    return _chip_lookup(TPU_HBM_BYTES, "BENCH_HBM_BYTES", None)
 
 
-def hbm_bandwidth_per_chip(default: float = 819e9) -> float:
-    """HBM bandwidth of the current chip (roofline ridge); the v5e
-    figure stands in off-TPU — the roofline is a TPU-shaped model."""
-    return _chip_lookup(TPU_HBM_BANDWIDTH, "BENCH_HBM_BANDWIDTH", default)
+def hbm_bandwidth_per_chip() -> float:
+    """HBM bandwidth of the attached chip (roofline ridge); the v5e
+    planning figure off-TPU — the roofline is a TPU-shaped model."""
+    return _chip_lookup(TPU_HBM_BANDWIDTH, "BENCH_HBM_BANDWIDTH",
+                        TPU_HBM_BANDWIDTH[PLANNING_CHIP])
 
 
-def ici_bandwidth_per_chip(default: float = 200e9) -> float:
-    """Aggregate ICI bandwidth of the current chip — the gradient
-    all-reduce estimate's denominator; the v5e figure stands in off-TPU
+def ici_bandwidth_per_chip() -> float:
+    """Aggregate ICI bandwidth of the attached chip — the gradient
+    all-reduce estimate's denominator; the v5e planning figure off-TPU
     (the estimate is a TPU-shaped cost model, labeled `estimate`)."""
-    return _chip_lookup(TPU_ICI_BANDWIDTH, "BENCH_ICI_BANDWIDTH", default)
+    return _chip_lookup(TPU_ICI_BANDWIDTH, "BENCH_ICI_BANDWIDTH",
+                        TPU_ICI_BANDWIDTH[PLANNING_CHIP])

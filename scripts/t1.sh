@@ -124,10 +124,11 @@ else
 fi
 
 # -- kernel-coverage smoke ----------------------------------------------------
-# The 53/53 contract (analysis/kernelcoverage.py): every ResNet-50 conv
-# instance must resolve to covered or declined-with-roofline-verdict in
-# planning mode — a silently-unsupported shape is a kernel-family hole
-# nobody decided on, and fails the gate. Pure config walking, no trace.
+# The coverage contract (analysis/kernelcoverage.py): every ResNet-50 conv
+# instance must resolve to covered, declined-with-roofline-verdict or
+# refused-by-the-chip-compiler in planning mode — any other unsupported
+# shape is a kernel-family hole nobody decided on, and fails the gate.
+# Pure config walking, no trace.
 rm -f /tmp/_t1_kcov.log
 if timeout -k 10 120 env JAX_PLATFORMS=cpu \
     python -m deeplearning4j_tpu.analysis.kernelcoverage --preset resnet50 \

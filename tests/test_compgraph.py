@@ -640,18 +640,34 @@ def test_block_scan_detects_identity_runs():
 
 
 def test_block_scan_output_and_training_bit_identical():
-    """Scanned forward == unrolled forward bit for bit, eager and jitted,
-    and a 3-step training run lands on byte-identical params."""
+    """Scanned forward == unrolled forward bit for bit, eager and jitted.
+    Training is compared with a stated tolerance: the scanned backward
+    sums the same per-unit gradient terms in another f32 order than the
+    unrolled one (two differently shaped XLA:CPU programs). After ONE
+    step the params agree to a last bit (measured 3.5e-7 abs under jax
+    0.9; 8 f32 eps = 9.5e-7 allowed). This net moves its params by more
+    than 1.0 per step (batch-8 BatchNorm over 2-4 channels), so that bit
+    grows about tenfold per step (measured 1.0e-5, 1.1e-4 after steps 2
+    and 3); after 3 steps atol 1e-3 is allowed — a thousandth of one
+    step's movement, where a wrong block order, a dropped unit or state
+    not threaded between steps is off by the movement itself."""
     x, y = _scan_xy()
     a, b = _scan_resnet("unroll"), _scan_resnet(True)
     np.testing.assert_array_equal(np.asarray(a.output(x)),
                                   np.asarray(b.output(x)))
-    a.fit(x, y, epochs=3, batch_size=8, async_prefetch=False)
-    b.fit(x, y, epochs=3, batch_size=8, async_prefetch=False)
-    for p1, p2 in zip(a.params_list, b.params_list):
-        for k in p1:
-            np.testing.assert_array_equal(np.asarray(p1[k]),
-                                          np.asarray(p2[k]))
+
+    def params(net):
+        return [(k, np.asarray(p[k])) for p in net.params_list for k in p]
+
+    for net in (a, b):
+        net.fit(x, y, epochs=1, batch_size=8, async_prefetch=False)
+    for (k, p1), (_, p2) in zip(params(a), params(b)):
+        np.testing.assert_allclose(p1, p2, rtol=0, err_msg=k,
+                                   atol=8 * np.finfo(np.float32).eps)
+    for net in (a, b):
+        net.fit(x, y, epochs=2, batch_size=8, async_prefetch=False)
+    for (k, p1), (_, p2) in zip(params(a), params(b)):
+        np.testing.assert_allclose(p1, p2, rtol=0, atol=1e-3, err_msg=k)
 
 
 def test_block_scan_collapses_graph_block_compile_counter():

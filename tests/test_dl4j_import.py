@@ -82,7 +82,7 @@ def test_mlp_zip_round_trip(tmp_path):
 
 
 def test_graves_lstm_zip_round_trip_golden_forward(tmp_path):
-    """The headline case (VERDICT missing #6): gate permutation + peephole
+    """The headline case: gate permutation + peephole
     column mapping proven by forward equality on a Graves LSTM."""
     conf = (NeuralNetConfiguration.builder().seed(11)
             .weight_init("xavier").list()

@@ -1,5 +1,5 @@
 """Multi-host DCN data parallelism: 2 processes x 4 virtual CPU devices
-== 1 process x 8 devices (VERDICT next #9 done-criterion).
+== 1 process x 8 devices.
 
 The reference's equivalent test tier is BaseSparkTest's local[N] Spark
 context (SURVEY §4 "distributed-without-a-cluster"); here the two workers

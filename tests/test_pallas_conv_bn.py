@@ -445,9 +445,14 @@ def test_roofline_declines_compute_bound_conv():
 
 
 def test_resnet50_kernel_coverage_complete():
-    """The 53/53 contract: every ResNet-50 conv instance resolves to
-    covered or declined-with-roofline-verdict — zero silently-unsupported
-    shapes (the gap this kernel family closes)."""
+    """Every ResNet-50 conv instance (batch 128, bf16) is DECIDED: covered,
+    declined with a roofline verdict, or refused for a reason the chip's
+    compiler gave (`CHIP_REFUSALS`; tests/test_tpu_compile.py holds the
+    covered ones and each refusal to that compiler). No instance is
+    unsupported because the kernel family has a hole. PR 17's "53/53
+    have kernels" held in interpret mode only: the stem (7x7/s2), the
+    three 3x3/s2 stage entries and the 64-channel 3x3/s1 do not compile
+    for the v5e."""
     from deeplearning4j_tpu.analysis.kernelcoverage import (
         coverage_summary,
         coverage_table,
@@ -456,19 +461,25 @@ def test_resnet50_kernel_coverage_complete():
 
     rows = coverage_table(resnet50_conf(), batch=128)
     s = coverage_summary(rows)
-    assert s["total"] == 53
-    assert s["unsupported"] == 0
-    assert s["covered"] + s["declined"] == 53
-    assert s["covered"] > 0 and s["declined"] > 0
+    assert (s["total"], s["covered"], s["declined"], s["unsupported"]) \
+        == (53, 29, 17, 7)
     by = {r["layer"]: r for r in rows}
-    assert by["stem_conv"]["status"] == "covered"
-    assert by["stem_conv"]["family"] == "conv7x7s2"
-    assert by["s1b0_b_conv"]["status"] == "covered"   # 3x3/s2 stage entry
-    assert by["s1b0_b_conv"]["family"] == "conv3x3s2"
+    assert (by["stem_conv"]["status"], by["stem_conv"]["reason"],
+            by["stem_conv"]["family"]) \
+        == ("unsupported", "strided_taps", "conv7x7s2")
+    assert (by["s1b0_b_conv"]["status"], by["s1b0_b_conv"]["reason"],
+            by["s1b0_b_conv"]["family"]) \
+        == ("unsupported", "strided_taps", "conv3x3s2")
+    assert (by["s0b0_b_conv"]["status"], by["s0b0_b_conv"]["reason"]) \
+        == ("unsupported", "lane_alignment")
     for r in rows:
         if r["status"] == "declined":
             assert r["reason"] == "compute_bound"
             assert r["intensity"] > r["ridge"]
+        elif r["status"] == "unsupported":
+            assert r["reason"] in pcb.CHIP_REFUSALS, r
+        else:  # what the router selects on the chip is the 1x1 kernel
+            assert r["family"] in ("conv1x1", "conv1x1s2"), r
 
 
 def test_fallback_on_cpu_without_interpret():

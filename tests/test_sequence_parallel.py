@@ -27,9 +27,8 @@ def _seq_mesh():
 
 def _qkv(B=2, T=32, H=4, D=8, seed=0):
     # T=32 (was 64): same 8-hop ring coverage at a quarter of the
-    # compile/grad cost — these tests went from import-broken (the
-    # jax.shard_map shim un-broke them) to ~130s of the 870s tier-1
-    # budget, and the math they pin is shape-independent
+    # compile/grad cost — these tests take ~130s of the tier-1 budget,
+    # and the math they pin is shape-independent
     rng = np.random.default_rng(seed)
     mk = lambda: jnp.asarray(
         rng.standard_normal((B, T, H, D)) * 0.5, jnp.float32)
@@ -38,14 +37,12 @@ def _qkv(B=2, T=32, H=4, D=8, seed=0):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_equals_full_attention(causal):
-    from deeplearning4j_tpu.parallel.mesh import shard_map_fn
-    shard_map = shard_map_fn()
     from jax.sharding import PartitionSpec as P
 
     q, k, v = _qkv()
     mesh = _seq_mesh()
     spec = P(None, SEQ_AXIS, None, None)
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v: ring_attention_sharded(q, k, v, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )
@@ -83,15 +80,13 @@ def test_ring_attention_bf16_accumulates_f32():
     full-attention truth (within one bf16 rounding of inputs/outputs) —
     and exactly matches single-device attention run with the same f32
     accumulation policy."""
-    from deeplearning4j_tpu.parallel.mesh import shard_map_fn
-    shard_map = shard_map_fn()
     from jax.sharding import PartitionSpec as P
 
     q, k, v = _qkv(T=16)
     qb, kb, vb = (t.astype(jnp.bfloat16) for t in (q, k, v))
     mesh = _seq_mesh()
     spec = P(None, SEQ_AXIS, None, None)
-    ring = shard_map(
+    ring = jax.shard_map(
         lambda q, k, v: ring_attention_sharded(q, k, v, causal=True),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
     )
@@ -113,8 +108,6 @@ def test_ring_attention_bf16_accumulates_f32():
 def test_ring_attention_differentiable():
     """Gradients flow through the ring (training viability, not just
     inference)."""
-    from deeplearning4j_tpu.parallel.mesh import shard_map_fn
-    shard_map = shard_map_fn()
     from jax.sharding import PartitionSpec as P
 
     q, k, v = _qkv(T=16)
@@ -128,7 +121,7 @@ def test_ring_attention_differentiable():
     spec = P(None, SEQ_AXIS, None, None)
 
     def loss_ring(q, k, v):
-        f = shard_map(
+        f = jax.shard_map(
             lambda q, k, v: ring_attention_sharded(q, k, v, causal=True),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
         return jnp.sum(jnp.square(f(q, k, v)))
