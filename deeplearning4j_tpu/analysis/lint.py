@@ -451,7 +451,7 @@ class _ModuleLinter(ast.NodeVisitor):
             elif attr == "join" and _is_threadish_receiver(func.value):
                 blocked = "thread.join"
             elif attr == "block_until_ready":
-                blocked = "device_sync"
+                blocked = "device.sync"
             elif attr in ("urlopen", "create_connection", "getresponse"):
                 blocked = "socket/http"
         elif isinstance(func, ast.Name) and func.id == "urlopen":

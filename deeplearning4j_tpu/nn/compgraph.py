@@ -45,8 +45,9 @@ from deeplearning4j_tpu.nn.layers.registry import (
 from deeplearning4j_tpu.nn.multilayer import (
     _OUTPUT_LAYER_TYPES,
     _is_recurrent,
+    _l1_l2_penalty,
     _preout_of_output_layer,
-    _regularizable,
+    layer_scope,
 )
 from deeplearning4j_tpu.nn.netbase import NetworkBase
 from deeplearning4j_tpu.ops.losses import example_presence, masked_example_mean, loss_value
@@ -357,43 +358,49 @@ class ComputationGraph(NetworkBase):
                     for _ in range(r["count"]):
                         self._note_compile("graph_block", r["exit"])
             v = conf.vertices[name]
-            xs = [acts[i] for i in conf.vertex_inputs[name]]
-            if isinstance(v, LayerVertex):
-                x = xs[0]
-                timesteps = x.shape[1] if x.ndim == 3 else None
-                if v.preprocessor is not None:
-                    x = v.preprocessor(x, {"timesteps": timesteps})
-                    if hasattr(x, "ndim") and x.ndim == 3:
-                        timesteps = x.shape[1]
-                pidx = self._pidx[name]
-                lc = v.layer
-                st = states[pidx]
-                if stateful and _is_recurrent(lc) and st is None:
-                    st = {}  # empty dict triggers zero-state seed + carry
-                ctx = LayerContext(
-                    training=training,
-                    rng=jax.random.fold_in(rng, pidx) if rng is not None else None,
-                    mask=sole_mask if (hasattr(x, "ndim") and x.ndim == 3) else None,
-                    timesteps=timesteps,
-                    state=st,
-                )
-                if (
-                    preout_outputs
-                    and name in conf.outputs
-                    and isinstance(lc, _OUTPUT_LAYER_TYPES)
-                ):
-                    from deeplearning4j_tpu.nn.layers.core import apply_dropout
+            with jax.named_scope(layer_scope(
+                    name, v.layer if isinstance(v, LayerVertex) else v)):
+                xs = [acts[i] for i in conf.vertex_inputs[name]]
+                if isinstance(v, LayerVertex):
+                    x = xs[0]
+                    timesteps = x.shape[1] if x.ndim == 3 else None
+                    if v.preprocessor is not None:
+                        x = v.preprocessor(x, {"timesteps": timesteps})
+                        if hasattr(x, "ndim") and x.ndim == 3:
+                            timesteps = x.shape[1]
+                    pidx = self._pidx[name]
+                    lc = v.layer
+                    st = states[pidx]
+                    if stateful and _is_recurrent(lc) and st is None:
+                        st = {}  # empty dict triggers zero-state seed + carry
+                    ctx = LayerContext(
+                        training=training,
+                        rng=(jax.random.fold_in(rng, pidx)
+                             if rng is not None else None),
+                        mask=(sole_mask if hasattr(x, "ndim")
+                              and x.ndim == 3 else None),
+                        timesteps=timesteps,
+                        state=st,
+                    )
+                    if (
+                        preout_outputs
+                        and name in conf.outputs
+                        and isinstance(lc, _OUTPUT_LAYER_TYPES)
+                    ):
+                        from deeplearning4j_tpu.nn.layers.core import (
+                            apply_dropout,
+                        )
 
-                    x = apply_dropout(x, lc.dropout, ctx)
-                    acts[name + "__features"] = x
-                    x = _preout_of_output_layer(lc, params[pidx], x)
-                    ns = None
+                        x = apply_dropout(x, lc.dropout, ctx)
+                        acts[name + "__features"] = x
+                        x = _preout_of_output_layer(lc, params[pidx], x)
+                        ns = None
+                    else:
+                        x, ns = forward_layer(lc, params[pidx], x, ctx)
+                    new_states[pidx] = ns
+                    acts[name] = x
                 else:
-                    x, ns = forward_layer(lc, params[pidx], x, ctx)
-                new_states[pidx] = ns
-                acts[name] = x
-            else:
-                acts[name] = v.forward(xs, env)
+                    acts[name] = v.forward(xs, env)
             pos += 1
         return acts, new_states
 
@@ -464,10 +471,12 @@ class ComputationGraph(NetworkBase):
                         timesteps=xq.shape[1] if xq.ndim == 3 else None,
                         state=us[j],
                     )
-                    y, ns = forward_layer(v.layer, up[j], xq, ctx)
+                    with jax.named_scope(layer_scope(vname, v.layer)):
+                        y, ns = forward_layer(v.layer, up[j], xq, ctx)
                     new_sts.append(ns)
                 else:
-                    y = v.forward(srcs, {})
+                    with jax.named_scope(layer_scope(vname, v)):
+                        y = v.forward(srcs, {})
                 local[q] = y
             return local[p - 1], tuple(new_sts)
 
@@ -492,7 +501,11 @@ class ComputationGraph(NetworkBase):
             up, us, prow = xs_scan
             return run_unit(carry, up, us, prow)
 
-        exit_act, ys = jax.lax.scan(body, x, (sp, ss, pmat))
+        # one body for k units: the events carry the block's name (its
+        # exit vertex, as compile_total{kind="graph_block"} has it) and,
+        # under it, the first unit's layer scopes
+        with jax.named_scope(f"block_{r['exit']}"):
+            exit_act, ys = jax.lax.scan(body, x, (sp, ss, pmat))
         updates = {}
         for j in range(len(slots)):
             nsj = ys[j]
@@ -515,67 +528,56 @@ class ComputationGraph(NetworkBase):
             params, states, xs, training=training, rng=rng,
             input_masks=f_masks, preout_outputs=True,
         )
-        score = 0.0
-        n_heads = 0
-        for i, name in enumerate(conf.outputs):
-            v = conf.vertices[name]
-            if not (isinstance(v, LayerVertex)
-                    and isinstance(v.layer, _OUTPUT_LAYER_TYPES)):
-                continue
-            lc = v.layer
-            lm = l_masks[i] if l_masks is not None else None
-            per_ex = loss_value(
-                lc.loss, ys[i], self.policy.cast_output(acts[name]),
-                lc.activation, lm,
-            )
-            score = score + masked_example_mean(per_ex, lm)
-            if isinstance(lc, L.CenterLossOutputLayer):
-                # center loss head (reference: CenterLossOutputLayer.java):
-                # + lambda * mean(0.5||f - c_y||^2) on the head's input
-                # features, centers EMA-updated as non-trainable state
-                pidx = self._pidx[name]
-                feats = acts[name + "__features"]
-                centers = states[pidx]["centers"].astype(feats.dtype)
-                y32 = ys[i].astype(feats.dtype)
-                diff = feats - y32 @ centers
-                center_per_ex = 0.5 * jnp.sum(diff * diff, axis=-1)
-                present = example_presence(per_ex, lm)
-                score = score + lc.lambda_ * (
-                    jnp.sum(center_per_ex * present)
-                    / jnp.maximum(jnp.sum(present), 1.0))
-                if training:
-                    f_sg = jax.lax.stop_gradient(feats)
-                    yw = y32 * present[:, None]
-                    counts = jnp.sum(yw, axis=0)[:, None]
-                    means = (yw.T @ f_sg) / jnp.maximum(counts, 1.0)
-                    updated = jnp.where(
-                        counts > 0,
-                        (1.0 - lc.alpha) * centers + lc.alpha * means,
-                        centers,
-                    )
-                    new_states[pidx] = {
-                        "centers": updated.astype(states[pidx]["centers"].dtype)
-                    }
-            n_heads += 1
-        if n_heads == 0:
-            raise ValueError(
-                "no output vertex is a loss head (OutputLayer/RnnOutputLayer/"
-                "LossLayer) — cannot compute a training loss"
-            )
-        reg = 0.0
-        for lc, p in zip(self._layer_confs, params):
-            inner = lc.inner if isinstance(lc, L.FrozenLayer) else lc
-            l1 = getattr(inner, "l1", 0.0) or 0.0
-            l2 = getattr(inner, "l2", 0.0) or 0.0
-            if l1 == 0.0 and l2 == 0.0:
-                continue
-            for pname, w in p.items():
-                if _regularizable(pname):
-                    if l1:
-                        reg = reg + l1 * jnp.sum(jnp.abs(w))
-                    if l2:
-                        reg = reg + 0.5 * l2 * jnp.sum(w * w)
-        return score + reg, new_states
+        with jax.named_scope("loss"):
+            score = 0.0
+            n_heads = 0
+            for i, name in enumerate(conf.outputs):
+                v = conf.vertices[name]
+                if not (isinstance(v, LayerVertex)
+                        and isinstance(v.layer, _OUTPUT_LAYER_TYPES)):
+                    continue
+                lc = v.layer
+                lm = l_masks[i] if l_masks is not None else None
+                per_ex = loss_value(
+                    lc.loss, ys[i], self.policy.cast_output(acts[name]),
+                    lc.activation, lm,
+                )
+                score = score + masked_example_mean(per_ex, lm)
+                if isinstance(lc, L.CenterLossOutputLayer):
+                    # center loss head (reference: CenterLossOutputLayer.java):
+                    # + lambda * mean(0.5||f - c_y||^2) on the head's input
+                    # features, centers EMA-updated as non-trainable state
+                    pidx = self._pidx[name]
+                    feats = acts[name + "__features"]
+                    centers = states[pidx]["centers"].astype(feats.dtype)
+                    y32 = ys[i].astype(feats.dtype)
+                    diff = feats - y32 @ centers
+                    center_per_ex = 0.5 * jnp.sum(diff * diff, axis=-1)
+                    present = example_presence(per_ex, lm)
+                    score = score + lc.lambda_ * (
+                        jnp.sum(center_per_ex * present)
+                        / jnp.maximum(jnp.sum(present), 1.0))
+                    if training:
+                        f_sg = jax.lax.stop_gradient(feats)
+                        yw = y32 * present[:, None]
+                        counts = jnp.sum(yw, axis=0)[:, None]
+                        means = (yw.T @ f_sg) / jnp.maximum(counts, 1.0)
+                        updated = jnp.where(
+                            counts > 0,
+                            (1.0 - lc.alpha) * centers + lc.alpha * means,
+                            centers,
+                        )
+                        new_states[pidx] = {
+                            "centers": updated.astype(states[pidx]["centers"].dtype)
+                        }
+                n_heads += 1
+            if n_heads == 0:
+                raise ValueError(
+                    "no output vertex is a loss head (OutputLayer/RnnOutputLayer/"
+                    "LossLayer) — cannot compute a training loss"
+                )
+            return score + _l1_l2_penalty(self._layer_confs, params), \
+                new_states
 
     # -- train step ----------------------------------------------------------
 
@@ -681,35 +683,38 @@ class ComputationGraph(NetworkBase):
                 loss_fn, has_aux=True
             )(params)
             if plan is not None:
-                grads = plan.reduce_grads(self, grads)
-            # global grad norm of the RAW gradient (before masking/
-            # clipping), accumulated in f32 — the sentinel diagnostic
-            gsq = jnp.float32(0.0)
-            for g in jax.tree_util.tree_leaves(grads):
-                gsq = gsq + jnp.sum(jnp.square(g.astype(jnp.float32)))
-            diag = jnp.stack([score.astype(jnp.float32), jnp.sqrt(gsq)])
-            if not minimize:
-                grads = jax.tree_util.tree_map(lambda g: -g, grads)
-            grads = [
-                {k: g[k] * m[k] for k in g} for g, m in zip(grads, tmask)
-            ]
-            grads = normalize_gradients(grads, gnorm, gthresh)
-            lr_tree = [
-                {k: lr * m[k] for k in g} for g, m in zip(grads, mults)
-            ]
-            updates, new_upd = updater.apply_tree(grads, upd_state, lr_tree, t)
-            new_params = jax.tree_util.tree_map(jnp.add, params, updates)
+                with jax.named_scope("reduce_grads"):
+                    grads = plan.reduce_grads(self, grads)
             merged = self._merge_states(states, new_states)
-            if collect:
-                # per-layer mean |x| scalars for the stats pipeline
-                # (reference: BaseStatsListener mean magnitudes)
-                mm = lambda tree: [
-                    {k: jnp.mean(jnp.abs(v)) for k, v in p.items()}
-                    for p in tree
+            with jax.named_scope("update"):
+                # global grad norm of the RAW gradient (before masking/
+                # clipping), accumulated in f32 — the sentinel diagnostic
+                gsq = jnp.float32(0.0)
+                for g in jax.tree_util.tree_leaves(grads):
+                    gsq = gsq + jnp.sum(jnp.square(g.astype(jnp.float32)))
+                diag = jnp.stack([score.astype(jnp.float32), jnp.sqrt(gsq)])
+                if not minimize:
+                    grads = jax.tree_util.tree_map(lambda g: -g, grads)
+                grads = [
+                    {k: g[k] * m[k] for k in g} for g, m in zip(grads, tmask)
                 ]
-                stats = {"grad_mm": mm(grads), "update_mm": mm(updates),
-                         "param_mm": mm(new_params)}
-                return new_params, merged, new_upd, score, diag, stats
+                grads = normalize_gradients(grads, gnorm, gthresh)
+                lr_tree = [
+                    {k: lr * m[k] for k in g} for g, m in zip(grads, mults)
+                ]
+                updates, new_upd = updater.apply_tree(grads, upd_state,
+                                                      lr_tree, t)
+                new_params = jax.tree_util.tree_map(jnp.add, params, updates)
+                if collect:
+                    # per-layer mean |x| scalars for the stats pipeline
+                    # (reference: BaseStatsListener mean magnitudes)
+                    mm = lambda tree: [
+                        {k: jnp.mean(jnp.abs(v)) for k, v in p.items()}
+                        for p in tree
+                    ]
+                    stats = {"grad_mm": mm(grads), "update_mm": mm(updates),
+                             "param_mm": mm(new_params)}
+                    return new_params, merged, new_upd, score, diag, stats
             return new_params, merged, new_upd, score, diag
 
         return step

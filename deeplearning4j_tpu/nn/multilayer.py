@@ -79,6 +79,40 @@ def _regularizable(name: str) -> bool:
     return base in ("W", "RW", "pI", "pF", "pO")
 
 
+def _l1_l2_penalty(confs, params):
+    """L1/L2 penalties (reference: BaseLayer.calcL1/calcL2 added to score;
+    gradients come from differentiating this same expression)."""
+    reg = 0.0
+    for conf, p in zip(confs, params):
+        inner = conf.inner if isinstance(conf, L.FrozenLayer) else conf
+        l1 = getattr(inner, "l1", 0.0) or 0.0
+        l2 = getattr(inner, "l2", 0.0) or 0.0
+        if l1 == 0.0 and l2 == 0.0:
+            continue
+        for name, w in p.items():
+            if _regularizable(name):
+                if l1:
+                    reg = reg + l1 * jnp.sum(jnp.abs(w))
+                if l2:
+                    reg = reg + 0.5 * l2 * jnp.sum(w * w)
+    return reg
+
+
+def layer_scope(key, conf) -> str:
+    """The `jax.named_scope` of one layer or vertex in the step program:
+    `L<key>_<kind>`, key as in `params_list` (position in a sequential
+    net, vertex name in a graph), kind the conf class in lower case
+    without its `Layer`/`Vertex` suffix (`L2_convolution`,
+    `Lstem_bn_batchnorm`). Device-trace events carry it in their op name
+    (backward ops as `transpose(jvp(L2_convolution))`), so a trace
+    reduction finds a layer's device time after any refactor."""
+    kind = type(conf).__name__.lower().replace("normalization", "norm")
+    for suffix in ("layer", "vertex"):
+        if kind.endswith(suffix) and kind != suffix:
+            kind = kind[:-len(suffix)]
+    return f"L{key}_{kind}"
+
+
 def _preout_of_output_layer(conf, params, x):
     """Pre-activation of the final (output) layer — the quantity losses
     consume (reference: BaseOutputLayer.preOutput2d)."""
@@ -134,32 +168,39 @@ class MultiLayerNetwork(NetworkBase):
         n = len(confs) if to_layer is None else to_layer
         for i in range(n):
             conf = confs[i]
-            pp = pps.get(str(i))
-            if pp is not None:
-                x = pp(x, {"timesteps": timesteps})
-            if hasattr(x, "ndim") and x.ndim == 3:
-                timesteps = x.shape[1]
-            st = states[i]
-            if stateful and _is_recurrent(conf) and st is None:
-                st = {}  # empty dict triggers zero-state seed + state return
-            ctx = LayerContext(
-                training=training,
-                rng=jax.random.fold_in(rng, i) if rng is not None else None,
-                mask=f_mask if (hasattr(x, "ndim") and x.ndim == 3) else None,
-                timesteps=timesteps,
-                state=st,
-            )
-            is_last = i == len(confs) - 1
-            if preout_last and is_last and isinstance(conf, _OUTPUT_LAYER_TYPES):
-                # input dropout applies to the output layer too (reference:
-                # BaseOutputLayer preOutput applies Dropout to its input)
-                from deeplearning4j_tpu.nn.layers.core import apply_dropout
+            with jax.named_scope(layer_scope(i, conf)):
+                pp = pps.get(str(i))
+                if pp is not None:
+                    x = pp(x, {"timesteps": timesteps})
+                if hasattr(x, "ndim") and x.ndim == 3:
+                    timesteps = x.shape[1]
+                st = states[i]
+                if stateful and _is_recurrent(conf) and st is None:
+                    st = {}  # empty: zero-state seed + state return
+                ctx = LayerContext(
+                    training=training,
+                    rng=(jax.random.fold_in(rng, i)
+                         if rng is not None else None),
+                    mask=(f_mask if hasattr(x, "ndim") and x.ndim == 3
+                          else None),
+                    timesteps=timesteps,
+                    state=st,
+                )
+                is_last = i == len(confs) - 1
+                if (preout_last and is_last
+                        and isinstance(conf, _OUTPUT_LAYER_TYPES)):
+                    # input dropout applies to the output layer too
+                    # (reference: BaseOutputLayer preOutput applies
+                    # Dropout to its input)
+                    from deeplearning4j_tpu.nn.layers.core import (
+                        apply_dropout,
+                    )
 
-                x = apply_dropout(x, conf.dropout, ctx)
-                x = _preout_of_output_layer(conf, params[i], x)
-                ns = None
-            else:
-                x, ns = forward_layer(conf, params[i], x, ctx)
+                    x = apply_dropout(x, conf.dropout, ctx)
+                    x = _preout_of_output_layer(conf, params[i], x)
+                    ns = None
+                else:
+                    x, ns = forward_layer(conf, params[i], x, ctx)
             new_states[i] = ns
         return x, new_states
 
@@ -185,25 +226,14 @@ class MultiLayerNetwork(NetworkBase):
                 params, states, x, training=training, rng=rng, f_mask=f_mask,
                 preout_last=True,
             )
-            preout = self.policy.cast_output(preout)
-            per_ex = loss_value(last.loss, y, preout, last.activation, l_mask)
-            score = masked_example_mean(per_ex, l_mask)
-        # L1/L2 penalties (reference: BaseLayer.calcL1/calcL2 added to score;
-        # gradients come from differentiating this same expression)
-        reg = 0.0
-        for conf, p in zip(self.layer_confs, params):
-            inner = conf.inner if isinstance(conf, L.FrozenLayer) else conf
-            l1 = getattr(inner, "l1", 0.0) or 0.0
-            l2 = getattr(inner, "l2", 0.0) or 0.0
-            if l1 == 0.0 and l2 == 0.0:
-                continue
-            for name, w in p.items():
-                if _regularizable(name):
-                    if l1:
-                        reg = reg + l1 * jnp.sum(jnp.abs(w))
-                    if l2:
-                        reg = reg + 0.5 * l2 * jnp.sum(w * w)
-        return score + reg, new_states
+            with jax.named_scope("loss"):
+                preout = self.policy.cast_output(preout)
+                per_ex = loss_value(last.loss, y, preout, last.activation,
+                                    l_mask)
+                score = masked_example_mean(per_ex, l_mask)
+        with jax.named_scope("loss"):
+            return score + _l1_l2_penalty(self.layer_confs, params), \
+                new_states
 
     def _center_loss(self, params, states, x, y, f_mask, l_mask, rng, training):
         """Center loss (reference: nn/layers/training/CenterLossOutputLayer
@@ -222,35 +252,37 @@ class MultiLayerNetwork(NetworkBase):
             training=training,
             rng=jax.random.fold_in(rng, n - 1) if rng is not None else None,
         )
-        feats = apply_dropout(feats, last.dropout, ctx_last)
-        preout = _preout_of_output_layer(last, params[-1], feats)
-        preout = self.policy.cast_output(preout)
-        per_ex = loss_value(last.loss, y, preout, last.activation, l_mask)
+        with jax.named_scope(layer_scope(n - 1, last)):
+            feats = apply_dropout(feats, last.dropout, ctx_last)
+            preout = _preout_of_output_layer(last, params[-1], feats)
+        with jax.named_scope("loss"):
+            preout = self.policy.cast_output(preout)
+            per_ex = loss_value(last.loss, y, preout, last.activation, l_mask)
 
-        centers = states[-1]["centers"].astype(feats.dtype)  # [classes, nIn]
-        y32 = y.astype(feats.dtype)
-        per_example_center = y32 @ centers  # one-hot pick
-        diff = feats - per_example_center
-        center_per_ex = 0.5 * jnp.sum(diff * diff, axis=-1)
-        present = example_presence(per_ex, l_mask)
-        score = (masked_example_mean(per_ex, l_mask)
-                 + last.lambda_ * jnp.sum(center_per_ex * present)
-                 / jnp.maximum(jnp.sum(present), 1.0))
+            centers = states[-1]["centers"].astype(feats.dtype)  # [classes, nIn]
+            y32 = y.astype(feats.dtype)
+            per_example_center = y32 @ centers  # one-hot pick
+            diff = feats - per_example_center
+            center_per_ex = 0.5 * jnp.sum(diff * diff, axis=-1)
+            present = example_presence(per_ex, l_mask)
+            score = (masked_example_mean(per_ex, l_mask)
+                     + last.lambda_ * jnp.sum(center_per_ex * present)
+                     / jnp.maximum(jnp.sum(present), 1.0))
 
-        if training:
-            # EMA update: c_k <- (1-alpha) c_k + alpha * mean(f_i : y_i = k),
-            # only for classes present in the batch; gradients do not flow
-            # into the centers (they are state, not params)
-            f_sg = jax.lax.stop_gradient(feats)
-            yw = y32 * present[:, None]  # pad rows excluded from the EMA
-            counts = jnp.sum(yw, axis=0)[:, None]  # [classes, 1]
-            sums = yw.T @ f_sg  # [classes, nIn]
-            means = sums / jnp.maximum(counts, 1.0)
-            updated = jnp.where(
-                counts > 0, (1.0 - last.alpha) * centers + last.alpha * means,
-                centers,
-            )
-            new_states[-1] = {"centers": updated.astype(states[-1]["centers"].dtype)}
+            if training:
+                # EMA update: c_k <- (1-alpha) c_k + alpha * mean(f_i : y_i = k),
+                # only for classes present in the batch; gradients do not flow
+                # into the centers (they are state, not params)
+                f_sg = jax.lax.stop_gradient(feats)
+                yw = y32 * present[:, None]  # pad rows excluded from the EMA
+                counts = jnp.sum(yw, axis=0)[:, None]  # [classes, 1]
+                sums = yw.T @ f_sg  # [classes, nIn]
+                means = sums / jnp.maximum(counts, 1.0)
+                updated = jnp.where(
+                    counts > 0, (1.0 - last.alpha) * centers + last.alpha * means,
+                    centers,
+                )
+                new_states[-1] = {"centers": updated.astype(states[-1]["centers"].dtype)}
         return score, new_states
 
     # -- train step ----------------------------------------------------------
@@ -317,37 +349,40 @@ class MultiLayerNetwork(NetworkBase):
                 loss_fn, has_aux=True
             )(params)
             if plan is not None:
-                grads = plan.reduce_grads(self, grads)
-            # global grad norm of the RAW gradient (before masking/
-            # clipping — clipping would hide exactly the explosion the
-            # sentinel watches for), accumulated in f32
-            gsq = jnp.float32(0.0)
-            for g in jax.tree_util.tree_leaves(grads):
-                gsq = gsq + jnp.sum(jnp.square(g.astype(jnp.float32)))
-            diag = jnp.stack([score.astype(jnp.float32), jnp.sqrt(gsq)])
-            if not minimize:
-                grads = jax.tree_util.tree_map(lambda g: -g, grads)
-            grads = [
-                {k: g[k] * m[k] for k in g} for g, m in zip(grads, tmask)
-            ]
-            grads = normalize_gradients(grads, gnorm, gthresh)
-            lr_tree = [
-                {k: lr * m[k] for k in g} for g, m in zip(grads, mults)
-            ]
-            updates, new_upd = updater.apply_tree(grads, upd_state, lr_tree, t)
-            new_params = jax.tree_util.tree_map(jnp.add, params, updates)
+                with jax.named_scope("reduce_grads"):
+                    grads = plan.reduce_grads(self, grads)
             merged = self._merge_states(states, new_states)
-            if collect:
-                # per-layer mean |x| scalars for the stats pipeline
-                # (reference: BaseStatsListener param/grad/update mean
-                # magnitudes) — fused into the step; tiny reductions
-                mm = lambda tree: [
-                    {k: jnp.mean(jnp.abs(v)) for k, v in p.items()}
-                    for p in tree
+            with jax.named_scope("update"):
+                # global grad norm of the RAW gradient (before masking/
+                # clipping — clipping would hide exactly the explosion the
+                # sentinel watches for), accumulated in f32
+                gsq = jnp.float32(0.0)
+                for g in jax.tree_util.tree_leaves(grads):
+                    gsq = gsq + jnp.sum(jnp.square(g.astype(jnp.float32)))
+                diag = jnp.stack([score.astype(jnp.float32), jnp.sqrt(gsq)])
+                if not minimize:
+                    grads = jax.tree_util.tree_map(lambda g: -g, grads)
+                grads = [
+                    {k: g[k] * m[k] for k in g} for g, m in zip(grads, tmask)
                 ]
-                stats = {"grad_mm": mm(grads), "update_mm": mm(updates),
-                         "param_mm": mm(new_params)}
-                return new_params, merged, new_upd, score, diag, stats
+                grads = normalize_gradients(grads, gnorm, gthresh)
+                lr_tree = [
+                    {k: lr * m[k] for k in g} for g, m in zip(grads, mults)
+                ]
+                updates, new_upd = updater.apply_tree(grads, upd_state,
+                                                      lr_tree, t)
+                new_params = jax.tree_util.tree_map(jnp.add, params, updates)
+                if collect:
+                    # per-layer mean |x| scalars for the stats pipeline
+                    # (reference: BaseStatsListener param/grad/update mean
+                    # magnitudes) — fused into the step; tiny reductions
+                    mm = lambda tree: [
+                        {k: jnp.mean(jnp.abs(v)) for k, v in p.items()}
+                        for p in tree
+                    ]
+                    stats = {"grad_mm": mm(grads), "update_mm": mm(updates),
+                             "param_mm": mm(new_params)}
+                    return new_params, merged, new_upd, score, diag, stats
             return new_params, merged, new_upd, score, diag
 
         return step

@@ -89,6 +89,10 @@ class NetworkBase:
         # the per-step hot path touches cached children only (the ISSUE's
         # overhead guard: zero registry lookups per step)
         self._fit_instruments = None
+        # `t_end` of the fit thread's last dispatch on tracing.now_ns()
+        # (the epoch's start before the first): the next record's
+        # `t_wait0`, so the timeline's phases tile with no hole
+        self._fit_t_mark = 0
         # donate_argnums the step builders actually used (recorded by
         # _step_donate_argnums) — the doctor's JX006 check audits THIS,
         # not a reconstruction of the policy
@@ -512,13 +516,9 @@ class NetworkBase:
                     "dispatch").labels(),
                 "dispatch": reg.histogram(
                     "fit_dispatch_seconds",
-                    "host time in the train-step call (trace + dispatch; "
-                    "excludes device sync)").labels(),
-                "sync": reg.histogram(
-                    "fit_device_sync_seconds",
-                    "device sync to the step's score — measured only "
-                    "while tracing is enabled, so the default fit path "
-                    "never adds blocking syncs").labels(),
+                    "host time in the train-step call (trace + dispatch, "
+                    "and the wait for a free slot in the device's queue: "
+                    "on a busy chip it tracks the device step)").labels(),
                 "examples_unknown": reg.counter(
                     "fit_examples_unknown_total",
                     "fit batches whose example count could not be "
@@ -543,6 +543,7 @@ class NetworkBase:
                     "all-reduce, by accounting source",
                     ("source",)).labels("measured"),
                 "recorder": _blackbox.get_recorder(),
+                "timeline": _tracing.get_step_timeline().append,
                 "devprof": _devprof.get_profiler(),
             }
         return ins
@@ -550,16 +551,20 @@ class NetworkBase:
     def _timed_fit(self, fit_fn, data_wait: float, n_examples: int,
                    n_batches: int = 1, batches=None):
         """Run one dispatch (a single `_fit_dataset` or a fused flush)
-        under the step-phase timers: data-wait / dispatch / device-sync,
-        each a histogram in the shared registry and a span when tracing
-        is on. Device-sync is only MEASURED (a blocking read of the
-        step's score) when tracing is enabled — observability must not
-        change the async dispatch pipeline it observes. `batches` names
-        the DataSet(s) behind this dispatch for the divergence sentinel's
-        quarantine records and the `nan` fault kind's batch taint."""
+        under the step-phase timers. The clock (`tracing.now_ns`) is read
+        once at each boundary — dispatch start, dispatch end, end of the
+        observers — and the readings feed the histograms, the always-on
+        step timeline (one tuple a dispatch; the flight recorder and the
+        benchmark read it) and, when the tracer is on, the spans
+        `fit/step` > `fit/dispatch`, `fit/observe`. Nothing here blocks
+        on the device, tracer on or off: observability must not change
+        the async dispatch pipeline it observes (devprof's sampled read
+        is the one exception, and the timeline records its interval).
+        `batches` names the DataSet(s) behind this dispatch for the
+        divergence sentinel's quarantine records and the `nan` fault
+        kind's batch taint."""
         ins = self._fit_obs()
         it0 = self.iteration
-        sync = None
         # resume bookkeeping BEFORE the dispatch: a checkpoint listener
         # firing inside it (post-step _notify) must record this batch as
         # consumed — the snapshot's params already include its update
@@ -576,9 +581,10 @@ class NetworkBase:
         # attached (the <10us off-path contract); with one, the pre-step
         # references that make an anomalous step's update discardable
         pre = _sentinel.pre_step(self)
-        t0 = time.perf_counter()
+        now_ns, cpu_ns = _tracing.now_ns, time.thread_time_ns
         with _tracing.span("fit/step", data_wait_ms=round(data_wait * 1e3, 3)):
             with _tracing.span("fit/dispatch"):
+                t_d0, c0 = now_ns(), cpu_ns()
                 # chaos hook: an `oom` fault here is a device allocator
                 # failure mid-fit — it unwinds through _run_fit's OOM
                 # forensics exactly as a real RESOURCE_EXHAUSTED would;
@@ -593,15 +599,32 @@ class NetworkBase:
                 # program (off = one module-global read)
                 _locktrace.note_dispatch("fit/dispatch")
                 fit_fn()
-            dispatch = time.perf_counter() - t0
-            if _tracing.is_enabled() and self._score is not None:
-                import jax
+                t_d1, c1 = now_ns(), cpu_ns()
+            with _tracing.span("fit/observe"):
+                sampled = None
+                try:
+                    sampled = self._observe_step(
+                        ins, it0, data_wait, (t_d1 - t_d0) * 1e-9,
+                        n_examples, pre, batches)
+                finally:
+                    # the record lands even when the sentinel raises: an
+                    # anomalous step stays visible in the flight recorder
+                    # though its update is about to be discarded
+                    s0, s1 = sampled or (0, 0)
+                    t_end = now_ns()
+                    ins["timeline"]((
+                        self.iteration - 1, max(1, self.iteration - it0),
+                        self._fit_t_mark or t_d0, t_d0, t_d1, t_end, s0, s1,
+                        c1 - c0, cpu_ns() - c1, self._score))
+                    self._fit_t_mark = t_end
 
-                t1 = time.perf_counter()
-                with _tracing.span("fit/device_sync"):
-                    jax.block_until_ready(self._score)
-                sync = time.perf_counter() - t1
-                ins["sync"].observe(sync)
+    def _observe_step(self, ins, it0: int, data_wait: float, dispatch: float,
+                      n_examples: int, pre, batches):
+        """Everything the fit thread does for its observers after a
+        dispatch returned: counters and histograms, collective books, the
+        flight recorder's count, devprof, the run ledger, the sentinel's
+        judgment, the heartbeat. Returns devprof's `(t0, t1)` where this
+        dispatch was sampled."""
         n_steps = max(1, self.iteration - it0)
         ins["steps"].inc(n_steps)
         ins["examples"].inc(n_examples)
@@ -629,27 +652,26 @@ class NetworkBase:
                 self, n_steps, ins["devprof"].sample_every)
             if measured is not None:
                 ins["collective_seconds_measured"].inc(measured)
-        # black box + liveness: one ring append (score kept as a device
-        # reference — never synced here) and a heartbeat refresh
-        ins["recorder"].record_step(self.iteration - 1, score=self._score,
-                                    data_wait=data_wait, dispatch=dispatch,
-                                    sync=sync)
+        # black box + liveness: the recorder counts the dispatch (its
+        # record is the timeline's, appended by the caller; the score
+        # stays a device reference — never synced here)
+        ins["recorder"].note_step()
         # device-side accounting: two integer ops on unsampled steps,
         # one blocking score read every sample_every-th (utils/devprof)
-        ins["devprof"].on_step(self, n_examples, self._score)
+        sampled = ins["devprof"].on_step(self, n_examples, self._score)
         # run-ledger hook: ONE module-global read with no ledger
         # attached (the off-by-default overhead contract); sampling
         # itself lives on the ledger's own daemon, never here
         _runledger.note_fit_step(self)
-        # sentinel judgment AFTER the step's own forensics recorded it:
-        # an anomalous step stays visible in the flight recorder even
-        # though its update is about to be discarded. May raise
-        # RollbackSignal (answered by _run_fit) or TrainingDivergedError.
+        # sentinel judgment AFTER the step's own forensics recorded it.
+        # May raise RollbackSignal (answered by _run_fit) or
+        # TrainingDivergedError.
         if pre is not None:
             _sentinel.post_step(self, pre, batches)
         hb = self._fit_heartbeat
         if hb is not None:
             hb.beat()
+        return sampled
 
     def _ds_examples(self, ds) -> int:
         """Example count for `fit_examples_total`. Only structural
@@ -1086,6 +1108,7 @@ class NetworkBase:
                 "iterator_state": self._capture_iterator_state(iterator),
             }
             t_etl = time.perf_counter()
+            self._fit_t_mark = _tracing.now_ns()
             buf, sig = [], None
             # data-wait accumulates across buffered (fused) batches so a
             # fused dispatch's histogram entry covers ALL the iterator

@@ -239,6 +239,7 @@ def _mm_stats_call(x2, w2):
             jax.ShapeDtypeStruct((1, cout), acc),
             jax.ShapeDtypeStruct((1, cout), acc),
         ],
+        name="conv_bn_stats",
         interpret=_interpret(),
     )(x2, w2)
     return y2, s1, s2
@@ -353,6 +354,7 @@ def _ck_stats_call(x, w, strides):
             jax.ShapeDtypeStruct((1, cout), acc),
             jax.ShapeDtypeStruct((1, cout), acc),
         ],
+        name="convk_bn_stats",
         interpret=_interpret(),
     )(x, w)
     return y, s1, s2
@@ -459,6 +461,7 @@ def _norm_call(x2, mean_b, scale, shift, relu):
         out_specs=pl.BlockSpec((tm, c), lambda t: (t, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, c), x2.dtype),
+        name="bn_apply_relu" if relu else "bn_apply",
         interpret=_interpret(),
     )(x2, mean_b, scale, shift)
 
@@ -522,6 +525,7 @@ def _bnb_reduce_call(g2, x2):
             jax.ShapeDtypeStruct((1, c), acc),
             jax.ShapeDtypeStruct((1, c), acc),
         ],
+        name="bn_bwd_reduce",
         interpret=_interpret(),
     )(g2, x2)
 
@@ -542,6 +546,7 @@ def _bnb_apply_call(g2, x2, c1, c3, c0):
         out_specs=pl.BlockSpec((tm, c), lambda t: (t, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, c), g2.dtype),
+        name="bn_bwd_apply",
         interpret=_interpret(),
     )(g2, x2, c1, c3, c0)
 

@@ -176,6 +176,7 @@ def _fwd_call(xg, rw, pI, pF, pO, h0, c0):
             pltpu.VMEM((B, H), jnp.float32),
             pltpu.VMEM((B, H), jnp.float32),
         ],
+        name="lstm_seq",
         interpret=_interpret(),
     )(xg, rw, pI[None, :], pF[None, :], pO[None, :], h0, c0)
     return y, acts, hprev, cprev
@@ -221,6 +222,7 @@ def _bwd_call(acts, hprev, cprev, rw, pI, pF, pO, dy, dcF):
             pltpu.VMEM((H, H4), jnp.float32),
             pltpu.VMEM((3, H), jnp.float32),
         ],
+        name="lstm_seq_bwd",
         interpret=_interpret(),
     )(acts, hprev, cprev, rw, pI[None, :], pF[None, :], pO[None, :],
       dy, dcF)
@@ -309,6 +311,7 @@ def lstm_step(xg, rw, pI, pF, pO, h0, c0):
         out_specs=[whole((B, H)), whole((B, H))],
         out_shape=[jax.ShapeDtypeStruct((B, H), dt),
                    jax.ShapeDtypeStruct((B, H), dt)],
+        name="lstm_step",
         interpret=_interpret(),
     )(xg, rw, pI[None, :], pF[None, :], pO[None, :], h0, c0)
 

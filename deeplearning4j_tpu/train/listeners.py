@@ -177,7 +177,7 @@ class TracingListener(IterationListener):
     listener SPI instead of an HTTP route.
 
     With tracing enabled, the fit loop itself emits the `fit/step` /
-    `fit/dispatch` / `fit/device_sync` spans (nn/netbase.py); this
+    `fit/dispatch` / `fit/observe` spans (nn/netbase.py); this
     listener adds an `iteration` instant per step (iteration number +
     batch size) and writes `jsonl_path` / `chrome_path` after each epoch
     so a killed run still leaves a trace artifact behind.
@@ -186,7 +186,7 @@ class TracingListener(IterationListener):
     state at each epoch end (pass restore_on_epoch_end=False to leave it
     on between/after epochs). Construction alone changes nothing — the
     tracing flag is process-global and flipping it permanently would
-    impose the per-step device sync on every OTHER net in the process."""
+    impose the spans' host work on every OTHER net in the process."""
 
     def __init__(self, jsonl_path: Optional[str] = None,
                  chrome_path: Optional[str] = None,

@@ -3,13 +3,14 @@ liveness layer (utils/health.py is the watchdog half).
 
 utils/tracing.py records spans only while tracing is ON, because spans
 cost a clock read and a ring append per section; a crashed process that
-never enabled tracing leaves nothing. The flight recorder borrows the
-same bounded-ring design but is ALWAYS on at fixed cost: the fit loop
-appends one small step record per dispatch (step index, score reference,
-per-phase timings), interesting events (compiles, helper fallbacks,
-health transitions) append markers, and every `metrics_every` steps a
-cheap scalar delta of the metrics registry is captured. Memory bound:
-three bounded deques, regardless of run length.
+never enabled tracing leaves nothing. The flight recorder is ALWAYS on at
+fixed cost. Its "final steps" are the newest records of the program's one
+per-step ring, tracing's step timeline (the fit loop appends one tuple a
+dispatch: step index, score reference, phase boundaries); interesting
+events (compiles, helper fallbacks, health transitions) append markers
+here, and every `metrics_every` steps a cheap scalar delta of the metrics
+registry is captured. Memory bound: two bounded deques beside the
+timeline, regardless of run length.
 
 Forensics surfaces:
 
@@ -51,6 +52,7 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from deeplearning4j_tpu.utils import metrics as _metrics
+from deeplearning4j_tpu.utils import tracing as _tracing
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
@@ -92,20 +94,26 @@ def thread_stacks() -> List[dict]:
 
 
 class FlightRecorder:
-    """Always-on bounded ring of step records + event markers + periodic
-    metrics deltas. `enabled=False` exists only for the overhead A/B
-    guard in tests — production never turns the black box off."""
+    """The newest `capacity` records of a step timeline + event markers +
+    periodic metrics deltas. The process's recorder reads the process's
+    timeline (`tracing.get_step_timeline()`); one built without a
+    `timeline` keeps a ring of its own. `enabled=False` exists only for
+    the overhead A/B guard in tests — production never turns the black
+    box off."""
 
     def __init__(self, capacity: int = 256, events_capacity: int = 256,
-                 metrics_every: int = 64):
+                 metrics_every: int = 64,
+                 timeline: Optional[_tracing.StepTimeline] = None):
         self.enabled = True
+        self.capacity = int(capacity)
+        self.timeline = (timeline if timeline is not None
+                         else _tracing.StepTimeline(self.capacity))
         self.metrics_every = max(1, int(metrics_every))
         # RLock, deliberately: the SIGTERM dump runs as a Python signal
         # handler on the main thread, which may be interrupted INSIDE a
-        # record_step() holding this lock — a plain Lock would deadlock
+        # note_step() holding this lock — a plain Lock would deadlock
         # the crash path at exactly the moment it exists for
         self._lock = threading.RLock()
-        self._steps: deque = deque(maxlen=int(capacity))
         self._events: deque = deque(maxlen=int(events_capacity))
         self._metrics_deltas: deque = deque(maxlen=32)
         self._step_count = 0
@@ -121,23 +129,30 @@ class FlightRecorder:
 
     # -- recording (hot path) ------------------------------------------------
 
-    def record_step(self, step: int, score=None, **phases):
-        """One fit dispatch: a deque append of a small dict; every
-        `metrics_every`-th call also captures a registry scalar delta
+    def note_step(self):
+        """One fit dispatch went into the timeline: count it, and every
+        `metrics_every`-th call capture a registry scalar delta
         (counter/gauge values only — no histogram percentile work)."""
         if not self.enabled:
             return
-        rec = {"ts": round(time.time(), 3), "step": int(step),
-               "score": score}
-        for k, v in phases.items():
-            if v is not None:
-                rec[k] = round(float(v), 6)
         with self._lock:
-            self._steps.append(rec)
             self._step_count += 1
             snap_due = self._step_count % self.metrics_every == 0
         if snap_due:
             self.record_metrics_delta()
+
+    def record_step(self, step: int, score=None, data_wait: float = 0.0,
+                    dispatch: float = 0.0):
+        """A dispatch known by its durations alone (seconds), ending now:
+        one timeline record and `note_step`. The fit loop, which has the
+        boundaries, appends its record itself."""
+        if not self.enabled:
+            return
+        end = _tracing.now_ns()
+        d0 = end - int(dispatch * 1e9)
+        self.timeline.append((int(step), 1, d0 - int(data_wait * 1e9), d0,
+                              end, end, 0, 0, 0, 0, score))
+        self.note_step()
 
     def record_event(self, kind: str, **fields):
         if not self.enabled:
@@ -151,8 +166,6 @@ class FlightRecorder:
         # box must record even if tracing misbehaves)
         if "trace_id" not in ev:
             try:
-                from deeplearning4j_tpu.utils import tracing as _tracing
-
                 tid = _tracing.current_trace_id()
                 if tid is not None:
                     ev["trace_id"] = tid
@@ -214,12 +227,15 @@ class FlightRecorder:
         steps (scores resolved non-blockingly), events, metrics deltas,
         component health, and all thread stacks."""
         with self._lock:
-            steps = [dict(r) for r in self._steps]
             events = [dict(e) for e in self._events]
             deltas = [dict(d) for d in self._metrics_deltas]
             step_count = self._step_count
-        for r in steps:
-            r["score"] = _resolve_score(r.get("score"))
+        steps = [{"ts": round(end * 1e-9, 3), "step": it,
+                  "score": _resolve_score(score),
+                  "data_wait": round((d0 - w0) * 1e-9, 6),
+                  "dispatch": round((d1 - d0) * 1e-9, 6)}
+                 for (it, _, w0, d0, d1, end, _, _, _, _, score)
+                 in self.timeline.records()[-self.capacity:]]
         try:
             from deeplearning4j_tpu.utils.health import get_health
 
@@ -299,7 +315,7 @@ class FlightRecorder:
 
 # -- the process-global recorder ---------------------------------------------
 
-_RECORDER = FlightRecorder()
+_RECORDER = FlightRecorder(timeline=_tracing.get_step_timeline())
 
 
 def get_recorder() -> FlightRecorder:
@@ -403,16 +419,14 @@ def render_dump(doc: dict, max_steps: int = 32,
         lines.append("")
         lines.append(f"final {min(len(steps), max_steps)} steps "
                      "(ms; score 'pending' = dispatched, never completed):")
-        lines.append("      step       score  data_wait   dispatch"
-                     "       sync")
+        lines.append("      step       score  data_wait   dispatch")
         for rec in steps[-max_steps:]:
             score = rec.get("score")
             s = (f"{score:11.6g}" if isinstance(score, (int, float))
                  else f"{score or '':>11}")
             lines.append(
                 f"  {rec.get('step', '?'):>8} {s} "
-                f"{_fmt_ms(rec, 'data_wait')}  {_fmt_ms(rec, 'dispatch')}  "
-                f"{_fmt_ms(rec, 'sync')}")
+                f"{_fmt_ms(rec, 'data_wait')}  {_fmt_ms(rec, 'dispatch')}")
     events = doc.get("events") or []
     if events:
         lines.append("")

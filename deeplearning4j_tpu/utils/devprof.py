@@ -47,6 +47,7 @@ from typing import List, Optional
 from deeplearning4j_tpu.utils import blackbox as _blackbox
 from deeplearning4j_tpu.utils import metrics as _metrics
 from deeplearning4j_tpu.utils import resourcemeter as _resourcemeter
+from deeplearning4j_tpu.utils.tracing import now_ns as _now_ns
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
@@ -149,19 +150,21 @@ class DeviceProfiler:
 
     # -- the fit-loop hook ---------------------------------------------------
 
-    def on_step(self, net, n_examples: int, score) -> None:
+    def on_step(self, net, n_examples: int, score):
         """Called by netbase._timed_fit after every dispatch. Unsampled
         steps: two integer adds and a modulo — the fixed cost the
-        overhead A/B test pins <1% of the fit loop."""
+        overhead A/B test pins <1% of the fit loop. On a sampled step,
+        returns the `(t0, t1)` of the blocking read on `tracing.now_ns()`
+        for the step timeline; else None."""
         se = self.sample_every
         if se <= 0:
-            return
+            return None
         st = self._state(net)
         st["dispatches"] += 1
         st["examples"] += n_examples
         if st["dispatches"] % se:
-            return
-        self._sample(net, st, score)
+            return None
+        return self._sample(net, st, score)
 
     def sample_now(self, net, score=None) -> None:
         """Force one sample outside the cadence (tests; end-of-fit)."""
@@ -178,13 +181,16 @@ class DeviceProfiler:
             }
         return st
 
-    def _sample(self, net, st: dict, score) -> None:
+    def _sample(self, net, st: dict, score):
         ins = self._instruments()
-        try:
-            if score is not None:
+        blocked = None
+        if score is not None:
+            t0 = _now_ns()
+            try:
                 _jax().block_until_ready(score)
-        except Exception:
-            pass  # a failed sync is the step's problem, not the sampler's
+            except Exception:
+                pass  # a failed sync is the step's problem, not ours
+            blocked = (t0, _now_ns())
         now = time.perf_counter()
         last = st["last_t"]
         iteration = int(getattr(net, "iteration", 0))
@@ -225,6 +231,7 @@ class DeviceProfiler:
             # module-global read when the process is unmetered.
             _resourcemeter.note_device_window(net, dt,
                                               examples=window_examples)
+        return blocked
 
     # -- memory watermarks ---------------------------------------------------
 

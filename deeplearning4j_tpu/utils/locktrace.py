@@ -531,7 +531,7 @@ def _traced_create_connection(*args, **kwargs):
 def _traced_block_until_ready(x):
     st = _STATE
     if st is not None:
-        _note_blocking_impl(st, "device_sync", None, 2)
+        _note_blocking_impl(st, "device.sync", None, 2)
     return _ORIG["block_until_ready"](x)
 
 

@@ -25,12 +25,29 @@ serving process never grows without bound) and export two ways:
   chrome://tracing / Perfetto and the host timeline sits next to the
   device xplane timeline captured by utils/profiler.py.
 
+The clock: every timestamp here is `now_ns()` — integer nanoseconds
+since the UNIX epoch, advanced by `time.perf_counter_ns()` from one anchor
+taken at import. It is monotonic, and it is the clock the profiler's
+xplane lines and events carry, so a span and a device event can be laid
+side by side without a conversion. The chrome and JSONL exports derive
+their microseconds (`ts`, `dur`) from the stored `start_ns` / `dur_ns`.
+
 Device correlation: when enabled, each span also enters
 `jax.profiler.TraceAnnotation(name)`, so the SAME names show up inside a
 `jax.profiler.trace()` capture — `cli profile` op tables and host spans
-line up by name.
+line up by name. Turning the tracer on adds host work only: no span
+blocks on the device.
 
-Overhead contract: tracing is OFF by default and every propagation entry
+The step timeline (`StepTimeline`, `step_timeline()`) is the one per-step
+ring of the program and is ALWAYS on: the fit loop appends one fixed tuple
+a dispatch — the four phase boundaries on `now_ns()`, the interval of
+devprof's sampled blocking read, and the fit thread's CPU time in the
+dispatch and in the observers — with no lock, no dict and no rounding.
+The flight recorder (utils/blackbox) builds its "final steps" from it, and
+the benchmark attributes device idle gaps to the fit thread's phases
+with it.
+
+Overhead contract: span recording is OFF by default and every propagation entry
 point — `span()`, `instant()`, `attach()`/`detach()`,
 `current_context()`, `current_traceparent()`, `record_complete()` —
 degrades to one flag check on the disabled path: no allocation, no lock,
@@ -60,6 +77,28 @@ _counter = itertools.count(
 _tls = threading.local()
 
 _SPAN_ID_MASK = (1 << 64) - 1
+
+# the one clock of the program's spans: UNIX-epoch nanoseconds at import,
+# advanced by the monotonic counter. The anchor is the midpoint of two
+# counter reads around the wall-clock read, so it is off by at most half
+# of that read's duration.
+_p0 = time.perf_counter_ns()
+_UNIX_ANCHOR_NS = time.time_ns()
+_PERF_ANCHOR_NS = (_p0 + time.perf_counter_ns()) // 2
+del _p0
+
+
+def now_ns() -> int:
+    """Nanoseconds since the UNIX epoch, monotonic within the process:
+    the clock of every span, timeline record and export of this module,
+    and of the profiler's xplane."""
+    return _UNIX_ANCHOR_NS + (time.perf_counter_ns() - _PERF_ANCHOR_NS)
+
+
+def perf_to_ns(t: float) -> int:
+    """A `time.perf_counter()` reading (seconds) on the `now_ns()` clock."""
+    return _UNIX_ANCHOR_NS + (int(t * 1e9) - _PERF_ANCHOR_NS)
+
 
 # attach() on the disabled path returns this token; detach() recognizes
 # it and does nothing — the pair stays one flag check when tracing is off
@@ -161,7 +200,7 @@ class _Span:
         self.id = next(_counter)
         self.parent = None
         self.trace = None
-        self.t0 = 0.0
+        self.t0 = 0
         self._ann = None
 
     @property
@@ -194,11 +233,11 @@ class _Span:
             if ann is not None:
                 self._ann = ann
                 ann.__enter__()
-        self.t0 = time.perf_counter()
+        self.t0 = now_ns()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        t1 = now_ns()
         if self._ann is not None:
             self._ann.__exit__(*exc)
         stack = getattr(_tls, "stack", None)
@@ -231,9 +270,6 @@ class Tracer:
         self.annotate_device = annotate_device
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=int(capacity))
-        # perf_counter origin so exported timestamps are relative to
-        # tracer creation (chrome trace wants microseconds, any epoch)
-        self._epoch = time.perf_counter()
 
     # -- recording -----------------------------------------------------------
 
@@ -266,7 +302,7 @@ class Tracer:
                 parent, trace = att.span_id, att.trace_id
             else:
                 parent, trace = None, _mint_trace_id()
-        self._record(name, time.perf_counter(), 0.0, next(_counter),
+        self._record(name, now_ns(), 0, next(_counter),
                      parent, args or None, phase="i", trace=trace)
 
     def record_complete(self, name: str, t0: float, t1: float,
@@ -283,18 +319,19 @@ class Tracer:
             return None
         sid = next(_counter)
         trace = parent.trace_id if parent is not None else _mint_trace_id()
-        self._record(name, t0, t1 - t0, sid,
+        start_ns = perf_to_ns(t0)
+        self._record(name, start_ns, perf_to_ns(t1) - start_ns, sid,
                      parent.span_id if parent is not None else None,
                      args or None, trace=trace)
         return SpanContext(trace, sid)
 
-    def _record(self, name, t0, dur, span_id, parent, args, phase="X",
-                trace=None):
+    def _record(self, name, start_ns, dur_ns, span_id, parent, args,
+                phase="X", trace=None):
         ev = {
             "name": name,
             "ph": phase,
-            "ts": round((t0 - self._epoch) * 1e6, 3),  # microseconds
-            "dur": round(dur * 1e6, 3),
+            "start_ns": start_ns,
+            "dur_ns": dur_ns,
             "id": span_id,
             "parent": parent,
             "trace": trace,
@@ -313,10 +350,13 @@ class Tracer:
         newest n')."""
         with self._lock:
             evs = list(self._events)
-        if n is None:
-            return evs
-        n = int(n)
-        return evs[-n:] if n > 0 else []
+        if n is not None:
+            n = int(n)
+            evs = evs[-n:] if n > 0 else []
+        # the exports' microseconds (chrome trace, JSONL, cli trace),
+        # derived here and never stored
+        return [dict(ev, ts=ev["start_ns"] / 1e3, dur=ev["dur_ns"] / 1e3)
+                for ev in evs]
 
     def clear(self):
         with self._lock:
@@ -361,9 +401,78 @@ class Tracer:
         return path
 
 
-# -- the process-global tracer ------------------------------------------------
+# -- the step timeline ---------------------------------------------------------
+
+class StepTimeline:
+    """The always-on ring of fit dispatches: fixed tuples
+
+        (iteration, n_steps, t_wait0, t_dispatch0, t_dispatch1, t_end,
+         sync0, sync1, cpu_dispatch_ns, cpu_observe_ns, score_ref)
+
+    `iteration` is the last optimizer step of the dispatch and `n_steps`
+    how many it ran (a fused dispatch runs several). The four boundaries
+    are on `now_ns()`: `t_wait0` is the previous dispatch's `t_end` (or
+    the epoch's start), so data wait, dispatch and observe tile the fit
+    thread's time with no hole. `sync0, sync1` is the interval of devprof's
+    blocking read when this dispatch was sampled, else 0, 0. The two CPU
+    times are `time.thread_time_ns()` of the fit thread inside the
+    dispatch and inside the observers. `score_ref` is the step's score as
+    the device array it is: never read here.
+
+    `append` is the deque's own: one call a dispatch, no lock (one writer,
+    and copying a deque is atomic under the interpreter lock). 4096 records
+    hold a traced second and the 1.5 s after it down to a 0.6 ms step."""
+
+    def __init__(self, capacity: int = 4096):
+        self._ring: deque = deque(maxlen=int(capacity))
+        self.append = self._ring.append
+
+    def records(self, since_ns: Optional[int] = None) -> List[tuple]:
+        """The tuples, oldest first; with `since_ns`, those that ended
+        at or after it."""
+        recs = list(self._ring)
+        if since_ns is None:
+            return recs
+        return [r for r in recs if r[5] >= since_ns]
+
+    def spans(self, since_ns: Optional[int] = None) -> List[dict]:
+        """The records as spans: a `fit/step` for each dispatch, whose
+        identifier `step` is the iteration, with the children
+        `fit/data_wait`, `fit/dispatch`, `fit/observe` and, where the
+        dispatch was sampled, `devprof/sample` under `fit/observe`.
+        `parent` names the parent span of the same `step`; `cpu_ns` is
+        the fit thread's CPU time where it was taken."""
+        out = []
+
+        def span(name, step, start, stop, parent, cpu_ns=None):
+            out.append({"name": name, "start_ns": start, "end_ns": stop,
+                        "parent": parent, "step": step, "cpu_ns": cpu_ns})
+
+        for (it, n, w0, d0, d1, end, s0, s1, cpu_d, cpu_o, _) in \
+                self.records(since_ns):
+            span("fit/step", it, w0, end, None, cpu_d + cpu_o)
+            out[-1]["n_steps"] = n
+            span("fit/data_wait", it, w0, d0, "fit/step")
+            span("fit/dispatch", it, d0, d1, "fit/step", cpu_d)
+            span("fit/observe", it, d1, end, "fit/step", cpu_o)
+            if s1 > s0:
+                span("devprof/sample", it, s0, s1, "fit/observe")
+        return out
+
+
+# -- the process-global tracer and timeline -----------------------------------
 
 _TRACER = Tracer()
+_STEPS = StepTimeline()
+
+
+def get_step_timeline() -> StepTimeline:
+    return _STEPS
+
+
+def step_timeline(since_ns: Optional[int] = None) -> List[dict]:
+    """The process's fit-phase timeline as spans (`StepTimeline.spans`)."""
+    return _STEPS.spans(since_ns)
 
 
 def get_tracer() -> Tracer:

@@ -58,6 +58,22 @@ def _xy(n=32, n_in=12, n_out=4, seed=0):
     return x, y
 
 
+def _count_blocking_reads(monkeypatch):
+    """Count every `jax.block_until_ready` the process makes from here
+    on (the fit loop's only way to wait for a step's score)."""
+    import jax
+
+    calls = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        calls.append(x)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    return calls
+
+
 # -- registry core -----------------------------------------------------------
 
 def test_counter_thread_safety():
@@ -210,7 +226,7 @@ def test_span_nesting_and_chrome_roundtrip(tmp_path):
         json.loads(line)
 
 
-def test_tracing_listener_writes_artifacts(tmp_path):
+def test_tracing_listener_writes_artifacts(tmp_path, monkeypatch):
     from deeplearning4j_tpu.train.listeners import TracingListener
 
     tracing.get_tracer().clear()
@@ -219,16 +235,27 @@ def test_tracing_listener_writes_artifacts(tmp_path):
     chrome = tmp_path / "spans.chrome.json"
     lst = TracingListener(jsonl_path=str(jsonl), chrome_path=str(chrome))
     # construction must NOT flip the process-global flag (that would
-    # impose the per-step device sync on every other net in the process)
+    # impose the spans' host work on every other net in the process)
     assert not tracing.is_enabled()
     net.set_listeners(lst)
     x, y = _xy(n=16)
+    blocking_reads = _count_blocking_reads(monkeypatch)
     net.fit(x, y, epochs=2, batch_size=8, async_prefetch=False)
     assert not tracing.is_enabled()  # restored
     lines = [json.loads(l) for l in jsonl.read_text().strip().splitlines()]
     names = {e["name"] for e in lines}
     assert "fit/step" in names and "iteration" in names
-    assert "fit/device_sync" in names  # tracing was on -> sync measured
+    # tracing was on: the observers' phase has its span, and no span
+    # waited for the device
+    assert {"fit/dispatch", "fit/observe"} <= names
+    assert blocking_reads == []
+    step = next(e for e in lines if e["name"] == "fit/step")
+    for e in lines:
+        if e["name"] in ("fit/dispatch", "fit/observe") \
+                and e["parent"] == step["id"]:
+            assert step["start_ns"] <= e["start_ns"]
+            assert e["start_ns"] + e["dur_ns"] \
+                <= step["start_ns"] + step["dur_ns"]
     # restore_on_epoch_end must NOT leave later epochs untraced: all 4
     # steps (2 epochs x 2 batches) recorded spans
     assert sum(e["name"] == "fit/step" for e in lines) == 4
@@ -394,8 +421,8 @@ def test_fit_hot_path_no_registry_lookups_when_disabled(monkeypatch):
     """The overhead guard, asserted structurally (iteration counts, not
     wall clock): with tracing disabled and no listeners, a fit's
     per-step path performs ZERO registry lookups (instruments resolve
-    once) and ZERO device syncs beyond the dispatch itself (the sync
-    histogram stays empty)."""
+    once) and ZERO blocking reads of a step's score — and turning the
+    tracer on adds none."""
     assert not tracing.is_enabled()
     reg = metrics_mod.get_registry()
     lookups = []
@@ -406,22 +433,27 @@ def test_fit_hot_path_no_registry_lookups_when_disabled(monkeypatch):
         return orig(self, name, *a, **k)
 
     net = MultiLayerNetwork(_mlp_conf()).init()
-    sync_before = reg.histogram("fit_device_sync_seconds").labels().count
+    blocking_reads = _count_blocking_reads(monkeypatch)
     x, y = _xy(n=200)
     monkeypatch.setattr(MetricsRegistry, "_get_or_create", counting)
     net.fit(x, y, epochs=1, batch_size=4, async_prefetch=False)  # 50 steps
     fit_lookups = [n for n in lookups if n.startswith("fit_")]
-    # instruments resolved at most once each (6 families as of the
-    # input-pipeline round: steps/examples/examples_unknown/data_wait/
-    # dispatch/sync), NOT once per 50 steps
-    assert len(fit_lookups) <= 6, fit_lookups
+    # instruments resolved at most once each (5 families: steps/
+    # examples/examples_unknown/data_wait/dispatch), NOT once per 50
+    # steps
+    assert len(fit_lookups) <= 5, fit_lookups
     # a second fit reuses the cached children: no new lookups at all
     lookups.clear()
     net.fit(x, y, epochs=1, batch_size=4, async_prefetch=False)
     assert [n for n in lookups if n.startswith("fit_")] == []
-    # tracing disabled -> the device-sync probe never ran
-    assert reg.histogram(
-        "fit_device_sync_seconds").labels().count == sync_before
+    # no step's score was waited for (tier-1 runs devprof's sampled
+    # read off), and the tracer turned on changes nothing about that
+    assert blocking_reads == []
+    tracing.enable(True)
+    net.fit(x, y, epochs=1, batch_size=4, async_prefetch=False)
+    tracing.enable(False)
+    assert blocking_reads == []
+    assert reg.get("fit_device_sync_seconds") is None
 
 
 def test_performance_listener_reports_window_etl():
@@ -736,3 +768,156 @@ def test_exposition_under_concurrent_registry_mutation():
         for t in threads:
             t.join(5)
     assert not errs
+
+
+# -- one clock, and the always-on step timeline --------------------------------
+
+def test_now_ns_is_monotonic_and_on_the_unix_epoch():
+    import time
+
+    reads = [tracing.now_ns() for _ in range(1000)]
+    assert all(isinstance(r, int) for r in reads)
+    assert all(b >= a for a, b in zip(reads, reads[1:]))
+    assert abs(tracing.now_ns() - time.time_ns()) < 1_000_000  # 1 ms
+    # a perf_counter reading lands on the same clock (record_complete's
+    # callers hand those in)
+    assert abs(tracing.perf_to_ns(time.perf_counter())
+               - tracing.now_ns()) < 1_000_000
+
+
+def test_spans_are_stored_on_the_shared_clock():
+    import time
+
+    tracer = tracing.get_tracer()
+    tracer.clear()
+    tracing.enable(True)
+    before = tracing.now_ns()
+    with tracing.span("on_the_clock"):
+        pass
+    t0 = time.perf_counter()
+    tracing.record_complete("handed_in", t0, t0 + 0.25)
+    tracing.instant("mark")
+    after = tracing.now_ns()
+    tracing.enable(False)
+    evs = {e["name"]: e for e in tracer.recent()}
+    for ev in evs.values():
+        assert isinstance(ev["start_ns"], int)
+        assert isinstance(ev["dur_ns"], int)
+        assert before <= ev["start_ns"] <= after
+        # the exports' microseconds are derived from the nanoseconds
+        assert ev["ts"] == ev["start_ns"] / 1e3
+        assert ev["dur"] == ev["dur_ns"] / 1e3
+    assert evs["handed_in"]["dur_ns"] == pytest.approx(250e6, abs=2)
+    assert evs["mark"]["dur_ns"] == 0
+    line = json.loads(tracer.to_jsonl().splitlines()[0])
+    assert {"start_ns", "dur_ns", "ts", "dur"} <= set(line)
+
+
+def _fit_records(net, x, y, **fit_args):
+    t0 = tracing.now_ns()
+    net.fit(x, y, async_prefetch=False, **fit_args)
+    return tracing.get_step_timeline().records(since_ns=t0), t0
+
+
+def test_step_timeline_phases_tile_the_fit_threads_time():
+    net = MultiLayerNetwork(_mlp_conf()).init()
+    x, y = _xy(n=40)
+    records, t0 = _fit_records(net, x, y, epochs=2, batch_size=10)
+    assert len(records) == 8
+    assert [r[0] for r in records] == list(range(8))  # the iteration
+    for (it, n, w0, d0, d1, end, s0, s1, cpu_d, cpu_o, score) in records:
+        assert n == 1 and t0 <= w0 <= d0 <= d1 <= end
+        assert (s0, s1) == (0, 0)          # tier-1: devprof's read is off
+        assert 0 <= cpu_d <= (d1 - d0) + 1_000_000
+        assert 0 <= cpu_o <= (end - d1) + 1_000_000
+        assert score is not None
+    # within an epoch a record starts where the one before it ended
+    for a, b in zip(records, records[1:]):
+        if b[0] % 4:
+            assert b[2] == a[5]
+        else:                               # the next epoch's first
+            assert b[2] >= a[5]
+    spans = tracing.step_timeline(since_ns=t0)
+    steps = [s for s in spans if s["name"] == "fit/step"]
+    assert [s["step"] for s in steps] == list(range(8))
+    for step in steps:
+        kids = [s for s in spans if s["step"] == step["step"]
+                and s["parent"] == "fit/step"]
+        assert [k["name"] for k in kids] == [
+            "fit/data_wait", "fit/dispatch", "fit/observe"]
+        assert kids[0]["start_ns"] == step["start_ns"]
+        assert kids[-1]["end_ns"] == step["end_ns"]
+        for a, b in zip(kids, kids[1:]):
+            assert a["end_ns"] == b["start_ns"]
+        assert step["cpu_ns"] == kids[1]["cpu_ns"] + kids[2]["cpu_ns"]
+
+
+def test_step_timeline_records_devprofs_blocking_read(monkeypatch):
+    from deeplearning4j_tpu.utils import devprof
+
+    profiler = devprof.get_profiler()
+    monkeypatch.setattr(profiler, "sample_every", 2)
+    blocking_reads = _count_blocking_reads(monkeypatch)
+    net = MultiLayerNetwork(_mlp_conf()).init()
+    x, y = _xy(n=40)
+    records, t0 = _fit_records(net, x, y, epochs=1, batch_size=10)
+    sampled = [r for r in records if r[7] > r[6]]
+    assert len(sampled) == len(blocking_reads) == 2   # steps 2 and 4 of 4
+    for (_, _, _, _, d1, end, s0, s1, *_rest) in sampled:
+        assert d1 <= s0 <= s1 <= end   # inside the observers' phase
+    spans = tracing.step_timeline(since_ns=t0)
+    samples = [s for s in spans if s["name"] == "devprof/sample"]
+    assert len(samples) == 2
+    assert all(s["parent"] == "fit/observe" for s in samples)
+
+
+def test_step_timeline_hot_path_cost_is_microseconds():
+    """What a dispatch pays for the timeline: three clock reads, three
+    reads of the thread's CPU time, one tuple, one append."""
+    import time
+
+    timeline = tracing.StepTimeline()
+    now_ns, cpu_ns, append = tracing.now_ns, time.thread_time_ns, \
+        timeline.append
+    n = 20_000
+    t0 = time.perf_counter()
+    for i in range(n):
+        a, c0 = now_ns(), cpu_ns()
+        b, c1 = now_ns(), cpu_ns()
+        end = now_ns()
+        append((i, 1, a, a, b, end, 0, 0, c1 - c0, cpu_ns() - c1, None))
+    per_dispatch = (time.perf_counter() - t0) / n
+    assert per_dispatch < 10e-6, f"{per_dispatch * 1e6:.2f}us a dispatch"
+    assert len(timeline.records()) == 4096   # bounded
+
+
+def test_flight_recorder_reads_the_one_ring(tmp_path):
+    """The process's recorder keeps no step ring of its own: its final
+    steps are the timeline's newest records, under the dump's old keys."""
+    from deeplearning4j_tpu.utils import blackbox
+
+    rec = blackbox.get_recorder()
+    assert rec.timeline is tracing.get_step_timeline()
+    from collections import deque
+
+    assert sorted(k for k, v in vars(rec).items()
+                  if isinstance(v, deque)) == ["_events", "_metrics_deltas"]
+    net = MultiLayerNetwork(_mlp_conf()).init()
+    x, y = _xy(n=30)
+    records, _ = _fit_records(net, x, y, epochs=1, batch_size=10)
+    with open(rec.dump(str(tmp_path / "bb.json"), reason="keys")) as f:
+        doc = json.load(f)
+    last = doc["steps"][-3:]
+    assert [r["step"] for r in last] == [0, 1, 2]
+    for got, (_, _, w0, d0, d1, end, *_rest) in zip(last, records):
+        assert set(got) == {"ts", "step", "score", "data_wait", "dispatch"}
+        assert got["data_wait"] == pytest.approx((d0 - w0) * 1e-9, abs=1e-6)
+        assert got["dispatch"] == pytest.approx((d1 - d0) * 1e-9, abs=1e-6)
+        assert got["ts"] == pytest.approx(end * 1e-9, abs=1e-3)
+        # resolved without a blocking read: a step still in flight
+        # reads "pending"
+        assert isinstance(got["score"], float) or got["score"] == "pending"
+    assert len(doc["steps"]) <= 256
+    text = blackbox.render_dump(doc)
+    assert "data_wait   dispatch" in text and "sync" not in text.split(
+        "events")[0]
