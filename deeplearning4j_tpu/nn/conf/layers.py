@@ -236,7 +236,14 @@ class Convolution1DLayer(FeedForwardLayerConf):
 @register_config("layer.subsampling")
 @dataclasses.dataclass(kw_only=True)
 class SubsamplingLayer(LayerConf):
-    """2D pooling (reference: SubsamplingLayer.java; XLA reduce_window)."""
+    """2D pooling (reference: SubsamplingLayer.java; XLA reduce_window).
+
+    A MAX pool whose windows tile its input (kernel == stride, nothing
+    padded or cut off, a floating 4-D input) takes its gradient from an
+    int8 argmax saved in the forward pass instead of XLA's
+    select-and-scatter; every other pool differentiates reduce_window
+    (nn/layers/conv._pool chooses from this configuration and the input's
+    shape and dtype)."""
 
     pooling_type: str = PoolingType.MAX
     kernel_size: Sequence[int] = (2, 2)

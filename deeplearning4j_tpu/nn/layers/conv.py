@@ -9,11 +9,23 @@ kernels can still override via ops/ when profiling says so.
 
 Pooling: SubsamplingLayer (max/avg/sum/pnorm) -> lax.reduce_window
 (reference: nn/layers/convolution/subsampling/SubsamplingLayer.java,
-CudnnSubsamplingHelper). Gradients come from autodiff, which XLA rewrites
-to the select-and-scatter form itself.
+CudnnSubsamplingHelper). Gradients come from autodiff, which for a max
+pool is XLA's select-and-scatter: an op that fuses with nothing and reads
+the pool's whole input again. A max pool whose windows tile its input
+(`_argmax_pool_applies`) instead saves each window's argmax as int8 in
+the forward pass and selects on it in the backward pass: one elementwise
+sweep over (index, pooled gradient). Where such a pool's input is the
+ReLU of a convolution in the same trace, the ReLU moves behind the pool
+(`relu(max(z)) == max(relu(z))`, values and gradients alike), so its
+mask and the conv's bias-gradient sum work on the pooled tensor and the
+conv writes one tensor, not two. `pool_lowering_total{kind}` counts, per
+trace, which backward a pool took.
 """
 
 from __future__ import annotations
+
+import functools
+from collections import deque
 
 import jax
 import jax.numpy as jnp
@@ -26,8 +38,16 @@ from deeplearning4j_tpu.nn.layers.registry import LayerContext, register_layer
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.activations import apply_activation
 from deeplearning4j_tpu.ops.helpers import HelperError, get_helper
+from deeplearning4j_tpu.ops.pallas_conv_bn import _stash_pop
+from deeplearning4j_tpu.utils import metrics as _metrics
 
 _DIMS2D = ("NHWC", "HWIO", "NHWC")
+
+# (relu(z), z) of the convs of a training trace, matched by `is` like the
+# conv->BN stashes of ops/pallas_conv_bn: a tiling max pool that finds its
+# input here pools z and applies the ReLU to the pooled tensor. Bounded:
+# a conv whose consumer is no such pool ages out.
+_PREACT_STASH: deque = deque(maxlen=8)
 
 
 def _padding_2d(conf) -> object:
@@ -91,7 +111,10 @@ def conv_forward(conf: L.ConvolutionLayer, params, x, ctx: LayerContext):
         )
     if conf.has_bias:
         z = z + params["b"].astype(z.dtype)
-    return apply_activation(conf.activation, z, key=ctx.rng, training=ctx.training), None
+    a = apply_activation(conf.activation, z, key=ctx.rng, training=ctx.training)
+    if ctx.training and conf.activation.lower() == "relu":
+        _PREACT_STASH.append((a, z))
+    return a, None
 
 
 def conv_order(conf):
@@ -139,9 +162,97 @@ register_layer(L.Convolution1DLayer, conv1d_init, conv1d_forward, order_fn=conv_
 
 # -- pooling -----------------------------------------------------------------
 
+def _argmax_pool_applies(x, window, strides, padding) -> bool:
+    """True where a window's argmax names exactly one input element per
+    output element: a 4-D floating input tiled by its windows (stride ==
+    window, nothing padded, nothing cut off), the position fitting int8."""
+    if x.ndim != 4 or not jnp.issubdtype(x.dtype, jnp.floating):
+        return False
+    if tuple(window) != tuple(strides) or window[0] != 1 or window[3] != 1:
+        return False
+    if isinstance(padding, str):
+        padding = lax.padtype_to_pads(x.shape, window, strides, padding)
+    if any(lo or hi for lo, hi in padding):
+        return False
+    kh, kw = window[1], window[2]
+    return x.shape[1] % kh == 0 and x.shape[2] % kw == 0 and kh * kw <= 127
+
+
+def _window_position(view, kw):
+    """int8 row-major position of each element in its window, over the
+    6-D (N, H/kh, kh, W/kw, kw, C) view."""
+    return (lax.broadcasted_iota(jnp.int8, view, 2) * jnp.int8(kw)
+            + lax.broadcasted_iota(jnp.int8, view, 4))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _max_pool_tiled(x, kh, kw):
+    """Max pool over kh x kw windows that tile x. With no gradient asked
+    this is the plain reduce_window, so inference programs do not change."""
+    window = (1, kh, kw, 1)
+    return lax.reduce_window(x, -jnp.inf, lax.max, window, window, "VALID")
+
+
+def _max_pool_tiled_fwd(x, kh, kw):
+    n, h, w, c = x.shape
+    view = (n, h // kh, kh, w // kw, kw, c)
+
+    def larger(a, b):
+        # the larger value (NaN counts as largest, as in lax.max); among
+        # equals the smaller position: select_and_scatter's `ge` chooser
+        # keeps the first maximum in row-major window order
+        (av, ai), (bv, bi) = a, b
+        a_val = (av > bv) | (av != av)
+        a_idx = a_val | ((av == bv) & (ai < bi))
+        return lax.select(a_val, av, bv), lax.select(a_idx, ai, bi)
+
+    y, idx = lax.reduce(
+        (x.reshape(view), _window_position(view, kw)),
+        (jnp.array(-jnp.inf, x.dtype), jnp.array(127, jnp.int8)),
+        larger, (2, 4))
+    return y, idx
+
+
+def _max_pool_tiled_bwd(kh, kw, idx, g):
+    n, ho, wo, c = g.shape
+    view = (n, ho, kh, wo, kw, c)
+    hit = _window_position(view, kw) == idx[:, :, None, :, None, :]
+    gx = jnp.where(hit, g[:, :, None, :, None, :], jnp.zeros((), g.dtype))
+    # The TPU compiler fuses no broadcast across the reshape below: left to
+    # itself it clones this select into every 4-D consumer and writes the
+    # broadcasts of idx and g out in full first (1.5x the bytes of gx).
+    # Behind the barrier the select is one 6-D fusion that reads idx and g
+    # and writes gx, and the consumers read gx.
+    gx = lax.optimization_barrier(gx)
+    return (gx.reshape(n, ho * kh, wo * kw, c),)
+
+
+_max_pool_tiled.defvjp(_max_pool_tiled_fwd, _max_pool_tiled_bwd)
+
+
+def _count_pool_lowering(kind: str) -> None:
+    """Trace-time, like ops/helpers._count: one event per pool per trace."""
+    _metrics.get_registry().counter(
+        "pool_lowering_total", "Pooling layers traced, by backward lowering",
+        ("kind",)).labels(kind).inc()
+
+
 def _pool(x, pooling_type, window, strides, padding, pnorm):
     """reduce_window pooling over explicitly-windowed axes. window/strides
     are full-rank tuples (1s for batch/channel)."""
+    if (pooling_type == PoolingType.MAX
+            and _argmax_pool_applies(x, window, strides, padding)):
+        _count_pool_lowering("argmax_vjp")
+        kh, kw = window[1], window[2]
+        conv = _stash_pop(_PREACT_STASH, x)
+        if conv is None:
+            return _max_pool_tiled(x, kh, kw)
+        # x = relu(z): the max commutes with a non-decreasing map, and the
+        # gradient lands on the same element (the first maximum of z is the
+        # first maximum of relu(z) wherever it is positive, and where it is
+        # not both forms give zero). relu(z) itself is then dead code.
+        return apply_activation("relu", _max_pool_tiled(conv[1], kh, kw))
+    _count_pool_lowering("reduce_window")
     if pooling_type == PoolingType.MAX:
         neg_inf = -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).min
         return lax.reduce_window(x, neg_inf, lax.max, window, strides, padding)

@@ -9,12 +9,19 @@ the three LSTM kernels at the char-LSTM width must compile, forward and
 backward; every shape the compiler refuses must be "unsupported" in
 `conv_decision` by shape. Interpret-mode tests cannot see any of this.
 
+The last section holds the XLA side of the step program to the compiler
+the same way: what `nn/layers/conv._pool` counts on (a max pool's backward
+as one elementwise fusion, no select-and-scatter, nothing full-size
+written twice) is a property of the chip's fusion pass, and only a
+compile for the chip shows it.
+
 This is the only test file that describes the chip, and it does so inside
 a module-scoped fixture: only one process may load the TPU library, so a
 call at import or collection time would break the other xdist workers.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -281,3 +288,88 @@ def test_refused_shape_is_unsupported_by_shape(case, one_chip):
     assert any(s in str(err.value) for s in (
         "extract_strided_slice", "unsupported shape cast",
         "exceeded scoped vmem limit")), str(err.value)[:400]
+
+
+# -- the max pool's backward pass as the chip's compiler fuses it --------------
+
+def _vgg_front_hlo(one_chip, pool):
+    """Optimized HLO of an SGD step over VGG16's first three convs
+    (3 -> 64 -> 64 -> pool -> 128 -> pool) at batch 128, 224x224, bf16,
+    through the layers' own forward functions."""
+    from deeplearning4j_tpu.nn.conf import layers as L
+    from deeplearning4j_tpu.nn.layers import conv as C
+    from deeplearning4j_tpu.nn.layers.registry import LayerContext
+
+    ctx = LayerContext(training=True)
+    convs = [L.ConvolutionLayer(n_in=i, n_out=o, kernel_size=(3, 3),
+                                convolution_mode="same", activation="relu")
+             for i, o in ((3, 64), (64, 64), (64, 128))]
+
+    def loss(params, x):
+        for i, (conf, p) in enumerate(zip(convs, params)):
+            x = C.conv_forward(conf, p, x, ctx)[0]
+            if i > 0:
+                x = C.subsampling_forward(pool, {}, x, ctx)[0]
+        return jnp.mean(x.astype(jnp.float32) ** 2)
+
+    def step(params, x):
+        grads = jax.grad(loss)(params, x)
+        return jax.tree.map(lambda p, g: p - 0.1 * g.astype(p.dtype),
+                            params, grads)
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    params = [{"W": sds(3, 3, c.n_in, c.n_out), "b": sds(c.n_out)}
+              for c in convs]
+    return jax.jit(step).lower(params, sds(BATCH, 224, 224, 3)) \
+        .compile().as_text()
+
+
+def _entry_ops(hlo):
+    """(name, result type, opcode, operands) of the entry computation."""
+    entry = hlo[hlo.index("ENTRY"):]
+    ops = re.findall(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*?) ([\w\-]+)\((.*?)\)(?:, |$)",
+        entry, re.M)
+    return [(name, result, op, [a.strip() for a in args.split(",")])
+            for name, result, op, args in ops]
+
+
+def test_tiling_max_pool_backward_is_one_select_fusion(one_chip):
+    from deeplearning4j_tpu.nn.conf import layers as L
+
+    ops = _entry_ops(_vgg_front_hlo(one_chip, L.SubsamplingLayer()))
+    types = {name: result for name, result, _, _ in ops}
+    assert not any(op == "select-and-scatter" for _, _, op, _ in ops)
+    # nothing but a fusion writes a tensor of the pools' input sizes: no
+    # broadcast, copy or transpose of the window view or the 4-D tensor
+    full = re.compile(r"\[128,(224,224,64|112,2,112,2,64"
+                      r"|112,112,128|56,2,56,2,128)\]")
+    writers = {op for _, result, op, _ in ops if full.search(result)}
+    assert writers <= {"fusion", "bitcast", "get-tuple-element"}, writers
+    for view, pooled in (("[128,112,2,112,2,64]", "[128,112,112,64]"),
+                         ("[128,56,2,56,2,128]", "[128,56,56,128]")):
+        selects = [(name, args) for name, result, op, args in ops
+                   if op == "fusion" and result.startswith("bf16" + view)]
+        # one fusion writes the pool's input gradient, in the window view,
+        # from the int8 index and the pooled gradient alone
+        assert len(selects) == 1, selects
+        read = sorted(types[a].split("{")[0] for a in selects[0][1]
+                      if "[128," in types.get(a, ""))
+        assert read == ["bf16" + pooled, "s8" + pooled], read
+    # the convs in front of the pools write one tensor, the pre-activation:
+    # the ReLU works behind the pool
+    for name, result, op, _ in ops:
+        assert not re.match(
+            r"\(bf16\[128,224,224,64\]\S*, bf16\[128,224,224,64\]", result), name
+
+
+def test_overlapping_max_pool_keeps_select_and_scatter(one_chip):
+    from deeplearning4j_tpu.nn.conf import layers as L
+
+    pool = L.SubsamplingLayer(kernel_size=(3, 3), stride=(2, 2),
+                              convolution_mode="same")
+    ops = _entry_ops(_vgg_front_hlo(one_chip, pool))
+    assert sum(op == "select-and-scatter" for _, _, op, _ in ops) == 2
+    assert not any("s8[128," in result for _, result, _, _ in ops)
