@@ -566,7 +566,7 @@ def train_step_args(net, *, batch_size: int = 8, timesteps: int = 16):
         x = _features_sds(conf.input_type, batch_size, timesteps)
         out_types = shapeflow.propagate_types(conf)
         y = _labels_sds(out_types[-1] if out_types else None,
-                        batch_size, timesteps)
+                        batch_size, timesteps, conf.layers[-1])
         if x is None or y is None:
             raise ValueError(
                 "no InputType on the configuration — cannot shape an "
@@ -587,7 +587,8 @@ def train_step_args(net, *, batch_size: int = 8, timesteps: int = 16):
         xs = tuple(_features_sds(t, batch_size, timesteps)
                    for t in conf.input_types)
         types = shapeflow.propagate_types(conf)
-        ys = tuple(_labels_sds(types.get(name), batch_size, timesteps)
+        ys = tuple(_labels_sds(types.get(name), batch_size, timesteps,
+                               getattr(conf.vertices[name], "layer", None))
                    for name in conf.outputs)
         if any(v is None for v in xs) or any(v is None for v in ys):
             raise ValueError(

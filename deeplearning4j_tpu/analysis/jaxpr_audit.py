@@ -261,8 +261,11 @@ def _features_sds(it, batch: int, timesteps: int):
         ConvolutionalInput,
         FeedForwardInput,
         RecurrentInput,
+        TokenSequenceInput,
     )
 
+    if isinstance(it, TokenSequenceInput):
+        return _sds((batch, it.timesteps or timesteps), np.int32)
     if isinstance(it, ConvolutionalInput):
         return _sds((batch, it.height, it.width, it.channels))
     if isinstance(it, ConvolutionalFlatInput):
@@ -274,9 +277,17 @@ def _features_sds(it, batch: int, timesteps: int):
     return None
 
 
-def _labels_sds(out_type, batch: int, timesteps: int):
+def _labels_sds(out_type, batch: int, timesteps: int, head=None):
+    """Abstract labels for an output of that type; `head` is the output
+    layer's conf (a vertex that is no layer: None). A head whose loss takes
+    integer labels (`sparse_mcxent`) gets int32 without the class axis."""
     from deeplearning4j_tpu.nn.conf.inputs import RecurrentInput
 
+    if getattr(getattr(head, "inner", None) or head, "loss",
+               None) == "sparse_mcxent" and out_type is not None:
+        if isinstance(out_type, RecurrentInput):
+            return _sds((batch, out_type.timesteps or timesteps), np.int32)
+        return _sds((batch,), np.int32)
     if isinstance(out_type, RecurrentInput):
         return _sds((batch, out_type.timesteps or timesteps, out_type.size))
     if out_type is not None:
@@ -328,7 +339,7 @@ def audit_network(net, *, batch_size: int = 2, timesteps: int = 8,
         x = _features_sds(conf.input_type, batch_size, timesteps)
         out_types = shapeflow.propagate_types(conf)
         y = _labels_sds(out_types[-1] if out_types else None,
-                        batch_size, timesteps)
+                        batch_size, timesteps, conf.layers[-1])
         if x is None or y is None:
             return skip
         layer_names = [
@@ -346,7 +357,8 @@ def audit_network(net, *, batch_size: int = 2, timesteps: int = 8,
         xs = tuple(_features_sds(t, batch_size, timesteps)
                    for t in conf.input_types)
         types = shapeflow.propagate_types(conf)
-        ys = tuple(_labels_sds(types.get(name), batch_size, timesteps)
+        ys = tuple(_labels_sds(types.get(name), batch_size, timesteps,
+                               getattr(conf.vertices[name], "layer", None))
                    for name in conf.outputs)
         if any(v is None for v in xs) or any(v is None for v in ys):
             return skip

@@ -29,7 +29,13 @@ class PrecisionPolicy:
     output_dtype: jnp.dtype = jnp.float32
 
     def cast_input(self, x):
-        return x.astype(self.compute_dtype) if x.dtype != self.compute_dtype else x
+        """Float inputs go to the compute dtype; integer inputs (token ids,
+        embedding indices) pass as they are: bf16 holds integers exactly
+        only up to 256, so a cast would turn most ids into a neighbour."""
+        if x.dtype == self.compute_dtype \
+                or not jnp.issubdtype(x.dtype, jnp.floating):
+            return x
+        return x.astype(self.compute_dtype)
 
     def cast_output(self, x):
         return x.astype(self.output_dtype) if x.dtype != self.output_dtype else x

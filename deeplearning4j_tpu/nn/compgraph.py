@@ -50,13 +50,25 @@ from deeplearning4j_tpu.nn.multilayer import (
     layer_scope,
 )
 from deeplearning4j_tpu.nn.netbase import NetworkBase
-from deeplearning4j_tpu.ops.losses import example_presence, masked_example_mean, loss_value
+from deeplearning4j_tpu.ops.losses import (
+    example_presence,
+    loss_value,
+    masked_example_mean,
+    sparse_head_loss,
+)
 from deeplearning4j_tpu.train.evaluation import Evaluation
 from deeplearning4j_tpu.train.updaters import (
     normalize_gradients,
     schedule_lr,
     updater_from_conf,
 )
+
+
+def _head_in_blocks(lc) -> bool:
+    """Does this output layer take its loss `head_rows_block` rows at a
+    time (ops/losses.sparse_head_loss), so that the whole batch's logits
+    never exist at once?"""
+    return bool(getattr(lc, "head_rows_block", None))
 
 
 # -- scan-over-identical-blocks ----------------------------------------------
@@ -326,6 +338,7 @@ class ComputationGraph(NetworkBase):
         env = {"activations": acts, "input_masks": masks}
         scan_on = self._block_scan_enabled()
         run_by_start = {r["start"]: r for r in self._block_runs()}
+        recompute_at = self._recompute_runs() if training else {}
         topo = self.topo
         pos = 0
         while pos < len(topo):
@@ -357,52 +370,145 @@ class ComputationGraph(NetworkBase):
                     # shows the collapse when the scan is on
                     for _ in range(r["count"]):
                         self._note_compile("graph_block", r["exit"])
-            v = conf.vertices[name]
-            with jax.named_scope(layer_scope(
-                    name, v.layer if isinstance(v, LayerVertex) else v)):
-                xs = [acts[i] for i in conf.vertex_inputs[name]]
-                if isinstance(v, LayerVertex):
-                    x = xs[0]
-                    timesteps = x.shape[1] if x.ndim == 3 else None
-                    if v.preprocessor is not None:
-                        x = v.preprocessor(x, {"timesteps": timesteps})
-                        if hasattr(x, "ndim") and x.ndim == 3:
-                            timesteps = x.shape[1]
-                    pidx = self._pidx[name]
-                    lc = v.layer
-                    st = states[pidx]
-                    if stateful and _is_recurrent(lc) and st is None:
-                        st = {}  # empty dict triggers zero-state seed + carry
-                    ctx = LayerContext(
-                        training=training,
-                        rng=(jax.random.fold_in(rng, pidx)
-                             if rng is not None else None),
-                        mask=(sole_mask if hasattr(x, "ndim")
-                              and x.ndim == 3 else None),
-                        timesteps=timesteps,
-                        state=st,
-                    )
-                    if (
-                        preout_outputs
-                        and name in conf.outputs
-                        and isinstance(lc, _OUTPUT_LAYER_TYPES)
-                    ):
-                        from deeplearning4j_tpu.nn.layers.core import (
-                            apply_dropout,
-                        )
-
-                        x = apply_dropout(x, lc.dropout, ctx)
-                        acts[name + "__features"] = x
-                        x = _preout_of_output_layer(lc, params[pidx], x)
-                        ns = None
-                    else:
-                        x, ns = forward_layer(lc, params[pidx], x, ctx)
-                    new_states[pidx] = ns
-                    acts[name] = x
-                else:
-                    acts[name] = v.forward(xs, env)
+            run = recompute_at.get(pos)
+            if run is not None:
+                self._exec_recompute_run(
+                    run, acts, env, params, states, new_states,
+                    training=training, rng=rng, sole_mask=sole_mask,
+                    preout_outputs=preout_outputs, stateful=stateful)
+                pos += len(run["names"])
+                continue
+            self._exec_vertex(name, acts, env, params, states, new_states,
+                              training=training, rng=rng,
+                              sole_mask=sole_mask,
+                              preout_outputs=preout_outputs,
+                              stateful=stateful)
             pos += 1
         return acts, new_states
+
+    def _exec_vertex(self, name, acts, env, params, states, new_states, *,
+                     training, rng, sole_mask, preout_outputs, stateful):
+        """One vertex of the walk: reads its inputs from `acts`, writes its
+        activation there and, for a layer, its new state into
+        `new_states`."""
+        conf = self.conf
+        v = conf.vertices[name]
+        with jax.named_scope(layer_scope(
+                name, v.layer if isinstance(v, LayerVertex) else v)):
+            xs = [acts[i] for i in conf.vertex_inputs[name]]
+            if isinstance(v, LayerVertex):
+                x = xs[0]
+                timesteps = x.shape[1] if x.ndim == 3 else None
+                if v.preprocessor is not None:
+                    x = v.preprocessor(x, {"timesteps": timesteps})
+                    if hasattr(x, "ndim") and x.ndim == 3:
+                        timesteps = x.shape[1]
+                pidx = self._pidx[name]
+                lc = v.layer
+                st = states[pidx]
+                if stateful and _is_recurrent(lc) and st is None:
+                    st = {}  # empty dict triggers zero-state seed + carry
+                ctx = LayerContext(
+                    training=training,
+                    rng=(jax.random.fold_in(rng, pidx)
+                         if rng is not None else None),
+                    mask=(sole_mask if hasattr(x, "ndim")
+                          and x.ndim == 3 else None),
+                    timesteps=timesteps,
+                    state=st,
+                    compute_dtype=self.policy.compute_dtype,
+                )
+                if (
+                    preout_outputs
+                    and name in conf.outputs
+                    and isinstance(lc, _OUTPUT_LAYER_TYPES)
+                ):
+                    from deeplearning4j_tpu.nn.layers.core import (
+                        apply_dropout,
+                    )
+
+                    x = apply_dropout(x, lc.dropout, ctx)
+                    acts[name + "__features"] = x
+                    # a head that takes its loss in blocks of rows never
+                    # holds the whole batch's logits (`_loss` reads the
+                    # features)
+                    x = None if _head_in_blocks(lc) else \
+                        _preout_of_output_layer(lc, params[pidx], x)
+                    ns = None
+                else:
+                    x, ns = forward_layer(lc, params[pidx], x, ctx)
+                new_states[pidx] = ns
+                acts[name] = x
+            else:
+                acts[name] = v.forward(xs, env)
+
+    # -- recomputation -------------------------------------------------------
+
+    def _recompute_runs(self) -> Dict[int, dict]:
+        """{topo position: run} of the configuration's `recompute` runs
+        (cached; pure conf analysis). A run is consecutive vertices of the
+        topological order; `inputs` are the activations it reads from
+        outside, `exits` those of its own that are read outside it."""
+        cached = getattr(self, "_recompute_cache", None)
+        if cached is not None:
+            return cached
+        conf, out = self.conf, {}
+        index = {n: i for i, n in enumerate(self.topo)}
+        taken = set()
+        for names in conf.recompute or ():
+            names = list(names)
+            unknown = [n for n in names if n not in conf.vertices]
+            if not names or unknown:
+                raise ValueError(f"recompute: {unknown or names} are not "
+                                 "vertices of the graph")
+            start = index[names[0]]
+            if self.topo[start:start + len(names)] != names:
+                raise ValueError(
+                    f"recompute: {names} are not consecutive in the "
+                    f"topological order (which has "
+                    f"{self.topo[start:start + len(names)]} there)")
+            if taken & set(names) or any(n in conf.outputs for n in names):
+                raise ValueError(f"recompute: {names} overlap another run "
+                                 "or hold an output vertex")
+            taken |= set(names)
+            inside = set(names)
+            inputs = []
+            for n in names:
+                for src in conf.vertex_inputs[n]:
+                    if src not in inside and src not in inputs:
+                        inputs.append(src)
+            exits = [n for n in names if any(
+                n in ins and c not in inside
+                for c, ins in conf.vertex_inputs.items())]
+            out[start] = {"names": names, "inputs": inputs, "exits": exits}
+        self._recompute_cache = out
+        return out
+
+    def _exec_recompute_run(self, run, acts, env, params, states, new_states,
+                            **walk):
+        """A `recompute` run under `jax.checkpoint`: the backward pass keeps
+        the run's inputs and computes everything inside it again. The same
+        vertices, scopes and arithmetic as the plain walk."""
+        names, inputs, exits = run["names"], run["inputs"], run["exits"]
+        slots = [self._pidx[n] for n in names if n in self._pidx]
+
+        def body(p_run, s_run, in_acts):
+            local = dict(zip(inputs, in_acts))
+            local_env = dict(env, activations=local)
+            params_of, states_of = dict(zip(slots, p_run)), \
+                dict(zip(slots, s_run))
+            ns: Dict[int, Optional[dict]] = {}
+            for n in names:
+                self._exec_vertex(n, local, local_env, params_of, states_of,
+                                  ns, **walk)
+            return [local[n] for n in exits], [ns[i] for i in slots]
+
+        outs, ns = jax.checkpoint(body)(
+            [params[i] for i in slots], [states[i] for i in slots],
+            [acts[n] for n in inputs])
+        acts.update(zip(exits, outs))
+        for i, st in zip(slots, ns):
+            new_states[i] = st
 
     def _run_shapes_ok(self, r, params, states) -> bool:
         """True when every unit's params/state trees share structure and
@@ -470,6 +576,7 @@ class ComputationGraph(NetworkBase):
                         mask=sole_mask if xq.ndim == 3 else None,
                         timesteps=xq.shape[1] if xq.ndim == 3 else None,
                         state=us[j],
+                        compute_dtype=self.policy.compute_dtype,
                     )
                     with jax.named_scope(layer_scope(vname, v.layer)):
                         y, ns = forward_layer(v.layer, up[j], xq, ctx)
@@ -538,10 +645,18 @@ class ComputationGraph(NetworkBase):
                     continue
                 lc = v.layer
                 lm = l_masks[i] if l_masks is not None else None
-                per_ex = loss_value(
-                    lc.loss, ys[i], self.policy.cast_output(acts[name]),
-                    lc.activation, lm,
-                )
+                if _head_in_blocks(lc):
+                    with jax.named_scope(layer_scope(name, lc)):
+                        per_ex = sparse_head_loss(
+                            acts[name + "__features"],
+                            params[self._pidx[name]], ys[i], lm,
+                            rows_block=int(lc.head_rows_block),
+                            compute_dtype=self.policy.compute_dtype)
+                else:
+                    per_ex = loss_value(
+                        lc.loss, ys[i], self.policy.cast_output(acts[name]),
+                        lc.activation, lm,
+                    )
                 score = score + masked_example_mean(per_ex, lm)
                 if isinstance(lc, L.CenterLossOutputLayer):
                     # center loss head (reference: CenterLossOutputLayer.java):

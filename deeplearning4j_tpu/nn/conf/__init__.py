@@ -18,14 +18,20 @@ from deeplearning4j_tpu.nn.conf.layers import (
     DenseLayer,
     DropoutLayer,
     EmbeddingLayer,
+    EmbeddingSequenceLayer,
     GlobalPoolingLayer,
     GravesBidirectionalLSTM,
     GravesLSTM,
+    GroupedQueryAttentionLayer,
     LocalResponseNormalization,
     LossLayer,
     LSTM,
+    Mamba2Layer,
     OutputLayer,
+    RMSNorm,
     RnnOutputLayer,
+    SelfAttentionLayer,
+    SparseExpertsLayer,
     Subsampling1DLayer,
     SubsamplingLayer,
     VariationalAutoencoder,

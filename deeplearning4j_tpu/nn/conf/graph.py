@@ -335,6 +335,10 @@ class ComputationGraphConfiguration:
     tbptt_fwd_length: int = 20
     tbptt_bwd_length: int = 20
     input_types: Optional[List[object]] = None
+    # runs of consecutive vertices (by name) whose forward is computed again
+    # in the backward pass instead of kept (`jax.checkpoint` around the
+    # run); None: nothing is recomputed
+    recompute: Optional[List[List[str]]] = None
 
     def to_json(self) -> str:
         return json.dumps(config_to_dict(self), indent=2)
@@ -389,6 +393,7 @@ class GraphBuilder:
         self._backprop_type = "standard"
         self._tbptt_fwd = 20
         self._tbptt_bwd = 20
+        self._recompute: List[List[str]] = []
 
     def add_inputs(self, *names: str) -> "GraphBuilder":
         self._inputs.extend(names)
@@ -432,6 +437,16 @@ class GraphBuilder:
         self._outputs = list(names)
         return self
 
+    def recompute(self, *names: str) -> "GraphBuilder":
+        """Mark a run of consecutive vertices (a block: norm, mixer, add)
+        to be computed again in the backward pass instead of kept."""
+        unknown = [n for n in names if n not in self._vertices]
+        if not names or unknown:
+            raise ValueError(f"recompute: {unknown or names} are not "
+                             "vertices added so far")
+        self._recompute.append(list(names))
+        return self
+
     def backprop_type(self, t: str) -> "GraphBuilder":
         self._backprop_type = t
         return self
@@ -473,6 +488,7 @@ class GraphBuilder:
             tbptt_fwd_length=self._tbptt_fwd,
             tbptt_bwd_length=self._tbptt_bwd,
             input_types=self._input_types,
+            recompute=self._recompute or None,
         )
         # hyperparameter inheritance into every layer conf
         for v in self._vertices.values():

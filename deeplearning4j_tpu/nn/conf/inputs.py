@@ -78,6 +78,23 @@ class ConvolutionalFlatInput:
         return self.height * self.width * self.channels
 
 
+@register_config("input.token_sequence")
+@dataclasses.dataclass
+class TokenSequenceInput:
+    """Integer token ids `[batch, timesteps]` drawn below `vocab`: what an
+    `EmbeddingSequenceLayer` consumes. Never cast to a float dtype."""
+
+    vocab: int
+    timesteps: Optional[int] = None
+
+    @property
+    def kind(self):
+        return "tokens"
+
+    def arity(self):
+        return self.vocab
+
+
 class InputType:
     """Factory namespace mirroring the reference's static methods."""
 
@@ -96,3 +113,7 @@ class InputType:
     @staticmethod
     def convolutional_flat(height: int, width: int, channels: int) -> ConvolutionalFlatInput:
         return ConvolutionalFlatInput(int(height), int(width), int(channels))
+
+    @staticmethod
+    def token_sequence(vocab: int, timesteps: Optional[int] = None) -> TokenSequenceInput:
+        return TokenSequenceInput(int(vocab), timesteps)

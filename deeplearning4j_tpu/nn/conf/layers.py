@@ -28,6 +28,7 @@ from deeplearning4j_tpu.nn.conf.inputs import (
     ConvolutionalInput,
     FeedForwardInput,
     RecurrentInput,
+    TokenSequenceInput,
 )
 from deeplearning4j_tpu.nn.conf.serde import register_config
 
@@ -111,9 +112,17 @@ class OutputLayer(FeedForwardLayerConf):
 @dataclasses.dataclass(kw_only=True)
 class RnnOutputLayer(FeedForwardLayerConf):
     """Time-distributed output layer (reference: RnnOutputLayer.java).
-    Input [batch, time, nIn] -> [batch, time, nOut], loss summed over time."""
+    Input [batch, time, nIn] -> [batch, time, nOut], loss summed over time
+    (`sparse_mcxent` on integer labels `[batch, time]`: the mean over
+    time). `has_bias=False` is a language model's bias-free head."""
 
     loss: str = "mcxent"
+    has_bias: bool = True
+    # ComputationGraph, `sparse_mcxent` with softmax: take the head and its
+    # loss this many rows of the batch at a time inside the step, so the
+    # whole batch's logits never exist at once (None: all rows at once).
+    # MultiLayerNetwork refuses it
+    head_rows_block: Optional[int] = None
 
     def output_type(self, it):
         ts = it.timesteps if isinstance(it, RecurrentInput) else None
@@ -478,3 +487,117 @@ class FrozenLayer(LayerConf):
 
     def has_params(self):
         return self.inner.has_params()
+
+
+# -- decoder-block vocabulary: token embedding, RMS norm, grouped-query
+# attention, the Mamba-2 mixer, the sparse-expert layer ------------------------
+
+
+@register_config("layer.embedding_sequence")
+@dataclasses.dataclass(kw_only=True)
+class EmbeddingSequenceLayer(FeedForwardLayerConf):
+    """Token lookup over a sequence: integer ids `[batch, time]` ->
+    `[batch, time, n_out]`, no bias. `n_in` is the vocabulary (the rows of
+    the table), as `EmbeddingLayer`'s is. The result is in the network's
+    compute dtype: this is where a net fed integers enters it."""
+
+    def output_type(self, it):
+        ts = it.timesteps if isinstance(
+            it, (RecurrentInput, TokenSequenceInput)) else None
+        return RecurrentInput(self.n_out, ts)
+
+    def infer_n_in(self, it) -> None:
+        if self.n_in is None and isinstance(it, TokenSequenceInput):
+            self.n_in = it.vocab
+
+
+@register_config("layer.rms_norm")
+@dataclasses.dataclass(kw_only=True)
+class RMSNorm(LayerConf):
+    """`x * rsqrt(mean(x^2) + eps) * gamma` over the last axis, statistics
+    in float32."""
+
+    n_in: Optional[int] = None
+    eps: float = 1e-5
+
+    def infer_n_in(self, it) -> None:
+        if self.n_in is None:
+            self.n_in = it.size if isinstance(it, RecurrentInput) \
+                else it.arity()
+
+
+@register_config("layer.grouped_query_attention")
+@dataclasses.dataclass(kw_only=True)
+class GroupedQueryAttentionLayer(BaseRecurrentLayerConf):
+    """Causal self-attention whose `n_heads` query heads share
+    `n_kv_heads` key-value heads (each read by `n_heads // n_kv_heads`
+    query heads), heads of `head_dim`; no bias, no positional term.
+    `[b, t, n_in] -> [b, t, n_out]`. With `n_kv_heads == n_heads` and
+    `head_dim == n_out // n_heads` it computes what a bias-free
+    `SelfAttentionLayer` does."""
+
+    n_heads: int = 4
+    n_kv_heads: int = 1
+    head_dim: int = 64
+    causal: bool = True
+
+
+@register_config("layer.mamba2")
+@dataclasses.dataclass(kw_only=True)
+class Mamba2Layer(BaseRecurrentLayerConf):
+    """Mamba-2 mixer (Dao & Gu 2024, state-space duality): in-projection to
+    `[z | xBC | dt]`, causal depthwise conv (with bias) and silu over
+    `xBC`, the recurrence `H_t = exp(dt_t A) H_(t-1) + dt_t x_t B_t^T`,
+    `y_t = H_t C_t + D x_t` a head (computed in chunks of `chunk_size`),
+    `GroupRMSNorm(y * silu(z))` over `n_groups` groups, out-projection.
+    Head `h` reads the `B`, `C` of group `h // (n_heads // n_groups)`.
+    `[b, t, n_in] -> [b, t, n_out]`."""
+
+    n_heads: int = 8
+    head_dim: int = 64
+    state_size: int = 128
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    norm_eps: float = 1e-5
+    time_step_min: float = 1e-3
+    time_step_max: float = 1e-1
+    time_step_floor: float = 1e-4
+
+
+@register_config("layer.sparse_experts")
+@dataclasses.dataclass(kw_only=True)
+class SparseExpertsLayer(BaseRecurrentLayerConf):
+    """Sparse-expert feed-forward that is told which experts it holds.
+
+    The router scores every token over all `router_width` experts
+    (sigmoid, float32), picks the `experts_per_token` largest, weights them
+    `scaling * s_i / sum of the chosen s` and computes the part of the
+    result that the experts in `experts_held` give (each `n_in -> width ->
+    n_in` with `activation`), plus one shared expert of `shared_width` for
+    every token. What the experts held elsewhere would add is left out: on
+    one chip the layer runs without its exchange.
+
+    Dropless on static shapes: assignments to held experts are gathered,
+    grouped by expert, into a buffer of `capacity_factor` times the uniform
+    mean `tokens * experts_per_token / router_width` rows a held expert. No
+    assignment is dropped: a step that sends a held expert more than its
+    rows takes the exact dense path (`tokens / rows` times the products) and
+    is counted (`experts_overflow_total`). The default is sized so that such
+    steps are rare without load balancing: on uniform tokens the fullest
+    held expert met 4.7 times the uniform mean within 24 steps of training
+    (PERF.md, PR 28). The layer's books (tokens routed to each expert,
+    overflow, peak load) are layer state, published where the fit loop
+    already blocks."""
+
+    router_width: int = 8
+    experts_held: Optional[List[int]] = None   # None: all of them
+    experts_per_token: int = 2
+    width: int = 0
+    shared_width: int = 0
+    scaling: float = 1.0
+    capacity_factor: float = 8.0
+
+    def held(self) -> List[int]:
+        return list(range(self.router_width)) if self.experts_held is None \
+            else [int(e) for e in self.experts_held]

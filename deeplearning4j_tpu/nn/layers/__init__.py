@@ -19,4 +19,5 @@ from deeplearning4j_tpu.nn.layers.registry import (
 )
 
 # Import impl modules for their registration side effects.
-from deeplearning4j_tpu.nn.layers import attention, core, conv, norm, rbm, recurrent, special  # noqa: E402,F401
+from deeplearning4j_tpu.nn.layers import (  # noqa: E402,F401
+    attention, core, conv, experts, norm, rbm, recurrent, special, ssm)

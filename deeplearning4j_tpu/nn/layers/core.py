@@ -58,13 +58,30 @@ register_layer(L.DenseLayer, dense_init, dense_forward)
 register_layer(L.OutputLayer, dense_init, dense_forward)
 
 
-def rnn_output_forward(conf, params, x, ctx: LayerContext):
+def rnn_output_init(key, conf: L.RnnOutputLayer, dtype):
+    p = dense_init(key, conf, dtype)
+    if not conf.has_bias:
+        del p["b"]
+    return p
+
+
+def rnn_output_preout(params, x):
     # x: [batch, time, nIn] — einsum keeps the time axis batched for the MXU
-    z = jnp.einsum("bti,io->bto", x, params["W"]) + params["b"]
-    return apply_activation(conf.activation, z, key=ctx.rng, training=ctx.training), None
+    z = jnp.einsum("bti,io->bto", x, params["W"])
+    return z + params["b"] if "b" in params else z
 
 
-register_layer(L.RnnOutputLayer, dense_init, rnn_output_forward)
+def rnn_output_forward(conf, params, x, ctx: LayerContext):
+    return apply_activation(conf.activation, rnn_output_preout(params, x),
+                            key=ctx.rng, training=ctx.training), None
+
+
+def rnn_output_order(conf):
+    return ("W", "b") if conf.has_bias else ("W",)
+
+
+register_layer(L.RnnOutputLayer, rnn_output_init, rnn_output_forward,
+               order_fn=rnn_output_order)
 
 
 def center_loss_init(key, conf: L.CenterLossOutputLayer, dtype):
@@ -146,6 +163,27 @@ def embedding_order(conf):
 
 
 register_layer(L.EmbeddingLayer, embedding_init, embedding_forward, order_fn=embedding_order)
+
+
+def embedding_sequence_init(key, conf: L.EmbeddingSequenceLayer, dtype):
+    return {"W": init_weights(key, (conf.n_in, conf.n_out), conf.n_in,
+                              conf.n_out, conf.weight_init, conf.dist, dtype)}
+
+
+def embedding_sequence_forward(conf, params, x, ctx: LayerContext):
+    """x: integer ids [batch, time] -> rows of the table [batch, time,
+    n_out], in the table's dtype: the residual stream of a decoder starts
+    here in the parameters' float32, whatever the matrix products use."""
+    if not jnp.issubdtype(x.dtype, jnp.integer):
+        raise TypeError(
+            f"EmbeddingSequenceLayer takes integer ids, got {x.dtype}: a "
+            "float cast has already rounded them (bf16 holds integers "
+            "exactly only up to 256)")
+    return jnp.take(params["W"], x, axis=0), None
+
+
+register_layer(L.EmbeddingSequenceLayer, embedding_sequence_init,
+               embedding_sequence_forward, order_fn=lambda conf: ("W",))
 
 
 # -- autoencoder (supervised path) ------------------------------------------

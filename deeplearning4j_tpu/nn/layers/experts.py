@@ -1,0 +1,262 @@
+"""The sparse-expert feed-forward (config: SparseExpertsLayer).
+
+    s = sigmoid(u W_router)              over all `router_width` experts, f32
+    chosen = the `experts_per_token` largest s;  w_i = scaling s_i / sum s
+    out = sum over chosen i held here of w_i W2_i act(W1_i u)
+          + Ws2 act(Ws1 u)               the shared expert, every token
+
+The layer is told which experts it holds (`experts_held`) and computes their
+part of the result; what the experts held elsewhere would add is left out.
+Assignments to held experts are gathered into one buffer `[held, capacity,
+n_in]`, multiplied as one grouped product over the held experts and added
+back with their weights. `capacity` is fixed from the configuration, so
+every shape is static. No assignment is ever dropped: a step in which some
+held expert is sent more than `capacity` tokens takes the exact path instead
+(`lax.cond`): every held expert applied to every token under a dense mask,
+`tokens / capacity` times the grouped path's products. Such steps are
+counted, never silent. The layer's books are layer state (`routed`: tokens
+sent to each of the `router_width` experts; `overflow`: assignments beyond
+the buffer, each served by the exact path; `peak`: the fullest held expert's
+load in one step), carried like batch norm's running statistics and
+published by `publish_expert_books`, the kind's publish hook (the net calls
+it where utils/devprof already blocks, and at the end of `fit()`).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Dict, List
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deeplearning4j_tpu.nn.conf import layers as L
+from deeplearning4j_tpu.nn.layers.registry import LayerContext, register_layer
+from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.ops.activations import apply_activation
+from deeplearning4j_tpu.utils import metrics as _metrics
+
+_ROWS = 128   # a held expert's capacity is a multiple of this many rows
+
+
+def expert_capacity(conf: L.SparseExpertsLayer, tokens: int) -> int:
+    """Rows of one held expert's buffer: `capacity_factor` times the uniform
+    mean `tokens * experts_per_token / router_width`, rounded up to a
+    multiple of 128 (never more than `tokens`: an expert meets a token
+    once)."""
+    mean = tokens * int(conf.experts_per_token) / int(conf.router_width)
+    rows = int(math.ceil(float(conf.capacity_factor) * mean / _ROWS)) * _ROWS
+    return max(1, min(rows, tokens))
+
+
+def experts_init(key, conf: L.SparseExpertsLayer, dtype):
+    n_in, width = int(conf.n_in), int(conf.width)
+    if int(conf.n_out) != n_in:
+        raise ValueError(f"SparseExpertsLayer: n_out {conf.n_out} is not "
+                         f"n_in {n_in} (the experts map back to their input)")
+    held = conf.held()
+    if len(set(held)) != len(held) or not all(
+            0 <= e < int(conf.router_width) for e in held):
+        raise ValueError(f"experts_held {held} are not distinct experts "
+                         f"below router_width {conf.router_width}")
+    ks = jax.random.split(key, 5)
+    mk = lambda k, shape, i, o: init_weights(
+        k, shape, i, o, conf.weight_init, conf.dist, dtype)
+    p = {"W_router": mk(ks[0], (n_in, int(conf.router_width)), n_in,
+                        int(conf.router_width)),
+         "W1": mk(ks[1], (len(held), n_in, width), n_in, width),
+         "W2": mk(ks[2], (len(held), width, n_in), width, n_in)}
+    if conf.shared_width:
+        sw = int(conf.shared_width)
+        p["Ws1"] = mk(ks[3], (n_in, sw), n_in, sw)
+        p["Ws2"] = mk(ks[4], (sw, n_in), sw, n_in)
+    return p
+
+
+def experts_order(conf):
+    return ("W_router", "W1", "W2") + (
+        ("Ws1", "Ws2") if conf.shared_width else ())
+
+
+def experts_state(conf: L.SparseExpertsLayer, dtype):
+    """The books since they were last published. int32: a count passes
+    2**31 only after 131,072 unpublished steps of 16,384 tokens."""
+    return {"routed": jnp.zeros((int(conf.router_width),), jnp.int32),
+            "overflow": jnp.zeros((), jnp.int32),
+            "peak": jnp.zeros((), jnp.int32)}
+
+
+def route(conf: L.SparseExpertsLayer, scores):
+    """scores: [tokens, router_width] float32 -> (chosen experts [tokens, k]
+    int32, their weights [tokens, k] float32): the k largest scores,
+    normalised over the k chosen (held here or not) and scaled."""
+    top, idx = jax.lax.top_k(scores, int(conf.experts_per_token))
+    return idx.astype(jnp.int32), \
+        float(conf.scaling) * top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
+    """x: [b, t, n_in] -> [b, t, n_in] in x's dtype, and the books."""
+    if ctx.mask is not None:
+        raise NotImplementedError(
+            "SparseExpertsLayer takes no time mask: a masked token would "
+            "still take a row of an expert's buffer")
+    shape = x.shape
+    d = shape[-1]
+    tokens = int(np.prod(shape[:-1]))
+    cd = ctx.compute_dtype or x.dtype
+    act = lambda a: apply_activation(conf.activation, a)
+    mm = lambda a, w: jnp.matmul(a, w, preferred_element_type=jnp.float32)
+    xf = x.reshape(tokens, d)
+    u = xf.astype(cd)
+    held = conf.held()
+    n_held, k = len(held), int(conf.experts_per_token)
+    cap = expert_capacity(conf, tokens)
+
+    with jax.named_scope("router"):
+        # the router reads the layer's input as it came (float32 on a
+        # float32 residual stream) at full precision: near-ties among the
+        # k largest would otherwise flip with the operands' rounding
+        scores = jax.nn.sigmoid(jnp.matmul(
+            xf.astype(jnp.float32), params["W_router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        idx, w = route(conf, scores)
+        # where each assignment goes: its expert's slot here (-1: held
+        # elsewhere) and its rank among that expert's assignments
+        slot_of = np.full((int(conf.router_width),), -1, np.int32)
+        slot_of[held] = np.arange(n_held, dtype=np.int32)
+        local = jnp.asarray(slot_of)[idx].reshape(-1)              # [T k]
+        onehot = local[:, None] == jnp.arange(n_held, dtype=jnp.int32)
+        rank = jnp.sum(jnp.where(onehot, jnp.cumsum(
+            onehot.astype(jnp.int32), axis=0) - 1, 0), axis=1)
+        kept = (local >= 0) & (rank < cap)
+        # dropped and foreign assignments all land in one spare row
+        dest = jnp.where(kept, local * cap + rank, n_held * cap)
+        token_of = jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), k)
+        spare = n_held * cap + 1
+        slot_token = jnp.zeros((spare,), jnp.int32).at[dest].set(
+            token_of)[:-1]
+        slot_w = jnp.zeros((spare,), jnp.float32).at[dest].set(
+            jnp.where(kept, w.reshape(-1), 0.0))[:-1]
+        overflow = jnp.sum((local >= 0) & (rank >= cap), dtype=jnp.int32)
+        books = {
+            "routed": jnp.sum(idx.reshape(-1)[:, None] == jnp.arange(
+                int(conf.router_width), dtype=jnp.int32), axis=0,
+                dtype=jnp.int32),
+            "overflow": overflow,
+            "peak": jnp.max(jnp.sum(onehot, axis=0, dtype=jnp.int32))}
+
+    w1, w2 = params["W1"].astype(cd), params["W2"].astype(cd)
+
+    def grouped():
+        rows = u[slot_token].reshape(n_held, cap, d)
+        hidden = act(jnp.einsum("ecd,edf->ecf", rows, w1,
+                                preferred_element_type=jnp.float32)
+                     ).astype(cd)
+        out_rows = jnp.einsum("ecf,efd->ecd", hidden, w2,
+                              preferred_element_type=jnp.float32)
+        out_rows = out_rows.reshape(n_held * cap, d) * slot_w[:, None]
+        return jnp.zeros((tokens, d), jnp.float32).at[slot_token].add(
+            out_rows)
+
+    def exact():
+        # every held expert over every token, weighted by the router's
+        # weight where the token chose it and by nought elsewhere
+        def one(y, expert):
+            w1_e, w2_e, number = expert
+            weight = jnp.sum(jnp.where(idx == number, w, 0.0), axis=-1)
+            return y + weight[:, None] * mm(act(mm(u, w1_e)).astype(cd),
+                                            w2_e), None
+
+        y, _ = jax.lax.scan(jax.checkpoint(one),
+                            jnp.zeros((tokens, d), jnp.float32),
+                            (w1, w2, jnp.asarray(held, jnp.int32)))
+        return y
+
+    with jax.named_scope("experts"):
+        y = jax.lax.cond(overflow > 0, exact, grouped)
+
+    if conf.shared_width:
+        with jax.named_scope("shared_expert"):
+            y = y + mm(act(mm(u, params["Ws1"].astype(cd))).astype(cd),
+                       params["Ws2"].astype(cd))
+
+    state = ctx.state
+    if state is not None:
+        books = {"routed": state["routed"] + books["routed"],
+                 "overflow": state["overflow"] + books["overflow"],
+                 "peak": jnp.maximum(state["peak"], books["peak"])}
+    return y.reshape(shape).astype(x.dtype), books
+
+
+# -- the books, published -------------------------------------------------------
+
+def _instruments():
+    reg = _metrics.get_registry()
+    return {
+        "assignments": reg.counter(
+            "experts_assignments_total",
+            "token-to-expert assignments routed by the sparse-expert "
+            "layers, by whether the expert is held here", ("held",)),
+        "overflow": reg.counter(
+            "experts_overflow_total",
+            "assignments to a held expert beyond its buffer's capacity: "
+            "none is dropped, their steps took the exact dense path "
+            "(tokens / capacity times the grouped products)").labels(),
+        "peak": reg.gauge(
+            "experts_peak_load",
+            "assignments to the fullest held expert in one step, the "
+            "worst layer's and step's since the books were last published "
+            "(above the buffer's rows that step took the exact path)"
+            ).labels(),
+        "load": reg.gauge(
+            "experts_load_max_over_mean",
+            "the fullest held expert's assignments over the mean of the "
+            "held ones, over all sparse-expert layers, since the books "
+            "were last published").labels(),
+    }
+
+
+def publish_expert_books(confs, books) -> Dict[str, float]:
+    """The publish hook of the kind (`register_layer(publish_fn=)`): host
+    copies of the sparse-expert layers' books since they were last
+    published go to the registry. The net calls it where the fit loop
+    already blocks (devprof's sampled steps, the end of `fit()`), never on
+    a plain step, and zeroes the books afterwards."""
+    held_n = foreign_n = overflow = peak = 0
+    loads: List[np.ndarray] = []
+    for conf, b in zip(confs, books):
+        peak = max(peak, int(b["peak"]))
+        routed = np.asarray(b["routed"], np.int64)
+        mine = routed[conf.held()]
+        held_n += int(mine.sum())
+        foreign_n += int(routed.sum() - mine.sum())
+        overflow += int(b["overflow"])
+        loads.append(mine)
+    ins = _instruments()
+    ins["assignments"].labels("1").inc(held_n)
+    ins["assignments"].labels("0").inc(foreign_n)
+    ins["overflow"].inc(overflow)
+    out = {"held": held_n, "foreign": foreign_n, "overflow": overflow,
+           "peak": peak}
+    ins["peak"].set(peak)
+    if held_n:
+        ratio = max(float(m.max()) / max(float(m.mean()), 1e-30)
+                    for m in loads if m.sum())
+        ins["load"].set(ratio)
+        out["load_max_over_mean"] = ratio
+    if overflow:
+        import logging
+
+        logging.getLogger("deeplearning4j_tpu").warning(
+            "SparseExpertsLayer: %d assignments beyond the buffer's "
+            "capacity (experts_overflow_total); none was dropped, their "
+            "steps took the exact dense path. A larger capacity_factor "
+            "makes such steps rarer", overflow)
+    return out
+
+
+register_layer(L.SparseExpertsLayer, experts_init, experts_forward,
+               order_fn=experts_order, state_fn=experts_state,
+               publish_fn=publish_expert_books)

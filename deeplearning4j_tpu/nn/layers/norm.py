@@ -290,3 +290,28 @@ def lrn_forward(conf: L.LocalResponseNormalization, params, x, ctx: LayerContext
 
 
 register_layer(L.LocalResponseNormalization, _no_params, lrn_forward)
+
+
+# -- RMS norm ------------------------------------------------------------------
+
+def rms_normalize(x, gamma, eps, groups: int = 1):
+    """`x * rsqrt(mean(x^2) + eps) * gamma` over the last axis (in `groups`
+    equal groups of it), statistics in float32, result in x's dtype."""
+    xf = x.astype(jnp.promote_types(x.dtype, jnp.float32))
+    shape = xf.shape
+    if groups > 1:
+        xf = xf.reshape(shape[:-1] + (groups, shape[-1] // groups))
+    xf = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (xf.reshape(shape) * gamma.astype(xf.dtype)).astype(x.dtype)
+
+
+def rms_norm_init(key, conf: L.RMSNorm, dtype):
+    return {"gamma": jnp.ones((int(conf.n_in),), dtype)}
+
+
+def rms_norm_forward(conf: L.RMSNorm, params, x, ctx: LayerContext):
+    return rms_normalize(x, params["gamma"], conf.eps), None
+
+
+register_layer(L.RMSNorm, rms_norm_init, rms_norm_forward,
+               order_fn=lambda conf: ("gamma",))

@@ -39,6 +39,7 @@ from deeplearning4j_tpu.data.iterators import (
 )
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.conf.network import BackpropType, MultiLayerConfiguration
+from deeplearning4j_tpu.nn.layers.core import rnn_output_preout
 from deeplearning4j_tpu.nn.layers.registry import (
     LayerContext,
     forward_layer,
@@ -119,7 +120,7 @@ def _preout_of_output_layer(conf, params, x):
     if isinstance(conf, L.LossLayer):
         return x
     if isinstance(conf, L.RnnOutputLayer):
-        return jnp.einsum("bti,io->bto", x, params["W"]) + params["b"]
+        return rnn_output_preout(params, x)
     return x @ params["W"] + params["b"]
 
 
@@ -131,6 +132,12 @@ class MultiLayerNetwork(NetworkBase):
         super().__init__()
         self.conf = conf
         self.layer_confs: List[L.LayerConf] = list(conf.layers)
+        if self.layer_confs and getattr(
+                self.layer_confs[-1], "head_rows_block", None):
+            raise ValueError(
+                "RnnOutputLayer.head_rows_block is honoured by "
+                "ComputationGraph only: MultiLayerNetwork takes the head "
+                "and its loss over the whole batch at once")
         self.net_conf = conf.net_conf
         self.policy = policy_from_name(self.net_conf.precision)
         self.updater_def = updater_from_conf(self.net_conf)
@@ -185,6 +192,7 @@ class MultiLayerNetwork(NetworkBase):
                           else None),
                     timesteps=timesteps,
                     state=st,
+                    compute_dtype=self.policy.compute_dtype,
                 )
                 is_last = i == len(confs) - 1
                 if (preout_last and is_last

@@ -19,6 +19,7 @@ from typing import List, Optional
 import numpy as np
 
 from deeplearning4j_tpu.data.iterators import AsyncDataSetIterator
+from deeplearning4j_tpu.nn.layers.registry import publish_slots
 from deeplearning4j_tpu.nn.params import (
     flat_to_params,
     num_params,
@@ -778,6 +779,10 @@ class NetworkBase:
                         # past the budget)
                         skip_batches, epochs = self._rollback_restore(
                             iterator, total_epoch_target)
+                # counters that layers keep on the device reach the
+                # registry here and on devprof's sampled steps, never on a
+                # plain step
+                self._publish_layer_books()
         except _health.StepHangError as e:
             if e.dump_path is not None:
                 raise  # already carries its forensics
@@ -827,6 +832,33 @@ class NetworkBase:
                 if hook is not None:
                     hook(self)
         return self
+
+    def _publish_layer_books(self):
+        """Publish counters that layers carry as state on the device,
+        through the hooks their kinds registered (`register_layer(...,
+        publish_fn=)`), and zero them. One attribute read for a net without
+        such layers; else one blocking read of a few hundred bytes, made
+        only where the fit loop blocks anyway."""
+        slots = getattr(self, "_book_slots", None)
+        if slots is None:
+            slots = self._book_slots = publish_slots(
+                self._ordered_layer_confs())
+        if not slots or self.state_list is None:
+            return None
+        import jax
+
+        confs = self._ordered_layer_confs()
+        flat = [i for idx in slots.values() for i in idx]
+        books = dict(zip(flat, jax.device_get(
+            [self.state_list[i] for i in flat])))
+        out = {}
+        for hook, idx in slots.items():
+            out.update(hook([confs[i] for i in idx],
+                            [books[i] for i in idx]) or {})
+        for i in flat:
+            self.state_list[i] = jax.tree_util.tree_map(
+                jax.numpy.zeros_like, self.state_list[i])
+        return out
 
     def _hang_action(self):
         """The watchdog-side stall action for fit(hang_timeout=...):

@@ -56,6 +56,13 @@ def relu(x):
     return jax.nn.relu(x)
 
 
+@_simple("relu2")
+def relu2(x):
+    """Squared ReLU (Primer; the expert activation of the nemotron_h family)."""
+    r = jax.nn.relu(x)
+    return r * r
+
+
 @_simple("relu6")
 def relu6(x):
     return jax.nn.relu6(x)

@@ -126,6 +126,75 @@ def mcxent(labels, preout, activation, mask=None):
     return _reduce(-labels * logp, mask)
 
 
+def _token_mean(per_token, mask):
+    """[batch, ...] per-position losses -> [batch]: the mean over an
+    example's positions (over its unmasked ones under a mask)."""
+    if per_token.ndim == 1:
+        return per_token if mask is None else per_token * mask.reshape(-1)
+    axes = tuple(range(1, per_token.ndim))
+    if mask is None:
+        return jnp.mean(per_token, axis=axes)
+    m = mask.astype(per_token.dtype).reshape(per_token.shape)
+    return jnp.sum(per_token * m, axis=axes) \
+        / jnp.maximum(jnp.sum(m, axis=axes), 1.0)
+
+
+@_loss("sparse_mcxent")
+def sparse_mcxent(labels, preout, activation, mask=None):
+    """Multi-class cross-entropy on INTEGER labels: `labels` holds the
+    class of each row, `[batch]` against `preout [batch, classes]` or
+    `[batch, time]` against `[batch, time, classes]`; no one-hot tensor is
+    built. Log-softmax in float32, then the label's entry. For `[batch]`
+    labels it equals `mcxent` on their one-hot rows; over a sequence it is
+    the MEAN over the example's positions (next-token loss: the mean over
+    `batch * time`), where `mcxent` sums over time."""
+    if not jnp.issubdtype(labels.dtype, jnp.integer):
+        raise TypeError(f"sparse_mcxent takes integer labels, got "
+                        f"{labels.dtype}")
+    z = preout.astype(jnp.promote_types(preout.dtype, jnp.float32))
+    if activation == "softmax":
+        logp = jax.nn.log_softmax(z, axis=-1)
+    else:
+        logp = jnp.log(jnp.clip(_out(z, activation), _EPS, None))
+    picked = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+    return _token_mean(-picked, mask)
+
+
+def sparse_head_loss(features, params, labels, mask=None, *, rows_block: int,
+                     compute_dtype=None):
+    """A bias-or-not linear head and `sparse_mcxent` (softmax) on it, taken
+    `rows_block` rows of the batch at a time: `features [batch, time, n_in]`
+    times `params["W"] [n_in, classes]` (operands in `compute_dtype`,
+    float32 accumulation) against integer `labels [batch, time]`, to the
+    per-example loss `[batch]`. Each block runs under `jax.checkpoint`
+    inside a `lax.map`, so one block's logits (and their gradient) live at
+    a time: `[4, 4096, 16384]` float32 logits are 1.07 GB, a row of them a
+    quarter of that. The same numbers as the whole batch at once."""
+    batch = features.shape[0]
+    if batch % rows_block:
+        raise ValueError(f"head_rows_block {rows_block} does not divide the "
+                         f"batch of {batch} rows")
+    cd = compute_dtype or features.dtype
+    w = params["W"].astype(cd)
+    b = params.get("b")
+
+    def block(args):
+        x, y, m = args
+        z = jnp.einsum("bti,io->bto", x.astype(cd), w,
+                       preferred_element_type=jnp.float32)
+        if b is not None:
+            z = z + b.astype(jnp.float32)
+        return sparse_mcxent(y, z, "softmax", m)
+
+    split = lambda a: None if a is None else a.reshape(
+        (batch // rows_block, rows_block) + a.shape[1:])
+    if mask is not None and mask.ndim == 1:
+        mask = jnp.broadcast_to(mask[:, None], labels.shape)
+    per_ex = jax.lax.map(jax.checkpoint(block),
+                         (split(features), split(labels), split(mask)))
+    return per_ex.reshape(batch)
+
+
 @_loss("negativeloglikelihood")
 def negativeloglikelihood(labels, preout, activation, mask=None):
     # Reference LossNegativeLogLikelihood extends LossMCXENT.
@@ -204,6 +273,7 @@ class LossFunction:
     L2 = "l2"
     XENT = "xent"
     MCXENT = "mcxent"
+    SPARSE_MCXENT = "sparse_mcxent"
     SQUARED_LOSS = "squared_loss"
     RECONSTRUCTION_CROSSENTROPY = "reconstruction_crossentropy"
     NEGATIVELOGLIKELIHOOD = "negativeloglikelihood"

@@ -190,6 +190,12 @@ class DeviceProfiler:
                 _jax().block_until_ready(score)
             except Exception:
                 pass  # a failed sync is the step's problem, not ours
+            # the step has ended: what layers count on the device is read
+            # inside this same blocked interval, so no other step pays a
+            # host read for it
+            publish = getattr(net, "_publish_layer_books", None)
+            if publish is not None:
+                publish()
             blocked = (t0, _now_ns())
         now = time.perf_counter()
         last = st["last_t"]

@@ -1,0 +1,671 @@
+"""The decoder-block vocabulary (token embedding, RMS norm, grouped-query
+attention, the Mamba-2 mixer, the sparse-expert layer), integer-label
+cross-entropy, recomputation and the int32 feed, at tiny sizes on the CPU:
+hidden 64, 2 key-value heads, 16 routed experts of which 8 are held, state
+16, chunk 8, sequences of 32. The plain reference is
+`benchmark/reference/nemotron_h.py`, which imports nothing of the program."""
+
+from __future__ import annotations
+
+import hashlib
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.reference import nemotron_h as ref  # noqa: E402
+from deeplearning4j_tpu.data.dataset import DataSet  # noqa: E402
+from deeplearning4j_tpu.data.iterators import ListDataSetIterator  # noqa: E402
+from deeplearning4j_tpu.models.nemotron_h import (  # noqa: E402
+    nemotron_h_conf,
+    tiny_nemotron_h_conf,
+)
+from deeplearning4j_tpu.nn.compgraph import ComputationGraph  # noqa: E402
+from deeplearning4j_tpu.nn.conf import layers as L  # noqa: E402
+from deeplearning4j_tpu.nn.layers import experts as X  # noqa: E402
+from deeplearning4j_tpu.nn.layers import ssm  # noqa: E402
+from deeplearning4j_tpu.nn.layers.registry import (  # noqa: E402
+    LayerContext,
+    forward_layer,
+    init_layer_params,
+    init_layer_state,
+)
+from deeplearning4j_tpu.ops.losses import (  # noqa: E402
+    LossFunction,
+    loss_value,
+    sparse_head_loss,
+)
+from deeplearning4j_tpu.utils.metrics import get_registry  # noqa: E402
+
+# the tiny preset as the reference reads a configuration
+TINY = {
+    "num_hidden_layers": 4, "hybrid_override_pattern": "ME*M",
+    "hidden_size": 64, "vocab_size": 128,
+    "mamba_num_heads": 8, "mamba_head_dim": 16, "ssm_state_size": 16,
+    "n_groups": 2, "conv_kernel": 4, "chunk_size": 8,
+    "time_step_min": 1e-3, "time_step_max": 0.1, "time_step_floor": 1e-4,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "n_routed_experts": 8, "router_width": 16,
+    "experts_held": list(range(8)), "num_experts_per_tok": 3,
+    "moe_intermediate_size": 48, "moe_shared_expert_intermediate_size": 96,
+    "routed_scaling_factor": 2.5, "layer_norm_epsilon": 1e-5,
+}
+SEQ, BATCH = 32, 4
+
+
+def _tokens(seed=0, batch=BATCH, seq=SEQ, vocab=128):
+    ids = np.random.default_rng(seed).integers(0, vocab, (batch, seq + 1),
+                                               dtype=np.int32)
+    return np.ascontiguousarray(ids[:, :-1]), np.ascontiguousarray(ids[:, 1:])
+
+
+def _net(precision="f32", seed=3, **kw):
+    net = ComputationGraph(tiny_nemotron_h_conf(precision=precision,
+                                                **kw)).init()
+    weights = ref.init_params(seed, TINY)
+    net.params_list = [dict(weights[name]) for name in
+                       net.layer_vertex_names]
+    return net, weights
+
+
+def _program_loss_and_grads(net, x, y):
+    def loss(params):
+        return net._loss(params, net.state_list, [jnp.asarray(x)],
+                         [jnp.asarray(y)], None, None, None)[0]
+
+    value, grads = jax.value_and_grad(loss)(net.params_list)
+    return value, dict(zip(net.layer_vertex_names, grads))
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm((a - b).ravel())
+                 / jnp.maximum(jnp.linalg.norm(b.ravel()), 1e-30))
+
+
+# -- the whole net against the plain reference ------------------------------------
+
+# f32: both sides are float32 at HIGHEST on the CPU; what is left is the order
+# of the sums (chunked scan against the literal one, grouped experts against
+# the masked loop). bf16: the program rounds the operands of every matrix
+# product to 8 bits of mantissa (relative 2**-9 an operand), and a leaf's
+# gradient is a sum over thousands of such products: the reference's own bf16
+# witness reads 2e-3 on the worst leaf here, so 3e-2 is the cell's order of
+# tolerance and a precision below bf16 (fp8: 2**-4) is ten times outside it.
+# This test is stricter than the cell's comparison, the norm of each leaf's
+# DIFFERENCE and not the difference of norms: an expert's W1 sums over the two
+# dozen tokens it meets here, and a token whose third and fourth router scores
+# lie within round-off goes to another expert, so its worst leaf reads 0.08.
+@pytest.mark.parametrize("precision,loss_tol,grad_tol", [
+    ("f32", 2e-6, 2e-4), ("bf16", 2e-4, 0.15)])
+def test_loss_and_every_gradient_leaf_against_the_reference(
+        precision, loss_tol, grad_tol):
+    net, weights = _net(precision)
+    x, y = _tokens(1)
+    got, got_grads = _program_loss_and_grads(net, x, y)
+    want, want_grads = jax.value_and_grad(ref.loss)(weights, x, y, TINY,
+                                                    "f32")
+    assert abs(float(got) - float(want)) / float(want) < loss_tol
+    assert set(got_grads) == set(want_grads)
+    for layer, leaves in want_grads.items():
+        assert set(got_grads[layer]) == set(leaves)
+        for name, g in leaves.items():
+            assert got_grads[layer][name].shape == g.shape
+            assert _rel(got_grads[layer][name], g) < grad_tol, \
+                f"{layer}/{name}"
+
+
+def test_the_references_layers_hold_the_programs_names_and_shapes():
+    net, weights = _net()
+    fresh = ComputationGraph(tiny_nemotron_h_conf()).init()
+    for name, mine in zip(fresh.layer_vertex_names, fresh.params_list):
+        assert set(mine) == set(weights[name]), name
+        for leaf, a in mine.items():
+            assert a.shape == weights[name][leaf].shape, (name, leaf)
+    assert set(weights) == set(fresh.layer_vertex_names)
+
+
+# -- the chunked scan against the literal recurrence ------------------------------
+
+def _scan_inputs(t, seed=0, b=2, H=4, P=8, G=2, N=8):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (b, t, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, t, H)) - 1.0)
+    A = -jnp.exp(jax.random.uniform(ks[2], (H,), minval=0.0, maxval=2.0))
+    B = jax.random.normal(ks[3], (b, t, G, N))
+    C = jax.random.normal(ks[4], (b, t, G, N))
+    return x, dt, A, B, C
+
+
+def _literal(x, dt, A, B, C, chunk=8):
+    """The plain reference's recurrence, one position at a time; it takes B
+    and C a head."""
+    H, G = x.shape[2], B.shape[2]
+    return ref.recurrence(x, dt, A, jnp.repeat(B, H // G, axis=2),
+                          jnp.repeat(C, H // G, axis=2), chunk=chunk)
+
+
+@pytest.mark.parametrize("t,chunk", [(32, 8), (29, 8), (5, 8), (16, 16),
+                                     (33, 4)],
+                         ids=["multiple", "not_a_multiple", "under_a_chunk",
+                              "one_chunk", "one_over"])
+def test_chunked_scan_against_the_literal_recurrence(t, chunk):
+    x, dt, A, B, C = _scan_inputs(t)
+    want = _literal(x, dt, A, B, C)
+    got = ssm.ssd_chunked(x, dt, A, B, C, chunk)
+    assert got.shape == want.shape == x.shape
+    assert _rel(got, want) < 1e-5
+    # the reference's chunks only bound what its backward pass recomputes
+    assert _rel(_literal(x, dt, A, B, C, chunk=t + 3), want) < 1e-6
+
+
+def test_chunked_scan_gradients_against_the_literal_recurrence():
+    x, dt, A, B, C = _scan_inputs(19, seed=4)
+    f = lambda fn: jax.grad(
+        lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2, 3, 4))
+    want = f(_literal)(x, dt, A, B, C)
+    got = f(lambda *a: ssm.ssd_chunked(*a, 8))(x, dt, A, B, C)
+    for g, w in zip(got, want):
+        assert _rel(g, w) < 1e-4
+
+
+def test_a_position_never_reads_a_later_one():
+    """Causality of the mixer, the conv included: changing the tokens from
+    position 20 on leaves the first 20 outputs as they were."""
+    conf = L.Mamba2Layer(n_in=16, n_out=16, n_heads=4, head_dim=8,
+                         state_size=8, n_groups=2, chunk_size=8,
+                         weight_init="xavier")
+    params = init_layer_params(jax.random.PRNGKey(0), conf, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 16))
+    x2 = x.at[:, 20:].set(0.5)
+    y, _ = forward_layer(conf, params, x, LayerContext())
+    y2, _ = forward_layer(conf, params, x2, LayerContext())
+    assert jnp.allclose(y[:, :20], y2[:, :20], atol=1e-6)
+    assert not jnp.allclose(y[:, 20:], y2[:, 20:], atol=1e-3)
+
+
+# -- the share of the experts ------------------------------------------------------
+
+def _expert_layer(held, seed=0, factor=8.0):
+    conf = L.SparseExpertsLayer(
+        n_in=32, n_out=32, router_width=16, experts_held=held,
+        experts_per_token=3, width=24, shared_width=40, scaling=2.5,
+        activation="relu2", capacity_factor=factor, weight_init="xavier")
+    return conf, init_layer_params(jax.random.PRNGKey(seed), conf,
+                                   jnp.float32)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """16 routed experts, 8 held by each of two chips: the routed parts that
+    both shares give, with the shared expert counted once, add up to what
+    the layer that holds all 16 gives."""
+    whole_conf, whole = _expert_layer(list(range(16)))
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 24, 32))
+    want, _ = forward_layer(whole_conf, whole, x, LayerContext())
+    shared = (X.apply_activation("relu2", x @ whole["Ws1"])) @ whole["Ws2"]
+    total = -shared          # both shares compute it: count it once
+    for held in (list(range(8)), list(range(8, 16))):
+        conf, _ = _expert_layer(held)
+        share = dict(whole, W1=whole["W1"][jnp.asarray(held)],
+                     W2=whole["W2"][jnp.asarray(held)])
+        part, _ = forward_layer(conf, share, x, LayerContext())
+        total = total + part
+    assert _rel(total, want) < 1e-5
+    # and the same from the plain reference, its shares by configuration
+    config = dict(TINY, hidden_size=32, moe_intermediate_size=24,
+                  moe_shared_expert_intermediate_size=40)
+    z = lambda held: ref._sizes(dict(config, experts_held=held,
+                                     n_routed_experts=len(held)))
+    theirs = ref.experts(whole, x, z(list(range(16))), config, "f32")
+    assert _rel(theirs, want) < 1e-5
+    halves = sum(ref.experts(
+        dict(whole, W1=whole["W1"][jnp.asarray(h)],
+             W2=whole["W2"][jnp.asarray(h)]), x, z(h), config, "f32",
+        with_shared=(h[0] == 0)) for h in (list(range(8)),
+                                           list(range(8, 16))))
+    assert _rel(halves, want) < 1e-5
+
+
+def test_router_weights_are_normalised_over_all_the_chosen():
+    conf, params = _expert_layer([0, 1])
+    scores = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(2),
+                                              (10, 16)))
+    idx, w = X.route(conf, scores)
+    assert idx.shape == w.shape == (10, 3)
+    assert jnp.allclose(jnp.sum(w, axis=-1), 2.5, atol=1e-5)
+    assert jnp.all(jnp.take_along_axis(scores, idx, 1)[:, :1]
+                   == jnp.max(scores, axis=1, keepdims=True))
+
+
+def test_an_overflowing_capacity_is_counted_and_nothing_is_dropped():
+    """Every token sent to the same experts, a buffer of a quarter of the
+    uniform mean: the step takes the exact path, its result and gradients
+    are the roomy buffer's, and the books say how far the load went."""
+    tight, params = _expert_layer(list(range(8)), factor=0.25)
+    roomy, _ = _expert_layer(list(range(8)), factor=8.0)
+    state = init_layer_state(tight, jnp.float32)
+    assert X.expert_capacity(tight, 256) == 128
+    assert X.expert_capacity(roomy, 256) == 256     # never more than tokens
+    x = jax.random.normal(jax.random.PRNGKey(7), (4, 64, 32))
+    same = x.at[:, :, :].set(x[:1, :1])
+    for inputs in (same, x):
+        f = lambda conf: lambda p, a: jnp.sum(jnp.sin(forward_layer(
+            conf, p, a, LayerContext(state=state))[0]))
+        want, want_g = jax.value_and_grad(f(roomy), argnums=(0, 1))(
+            params, inputs)
+        got, got_g = jax.value_and_grad(f(tight), argnums=(0, 1))(
+            params, inputs)
+        assert abs(float(got) - float(want)) < 1e-3 * abs(float(want)) + 1e-4
+        for k in want_g[0]:
+            assert _rel(got_g[0][k], want_g[0][k]) < 1e-4, k
+        assert _rel(got_g[1], want_g[1]) < 1e-4
+    _, books = forward_layer(tight, params, same, LayerContext(state=state))
+    assert int(books["routed"].sum()) == 256 * 3
+    held = int(books["routed"][:8].sum())
+    assert held > 0 and int(books["peak"]) == 256
+    assert int(books["overflow"]) == held - 128 * (held // 256) > 0
+    _, books = forward_layer(roomy, params, x, LayerContext(state=state))
+    assert int(books["overflow"]) == 0 and 0 < int(books["peak"]) <= 256
+
+
+def test_the_books_reach_the_registry_at_the_end_of_fit():
+    net, _ = _net()
+    x, y = _tokens(2)
+    before = get_registry().scalar_values()
+    net.fit(ListDataSetIterator(DataSet(x, y), BATCH), epochs=2)
+    after = get_registry().scalar_values()
+    moved = lambda k: after.get(k, 0.0) - before.get(k, 0.0)
+    total = moved('experts_assignments_total{held="1"}') \
+        + moved('experts_assignments_total{held="0"}')
+    assert total == 2 * BATCH * SEQ * 3      # one expert layer, two steps
+    assert moved('experts_assignments_total{held="1"}') > 0
+    assert moved("experts_overflow_total") == 0
+    assert after["experts_load_max_over_mean"] >= 1.0
+    # published means zeroed: the next fit counts from nought
+    (slot,), = net._book_slots.values()
+    assert int(net.state_list[slot]["routed"].sum()) == 0
+
+
+def test_a_net_without_expert_layers_publishes_nothing():
+    from deeplearning4j_tpu.models.resnet import tiny_resnet_conf
+
+    net = ComputationGraph(tiny_resnet_conf()).init()
+    assert net._publish_layer_books() is None
+    assert net._book_slots == {}
+    assert net._publish_layer_books() is None
+
+
+# -- attention ----------------------------------------------------------------------
+
+def test_grouped_query_attention_with_all_heads_is_self_attention():
+    old = L.SelfAttentionLayer(n_in=24, n_out=32, n_heads=4, causal=True,
+                               projection_bias=False, activation="identity",
+                               weight_init="xavier")
+    new = L.GroupedQueryAttentionLayer(n_in=24, n_out=32, n_heads=4,
+                                       n_kv_heads=4, head_dim=8, causal=True,
+                                       weight_init="xavier")
+    params = init_layer_params(jax.random.PRNGKey(0), old, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 12, 24))
+    want, _ = forward_layer(old, params, x, LayerContext())
+    got, _ = forward_layer(new, params, x, LayerContext())
+    assert _rel(got, want) < 1e-5
+
+
+@pytest.mark.parametrize("query_block", [4, 5, 64])
+def test_query_blocks_do_not_change_the_result(query_block, monkeypatch):
+    from deeplearning4j_tpu.nn.layers import attention
+
+    conf = L.GroupedQueryAttentionLayer(
+        n_in=16, n_out=16, n_heads=4, n_kv_heads=2, head_dim=8,
+        weight_init="xavier")
+    params = init_layer_params(jax.random.PRNGKey(0), conf, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 13, 16))
+    f = lambda p: jnp.sum(jnp.sin(forward_layer(
+        conf, p, x, LayerContext())[0]))
+    want, want_g = jax.value_and_grad(f)(params)     # 13 queries: one block
+    monkeypatch.setattr(attention, "QUERY_BLOCK", query_block)
+    got, got_g = jax.value_and_grad(f)(params)
+    assert abs(float(got) - float(want)) < 1e-4
+    for k in want_g:
+        assert _rel(got_g[k], want_g[k]) < 1e-5
+    # key-value head j serves query heads 2j and 2j + 1: against the
+    # reference's attention with repeated heads
+    z = {"heads": 4, "kv": 2, "hd": 8}
+    theirs = ref.attention(params, x, z, "f32")
+    assert _rel(forward_layer(conf, params, x, LayerContext())[0],
+                theirs) < 1e-5
+
+
+@pytest.mark.parametrize("conf", [
+    L.Mamba2Layer(n_in=16, n_out=16, n_heads=4, head_dim=8, state_size=8,
+                  n_groups=2, chunk_size=8, weight_init="xavier"),
+    L.SparseExpertsLayer(n_in=16, n_out=16, router_width=4, width=8,
+                         activation="relu2", weight_init="xavier"),
+], ids=["mamba2", "sparseexperts"])
+def test_a_time_mask_is_refused_not_ignored(conf):
+    params = init_layer_params(jax.random.PRNGKey(0), conf, jnp.float32)
+    with pytest.raises(NotImplementedError, match="mask"):
+        forward_layer(conf, params, jnp.ones((2, 8, 16)),
+                      LayerContext(mask=jnp.ones((2, 8))))
+
+
+# -- norm, activation, loss ---------------------------------------------------------
+
+def test_rms_norm_and_its_groups():
+    conf = L.RMSNorm(n_in=12, eps=1e-5)
+    params = {"gamma": jnp.arange(1.0, 13.0)}
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 12)) * 4.0
+    y, _ = forward_layer(conf, params, x, LayerContext())
+    want = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5) \
+        * params["gamma"]
+    assert jnp.allclose(y, want, atol=1e-5)
+    from deeplearning4j_tpu.nn.layers.norm import rms_normalize
+
+    grouped = rms_normalize(x, params["gamma"], 1e-5, groups=3)  # the mixer's
+    xg = x.reshape(3, 5, 3, 4)
+    want = (xg / jnp.sqrt(jnp.mean(xg * xg, -1, keepdims=True) + 1e-5)
+            ).reshape(3, 5, 12) * params["gamma"]
+    assert jnp.allclose(grouped, want, atol=1e-5)
+    # statistics in float32 whatever the input: a bf16 input comes back bf16
+    assert forward_layer(conf, params, x.astype(jnp.bfloat16),
+                         LayerContext())[0].dtype == jnp.bfloat16
+
+
+def test_relu2_is_registered():
+    from deeplearning4j_tpu.ops.activations import apply_activation
+
+    x = jnp.asarray([-2.0, 0.0, 3.0])
+    assert jnp.array_equal(apply_activation("relu2", x),
+                           jnp.asarray([0.0, 0.0, 9.0]))
+
+
+def test_sparse_mcxent_is_mcxent_on_the_one_hot():
+    assert LossFunction.SPARSE_MCXENT == "sparse_mcxent"
+    key = jax.random.PRNGKey(0)
+    z = jax.random.normal(key, (6, 10)) * 3.0
+    y = jax.random.randint(jax.random.PRNGKey(1), (6,), 0, 10)
+    sparse = loss_value("sparse_mcxent", y, z, "softmax")
+    dense = loss_value("mcxent", jax.nn.one_hot(y, 10), z, "softmax")
+    assert jnp.allclose(sparse, dense, atol=1e-6)
+    # over a sequence it is the mean over time where mcxent is the sum
+    zt = jax.random.normal(key, (3, 7, 10))
+    yt = jax.random.randint(jax.random.PRNGKey(2), (3, 7), 0, 10)
+    sparse = loss_value("sparse_mcxent", yt, zt, "softmax")
+    dense = loss_value("mcxent", jax.nn.one_hot(yt, 10), zt, "softmax")
+    assert sparse.shape == (3,)
+    assert jnp.allclose(sparse, dense / 7.0, atol=1e-6)
+    # a mask leaves positions out of the mean
+    mask = jnp.asarray([[1.0] * 7, [1.0] * 3 + [0.0] * 4, [0.0] * 7])
+    masked = loss_value("sparse_mcxent", yt, zt, "softmax", mask)
+    per = -jnp.take_along_axis(jax.nn.log_softmax(zt), yt[..., None],
+                               -1)[..., 0]
+    assert jnp.allclose(masked[1], jnp.mean(per[1, :3]), atol=1e-6)
+    assert float(masked[2]) == 0.0
+    with pytest.raises(TypeError, match="integer labels"):
+        loss_value("sparse_mcxent", jax.nn.one_hot(y, 10), z, "softmax")
+
+
+@pytest.mark.parametrize("rows_block", [1, 2, 4])
+def test_the_head_in_blocks_of_rows_is_the_head_at_once(rows_block):
+    feats = jax.random.normal(jax.random.PRNGKey(0), (4, 6, 8))
+    params = {"W": jax.random.normal(jax.random.PRNGKey(1), (8, 11))}
+    y = jax.random.randint(jax.random.PRNGKey(2), (4, 6), 0, 11)
+    whole = lambda p, f: jnp.mean(loss_value(
+        "sparse_mcxent", y, jnp.einsum("bti,io->bto", f, p["W"]), "softmax"))
+    blocks = lambda p, f: jnp.mean(sparse_head_loss(
+        f, p, y, rows_block=rows_block))
+    want, want_g = jax.value_and_grad(whole, argnums=(0, 1))(params, feats)
+    got, got_g = jax.value_and_grad(blocks, argnums=(0, 1))(params, feats)
+    assert abs(float(got) - float(want)) < 1e-6
+    assert _rel(got_g[0]["W"], want_g[0]["W"]) < 1e-5
+    assert _rel(got_g[1], want_g[1]) < 1e-5
+    with pytest.raises(ValueError, match="does not divide"):
+        sparse_head_loss(feats, params, y, rows_block=3)
+
+
+def test_the_sequential_engine_refuses_a_head_in_blocks():
+    """`head_rows_block` is ComputationGraph's: MultiLayerNetwork says so
+    rather than taking the whole batch's logits in silence."""
+    from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    conf = (NeuralNetConfiguration.builder().list()
+            .layer(L.EmbeddingSequenceLayer(n_in=11, n_out=8))
+            .layer(L.RnnOutputLayer(n_in=8, n_out=11, activation="softmax",
+                                    loss="sparse_mcxent", has_bias=False,
+                                    head_rows_block=1))
+            .build())
+    with pytest.raises(ValueError, match="ComputationGraph only"):
+        MultiLayerNetwork(conf)
+
+
+# -- recomputation -------------------------------------------------------------------
+
+def test_recomputation_changes_neither_loss_nor_gradients():
+    on, _ = _net(recompute=True)
+    off, _ = _net(recompute=False)
+    assert on.conf.recompute == [[f"b{i}_norm", f"b{i}_mixer", f"b{i}_add"]
+                                 for i in range(4)]
+    assert off.conf.recompute is None
+    x, y = _tokens(3)
+    a, ga = _program_loss_and_grads(on, x, y)
+    b, gb = _program_loss_and_grads(off, x, y)
+    assert abs(float(a) - float(b)) < 1e-6
+    for layer in ga:
+        for name in ga[layer]:
+            assert _rel(ga[layer][name], gb[layer][name]) < 1e-5
+
+    def text(net):
+        return str(jax.make_jaxpr(lambda p: net._loss(
+            p, net.state_list, [jnp.asarray(x)], [jnp.asarray(y)], None,
+            None, None)[0])(net.params_list))
+
+    # the head's blocks of rows and the attention's query blocks are
+    # checkpointed either way; the blocks' runs are what `recompute` adds
+    count = lambda net: len(re.findall(r"\b(?:remat2?|checkpoint)\[",
+                                       text(net)))
+    assert count(on) >= count(off) + 4
+
+
+def test_a_recompute_run_that_is_no_run_is_refused():
+    from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+
+    conf = tiny_nemotron_h_conf(recompute=False)
+    conf.recompute = [["b0_norm", "b0_add"]]       # skips the mixer
+    with pytest.raises(ValueError, match="not consecutive"):
+        ComputationGraph(conf).init()._recompute_runs()
+    conf.recompute = [["final_norm", "head"]]
+    with pytest.raises(ValueError, match="output vertex"):
+        ComputationGraph(conf).init()._recompute_runs()
+    gb = NeuralNetConfiguration.builder().graph_builder().add_inputs("in")
+    with pytest.raises(ValueError, match="not vertices"):
+        gb.recompute("nowhere")
+
+
+# recorded from the parent of PR 28 (commit a8caab7) in this container, under
+# the suite's `jax_default_matmul_precision` "highest": the whole train
+# step's jaxpr, addresses stripped, of presets that predate the
+# recompute option, the integer pass-through of `cast_input`, the bias-free
+# RnnOutputLayer and `LayerContext.compute_dtype`
+_PARENT_JAXPRS = {
+    "tiny_resnet": ("1b813e1331c3a034", 1795),
+    "tiny_resnet_bf16": ("28ff657301598c75", 2484),
+    "vgg16_32": ("5525cfe594d23c67", 2165),
+    "char_lstm": ("8510a47a41b1083b", 459),
+}
+
+
+def _preset(name):
+    from deeplearning4j_tpu.models import charlstm
+    from deeplearning4j_tpu.models.resnet import tiny_resnet_conf
+    from deeplearning4j_tpu.models.vgg16 import vgg16_conf
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    if name.startswith("tiny_resnet"):
+        conf = tiny_resnet_conf(
+            precision="bf16" if name.endswith("bf16") else "f32")
+        return ComputationGraph(conf).init(), {"batch_size": 2}
+    if name == "vgg16_32":
+        return MultiLayerNetwork(vgg16_conf(
+            num_classes=10, image_size=32, precision="bf16")).init(), \
+            {"batch_size": 2}
+    return MultiLayerNetwork(charlstm.char_lstm_conf(
+        vocab_size=11, hidden=8, layers=1)).init(), \
+        {"batch_size": 2, "timesteps": 5}
+
+
+@pytest.mark.parametrize("name", sorted(_PARENT_JAXPRS))
+def test_existing_presets_step_programs_are_what_they_were(name):
+    from deeplearning4j_tpu.analysis.costmodel import train_step_args
+
+    net, kw = _preset(name)
+    step, args = train_step_args(net, **kw)
+    text = str(jax.make_jaxpr(step)(*args))
+    assert "checkpoint" not in text and "remat" not in text
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    digest = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert (digest, text.count("\n")) == _PARENT_JAXPRS[name]
+
+
+# -- the feed --------------------------------------------------------------------------
+
+def test_integer_inputs_pass_the_precision_policy_uncast():
+    from deeplearning4j_tpu.common.dtypes import tpu_policy
+
+    policy = tpu_policy()
+    ids = jnp.asarray([[16383, 257, 0]], jnp.int32)
+    assert policy.cast_input(ids) is ids
+    # what the cast did before: bf16 holds 8 bits of mantissa
+    assert int(ids.astype(jnp.bfloat16)[0, 0]) == 16384
+    assert int(ids.astype(jnp.bfloat16)[0, 1]) == 256
+    images = jnp.ones((1, 2, 2, 3), jnp.float32)
+    assert policy.cast_input(images).dtype == jnp.bfloat16
+    assert policy.cast_input(images.astype(jnp.bfloat16)).dtype \
+        == jnp.bfloat16
+
+
+def test_the_embedding_refuses_ids_that_were_cast():
+    conf = L.EmbeddingSequenceLayer(n_in=10, n_out=4, weight_init="xavier")
+    params = init_layer_params(jax.random.PRNGKey(0), conf, jnp.float32)
+    out, _ = forward_layer(conf, params, jnp.asarray([[1, 9], [0, 3]]),
+                           LayerContext())
+    assert out.shape == (2, 2, 4)
+    assert jnp.array_equal(out[0, 1], params["W"][9])
+    with pytest.raises(TypeError, match="integer ids"):
+        forward_layer(conf, params, jnp.ones((2, 2), jnp.bfloat16),
+                      LayerContext())
+
+
+def test_fit_on_an_int32_pool_keeps_dtypes_and_counts_rows(monkeypatch):
+    """Through `async_prefetch=True` (the staged pipeline) under the bf16
+    policy: what reaches the step is int32 on both sides, ids above 256
+    included, and `fit_examples_total` counts sequences."""
+    net, _ = _net("bf16")
+    seen = []
+    real = net._fit_step
+
+    def spy(xs, ys, *a, **kw):
+        seen.append((xs[0].dtype, ys[0].dtype, int(jnp.max(xs[0]))))
+        return real(xs, ys, *a, **kw)
+
+    monkeypatch.setattr(net, "_fit_step", spy)
+    x, y = _tokens(5)
+    x[0, 0] = 127
+    before = get_registry().scalar_values().get("fit_examples_total", 0.0)
+    net.fit(ListDataSetIterator(DataSet(x, y), BATCH), epochs=3,
+            async_prefetch=True)
+    after = get_registry().scalar_values()["fit_examples_total"]
+    assert after - before == 3 * BATCH
+    assert seen == [(jnp.int32, jnp.int32, 127)] * 3
+    assert np.isfinite(float(net._score))
+
+
+def test_training_lowers_the_loss():
+    net, _ = _net()
+    x, y = _tokens(6)
+    it = ListDataSetIterator(DataSet(x, y), BATCH)
+    net.fit(it, epochs=1)
+    first = float(net._score)
+    net.fit(it, epochs=15)
+    assert float(net._score) < first - 0.05
+
+
+# -- configuration: serde, shapes, doctor ------------------------------------------------
+
+def test_serde_round_trip_and_shapes():
+    from deeplearning4j_tpu.nn.conf import ComputationGraphConfiguration
+    from deeplearning4j_tpu.nn.conf.inputs import RecurrentInput
+    from deeplearning4j_tpu.analysis import shapeflow
+
+    conf = tiny_nemotron_h_conf()
+    back = ComputationGraphConfiguration.from_json(conf.to_json())
+    assert back.to_json() == conf.to_json()
+    assert back.recompute == conf.recompute
+    kinds = {type(v.layer).__name__ for v in back.vertices.values()
+             if hasattr(v, "layer")}
+    assert kinds == {"EmbeddingSequenceLayer", "RMSNorm", "Mamba2Layer",
+                     "SparseExpertsLayer", "GroupedQueryAttentionLayer",
+                     "RnnOutputLayer"}
+    # n_in inferred from the InputType: the vocabulary for the embedding
+    assert back.vertices["embed"].layer.n_in == 128
+    assert back.vertices["b0_norm"].layer.n_in == 64
+    assert back.vertices["b1_mixer"].layer.n_in == 64
+    types = shapeflow.propagate_types(conf)
+    assert types["embed"] == RecurrentInput(64, SEQ)
+    assert types["b2_mixer"] == RecurrentInput(64, SEQ)
+    assert types["head"] == RecurrentInput(128, SEQ)
+    a = ComputationGraph(conf).init()
+    b = ComputationGraph(back).init()
+    for p, q in zip(a.params_list, b.params_list):
+        for k in p:
+            assert jnp.array_equal(p[k], q[k])
+
+
+def test_doctor_is_clean_and_the_cost_model_traces_the_step():
+    from deeplearning4j_tpu.analysis.costmodel import train_step_cost
+
+    net, _ = _net()
+    findings = net.doctor(batch_size=2)
+    assert [f for f in findings if f.severity == "error"] == [], findings
+    cm = train_step_cost(net, batch_size=2)
+    assert cm.param_bytes == 4 * net.num_params()
+    assert cm.flops_total > 0
+
+
+def test_layer_scopes_are_underscore_free_kinds():
+    from deeplearning4j_tpu.nn.multilayer import layer_scope
+
+    conf = tiny_nemotron_h_conf()
+    scopes = {layer_scope(n, v.layer) for n, v in conf.vertices.items()
+              if hasattr(v, "layer")}
+    assert {"Lembed_embeddingsequence", "Lb0_norm_rmsnorm",
+            "Lb0_mixer_mamba2", "Lb1_mixer_sparseexperts",
+            "Lb2_mixer_groupedqueryattention", "Lhead_rnnoutput"} <= scopes
+    net, _ = _net()
+    x, y = _tokens(7)
+    text = net._build_train_step().lower(
+        net.params_list, net.state_list, net.upd_state, [jnp.asarray(x)],
+        [jnp.asarray(y)], None, None, jnp.float32(1e-3), jnp.float32(0.0),
+        jax.random.PRNGKey(0)).as_text(debug_info=True)
+    for scope in ("Lb0_mixer_mamba2/ssd_scan", "Lb1_mixer_sparseexperts/"
+                  "experts", "Lb1_mixer_sparseexperts/router",
+                  "Lb1_mixer_sparseexperts/shared_expert",
+                  "loss)/Lhead_rnnoutput"):
+        assert scope in text, scope
+
+
+def test_the_factory_refuses_what_it_cannot_build():
+    with pytest.raises(ValueError, match="blocks are of"):
+        nemotron_h_conf(hybrid_override_pattern="MXM")
+    with pytest.raises(ValueError, match="experts held here"):
+        tiny_nemotron_h_conf(experts_held=[0, 1, 2])
+    with pytest.raises(ValueError, match="distinct experts"):
+        ComputationGraph(tiny_nemotron_h_conf(
+            experts_held=[0, 1, 2, 3, 4, 5, 6, 99])).init()
