@@ -585,10 +585,14 @@ class SparseExpertsLayer(BaseRecurrentLayerConf):
     rows takes the exact dense path (`tokens / rows` times the products) and
     is counted (`experts_overflow_total`). The default is sized so that such
     steps are rare without load balancing: on uniform tokens the fullest
-    held expert met 4.7 times the uniform mean within 24 steps of training
-    (PERF.md, PR 28). The layer's books (tokens routed to each expert,
-    overflow, peak load) are layer state, published where the fit loop
-    already blocks."""
+    held expert met 5.4 times the uniform mean within 27 steps of training
+    (4,159 of 6,144 rows, `experts_buffer_fill` 0.68; PERF.md, PR 28), and
+    the held experts of the worst layer together 3.5 times theirs, so that
+    one buffer shared by the held experts would need nearly the same rows
+    (PERF.md, PR 29, which also says why `lax.ragged_dot` over such a
+    buffer did not replace this one). The layer's books (tokens routed to
+    each expert, overflow, peak load and the buffer's rows) are layer
+    state, published where the fit loop already blocks."""
 
     router_width: int = 8
     experts_held: Optional[List[int]] = None   # None: all of them

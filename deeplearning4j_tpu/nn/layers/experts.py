@@ -17,9 +17,17 @@ held expert is sent more than `capacity` tokens takes the exact path instead
 counted, never silent. The layer's books are layer state (`routed`: tokens
 sent to each of the `router_width` experts; `overflow`: assignments beyond
 the buffer, each served by the exact path; `peak`: the fullest held expert's
-load in one step), carried like batch norm's running statistics and
-published by `publish_expert_books`, the kind's publish hook (the net calls
-it where utils/devprof already blocks, and at the end of `fit()`).
+load in one step, of the `rows` its buffer has), carried like batch norm's
+running statistics and published by `publish_expert_books`, the kind's
+publish hook (the net calls it where utils/devprof already blocks, and at
+the end of `fit()`).
+
+The grouped path stays inside the conditional, beside the exact path, and
+the buffers stay one per held expert: on the chip the same path outside the
+`lax.cond` ran 27 ms a step slower (the compiler then fuses the optimizer's
+update into the weight-gradient products and lays the buffers out worse),
+and one buffer shared by the held experts under `lax.ragged_dot` won only at
+half the rows, which the fullest layer fills to 86% (PERF.md, PR 29).
 """
 
 from __future__ import annotations
@@ -84,7 +92,8 @@ def experts_state(conf: L.SparseExpertsLayer, dtype):
     2**31 only after 131,072 unpublished steps of 16,384 tokens."""
     return {"routed": jnp.zeros((int(conf.router_width),), jnp.int32),
             "overflow": jnp.zeros((), jnp.int32),
-            "peak": jnp.zeros((), jnp.int32)}
+            "peak": jnp.zeros((), jnp.int32),
+            "rows": jnp.zeros((), jnp.int32)}
 
 
 def route(conf: L.SparseExpertsLayer, scores):
@@ -122,30 +131,43 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
             xf.astype(jnp.float32), params["W_router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
         idx, w = route(conf, scores)
-        # where each assignment goes: its expert's slot here (-1: held
-        # elsewhere) and its rank among that expert's assignments
-        slot_of = np.full((int(conf.router_width),), -1, np.int32)
-        slot_of[held] = np.arange(n_held, dtype=np.int32)
-        local = jnp.asarray(slot_of)[idx].reshape(-1)              # [T k]
-        onehot = local[:, None] == jnp.arange(n_held, dtype=jnp.int32)
+        # where each assignment goes: the slot of its expert here (none:
+        # held elsewhere) and its rank among that expert's assignments.
+        # By comparison and running sum, not by table look-up: a gather or
+        # a scatter over the 98,304 assignments of the Nemotron cell takes
+        # the chip 0.5-0.8 ms, an elementwise pass a few microseconds
+        flat = idx.reshape(-1)                                     # [T k]
+        onehot = flat[:, None] == jnp.asarray(held, jnp.int32)
+        mine = jnp.any(onehot, axis=1)
+        local = jnp.sum(jnp.where(onehot, jnp.arange(
+            n_held, dtype=jnp.int32), 0), axis=1)
         rank = jnp.sum(jnp.where(onehot, jnp.cumsum(
             onehot.astype(jnp.int32), axis=0) - 1, 0), axis=1)
-        kept = (local >= 0) & (rank < cap)
-        # dropped and foreign assignments all land in one spare row
+        kept = mine & (rank < cap)
+        # dropped and foreign assignments all land in one spare row. One
+        # scatter says which assignment fills each slot, as its token and
+        # which of the token's k choices it is, packed into one integer
+        # (shifts: the chip divides integers slowly); its weight follows
+        # by one gather
         dest = jnp.where(kept, local * cap + rank, n_held * cap)
-        token_of = jnp.repeat(jnp.arange(tokens, dtype=jnp.int32), k)
-        spare = n_held * cap + 1
-        slot_token = jnp.zeros((spare,), jnp.int32).at[dest].set(
-            token_of)[:-1]
-        slot_w = jnp.zeros((spare,), jnp.float32).at[dest].set(
-            jnp.where(kept, w.reshape(-1), 0.0))[:-1]
-        overflow = jnp.sum((local >= 0) & (rank >= cap), dtype=jnp.int32)
+        bits = max(1, (k - 1).bit_length())
+        label = (jnp.arange(tokens, dtype=jnp.int32)[:, None] << bits) \
+            | jnp.arange(k, dtype=jnp.int32)
+        slot = jnp.full((n_held * cap + 1,), -1, jnp.int32).at[dest].set(
+            label.reshape(-1))[:-1]
+        filled = slot >= 0
+        slot = jnp.maximum(slot, 0)
+        slot_token = slot >> bits
+        slot_w = jnp.where(filled, w.reshape(-1)[
+            slot_token * k + (slot & ((1 << bits) - 1))], 0.0)
+        overflow = jnp.sum(mine & (rank >= cap), dtype=jnp.int32)
         books = {
-            "routed": jnp.sum(idx.reshape(-1)[:, None] == jnp.arange(
+            "routed": jnp.sum(flat[:, None] == jnp.arange(
                 int(conf.router_width), dtype=jnp.int32), axis=0,
                 dtype=jnp.int32),
             "overflow": overflow,
-            "peak": jnp.max(jnp.sum(onehot, axis=0, dtype=jnp.int32))}
+            "peak": jnp.max(jnp.sum(onehot, axis=0, dtype=jnp.int32)),
+            "rows": jnp.asarray(cap, jnp.int32)}
 
     w1, w2 = params["W1"].astype(cd), params["W2"].astype(cd)
 
@@ -186,7 +208,8 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
     if state is not None:
         books = {"routed": state["routed"] + books["routed"],
                  "overflow": state["overflow"] + books["overflow"],
-                 "peak": jnp.maximum(state["peak"], books["peak"])}
+                 "peak": jnp.maximum(state["peak"], books["peak"]),
+                 "rows": jnp.maximum(state["rows"], books["rows"])}
     return y.reshape(shape).astype(x.dtype), books
 
 
@@ -210,6 +233,12 @@ def _instruments():
             "worst layer's and step's since the books were last published "
             "(above the buffer's rows that step took the exact path)"
             ).labels(),
+        "fill": reg.gauge(
+            "experts_buffer_fill",
+            "experts_peak_load over the rows of one held expert's buffer, "
+            "the worst layer's and step's since the books were last "
+            "published: how near the layer came to the exact path (above "
+            "1 it took it)").labels(),
         "load": reg.gauge(
             "experts_load_max_over_mean",
             "the fullest held expert's assignments over the mean of the "
@@ -225,9 +254,11 @@ def publish_expert_books(confs, books) -> Dict[str, float]:
     already blocks (devprof's sampled steps, the end of `fit()`), never on
     a plain step, and zeroes the books afterwards."""
     held_n = foreign_n = overflow = peak = 0
+    fill = 0.0
     loads: List[np.ndarray] = []
     for conf, b in zip(confs, books):
         peak = max(peak, int(b["peak"]))
+        fill = max(fill, int(b["peak"]) / max(int(b["rows"]), 1))
         routed = np.asarray(b["routed"], np.int64)
         mine = routed[conf.held()]
         held_n += int(mine.sum())
@@ -239,8 +270,9 @@ def publish_expert_books(confs, books) -> Dict[str, float]:
     ins["assignments"].labels("0").inc(foreign_n)
     ins["overflow"].inc(overflow)
     out = {"held": held_n, "foreign": foreign_n, "overflow": overflow,
-           "peak": peak}
+           "peak": peak, "fill": fill}
     ins["peak"].set(peak)
+    ins["fill"].set(fill)
     if held_n:
         ratio = max(float(m.max()) / max(float(m.mean()), 1e-30)
                     for m in loads if m.sum())
@@ -250,10 +282,11 @@ def publish_expert_books(confs, books) -> Dict[str, float]:
         import logging
 
         logging.getLogger("deeplearning4j_tpu").warning(
-            "SparseExpertsLayer: %d assignments beyond the buffer's "
-            "capacity (experts_overflow_total); none was dropped, their "
-            "steps took the exact dense path. A larger capacity_factor "
-            "makes such steps rarer", overflow)
+            "SparseExpertsLayer: %d assignments beyond a held expert's "
+            "buffer (experts_overflow_total; the fullest expert was sent "
+            "%.2f times its rows, experts_buffer_fill); none was dropped, "
+            "their steps took the exact dense path. A larger "
+            "capacity_factor makes such steps rarer", overflow, fill)
     return out
 
 

@@ -271,8 +271,71 @@ def test_an_overflowing_capacity_is_counted_and_nothing_is_dropped():
     held = int(books["routed"][:8].sum())
     assert held > 0 and int(books["peak"]) == 256
     assert int(books["overflow"]) == held - 128 * (held // 256) > 0
+    assert int(books["rows"]) == 128
     _, books = forward_layer(roomy, params, x, LayerContext(state=state))
     assert int(books["overflow"]) == 0 and 0 < int(books["peak"]) <= 256
+    # the books add up over steps: counts summed, loads at their largest
+    _, twice = forward_layer(tight, params, same, LayerContext(state=books))
+    assert int(twice["routed"].sum()) == 2 * 256 * 3
+    assert int(twice["overflow"]) == held - 128 * (held // 256)
+    assert int(twice["peak"]) == 256 and int(twice["rows"]) == 256
+
+
+def _skewed(skew):
+    """The layer's weights and tokens under one routing: feature 0 of every
+    token is 5 and the router's row 0 is 0, or -10 for an expert that no
+    token is to choose. 16 routed experts, 8 held."""
+    held = [9, 2, 3, 12, 5, 0, 15, 7] if skew == "held_in_any_order" \
+        else list(range(8))
+    conf, params = _expert_layer(held, seed=3)
+    x = jax.random.normal(jax.random.PRNGKey(11), (2, 48, 32))
+    x = x.at[..., 0].set(5.0)
+    if skew == "same_experts":
+        x = x.at[:, :, :].set(x[:1, :1])
+    unchosen = {"one_held_idle": [3], "none_held_chosen": held}.get(skew, [])
+    router = params["W_router"].at[0].set(0.0).at[
+        0, jnp.asarray(unchosen, jnp.int32)].set(-10.0)
+    return conf, dict(params, W_router=router), x
+
+
+@pytest.mark.parametrize("factor", [8.0, 0.25])
+@pytest.mark.parametrize("skew", ["uniform", "same_experts", "one_held_idle",
+                                  "none_held_chosen", "held_in_any_order"])
+def test_the_layer_is_the_reference_under_any_skew(skew, factor, monkeypatch):
+    """Value and gradients against the plain reference's masked loop, with
+    buffers that hold everything (8.0: the grouped path) and buffers that
+    do not (0.25: the exact path), whatever the routing sends the held
+    experts and in whatever order they are held."""
+    monkeypatch.setattr(X, "_ROWS", 8)
+    conf, params, x = _skewed(skew)
+    conf.capacity_factor = factor
+    held = conf.held()
+    config = dict(TINY, hidden_size=32, moe_intermediate_size=24,
+                  moe_shared_expert_intermediate_size=40, experts_held=held)
+    z = ref._sizes(config)
+    state = init_layer_state(conf, jnp.float32)
+    _, books = forward_layer(conf, params, x, LayerContext(state=state))
+    loads = np.asarray(books["routed"])[held]
+    assert {"uniform": loads.min() > 0, "held_in_any_order": loads.min() > 0,
+            "same_experts": 0 < (loads > 0).sum() <= 3,
+            "one_held_idle": loads[3] == 0 and loads.sum() > 0,
+            "none_held_chosen": loads.sum() == 0}[skew]
+    cap = X.expert_capacity(conf, 96)
+    assert cap == (96 if factor == 8.0 else 8) == int(books["rows"])
+    assert int(books["peak"]) == loads.max()
+    assert int(books["overflow"]) == np.maximum(loads - cap, 0).sum()
+    assert (int(books["overflow"]) > 0) == (factor < 1 and loads.sum() > 0)
+    mine = lambda p, a: jnp.sum(jnp.sin(forward_layer(
+        conf, p, a, LayerContext())[0]))
+    theirs = lambda p, a: jnp.sum(jnp.sin(ref.experts(p, a, z, config,
+                                                      "f32")))
+    got, got_g = jax.value_and_grad(mine, argnums=(0, 1))(params, x)
+    want, want_g = jax.value_and_grad(theirs, argnums=(0, 1))(params, x)
+    assert abs(float(got) - float(want)) < 1e-4 * abs(float(want)) + 1e-4
+    for k in want_g[0]:
+        assert jnp.linalg.norm(got_g[0][k] - want_g[0][k]) \
+            < 1e-4 * jnp.linalg.norm(want_g[0][k]) + 1e-5, k
+    assert _rel(got_g[1], want_g[1]) < 1e-4
 
 
 def test_the_books_reach_the_registry_at_the_end_of_fit():
@@ -288,6 +351,9 @@ def test_the_books_reach_the_registry_at_the_end_of_fit():
     assert moved('experts_assignments_total{held="1"}') > 0
     assert moved("experts_overflow_total") == 0
     assert after["experts_load_max_over_mean"] >= 1.0
+    # how near the fullest held expert came to its buffer's 128 rows
+    assert 0.0 < after["experts_buffer_fill"] <= 1.0
+    assert after["experts_buffer_fill"] == after["experts_peak_load"] / 128
     # published means zeroed: the next fit counts from nought
     (slot,), = net._book_slots.values()
     assert int(net.state_list[slot]["routed"].sum()) == 0
