@@ -3,8 +3,9 @@ of the device performance/memory observability layer (utils/devprof.py
 is the runtime half; each checks the other).
 
 One `jax.make_jaxpr` of the FULL optimizer step (loss + backward +
-updater — the same body every step jit uses, via `_make_step_body`) and
-a walk over the program produces, per primitive family:
+updater — the same body every step jit uses, nn/trainstep's
+`_make_step_body`) and a walk over the program produces, per primitive
+family:
 
 * **FLOPs** under HLO cost-analysis accounting: matmuls are 2·M·N·K,
   convolutions count only the *valid* (output, kernel-tap) pairs — SAME
@@ -544,10 +545,11 @@ def _tree_bytes(tree) -> int:
 
 def train_step_args(net, *, batch_size: int = 8, timesteps: int = 16):
     """(step_fn, args) of the FULL optimizer step — the same body every
-    step jit compiles (`_make_step_body`: loss, backward, gradient
-    normalization, updater, param update) on an abstract batch shaped
-    from the conf's InputTypes via shapeflow. Shared by the cost model
-    and the XLA cross-check so both sides measure the same program.
+    step jit compiles (nn/trainstep's `_make_step_body`: loss, backward,
+    gradient normalization, updater, param update), with its signature
+    `(params, states, upd_state, data, lr, t, rng)`, on an abstract batch
+    shaped from the conf's InputTypes via shapeflow. Shared by the cost
+    model and the XLA cross-check so both sides measure the same program.
     Raises ValueError when the conf has no InputType to shape a batch."""
     import jax.numpy as jnp
 
@@ -560,8 +562,6 @@ def train_step_args(net, *, batch_size: int = 8, timesteps: int = 16):
 
     net._require_init()
     conf = net.conf
-    rng = jax.random.PRNGKey(0)
-
     if isinstance(conf, MultiLayerConfiguration):
         x = _features_sds(conf.input_type, batch_size, timesteps)
         out_types = shapeflow.propagate_types(conf)
@@ -571,37 +571,25 @@ def train_step_args(net, *, batch_size: int = 8, timesteps: int = 16):
             raise ValueError(
                 "no InputType on the configuration — cannot shape an "
                 "abstract batch for the cost model")
-        body = net._make_step_body(net._std_loss_builder())
-
-        def step(params, states, upd_state, x, y, lr, t, rng):
-            return body(params, states, upd_state, (x, y, None, None),
-                        lr, t, rng)
-
-        args = (net.params_list, net.state_list, net.upd_state, x, y,
-                jnp.float32(0.1), jnp.float32(1.0), rng)
     else:
         if conf.input_types is None:
             raise ValueError(
                 "no InputTypes on the configuration — cannot shape an "
                 "abstract batch for the cost model")
-        xs = tuple(_features_sds(t, batch_size, timesteps)
-                   for t in conf.input_types)
+        x = tuple(_features_sds(t, batch_size, timesteps)
+                  for t in conf.input_types)
         types = shapeflow.propagate_types(conf)
-        ys = tuple(_labels_sds(types.get(name), batch_size, timesteps,
-                               getattr(conf.vertices[name], "layer", None))
-                   for name in conf.outputs)
-        if any(v is None for v in xs) or any(v is None for v in ys):
+        y = tuple(_labels_sds(types.get(name), batch_size, timesteps,
+                              getattr(conf.vertices[name], "layer", None))
+                  for name in conf.outputs)
+        if any(v is None for v in x) or any(v is None for v in y):
             raise ValueError(
                 "could not shape abstract features/labels from the "
                 "graph's InputTypes")
-        body = net._make_step_body()
-
-        def step(params, states, upd_state, xs, ys, lr, t, rng):
-            return body(params, states, upd_state, (xs, ys, None, None),
-                        lr, t, rng)
-
-        args = (net.params_list, net.state_list, net.upd_state, xs, ys,
-                jnp.float32(0.1), jnp.float32(1.0), rng)
+    step = net._make_step_body(net._std_loss_builder())
+    args = (net.params_list, net.state_list, net.upd_state,
+            (x, y, None, None), jnp.float32(0.1), jnp.float32(1.0),
+            jax.random.PRNGKey(0))
     return step, args
 
 
@@ -630,13 +618,13 @@ def _host_resident_bytes(net) -> Tuple[int, int]:
 
 def _model_of_step(net, step, args, batch_size: int) -> CostModel:
     """Trace + static memory bookkeeping shared by train_step_cost and
-    check_network (args[3:5] are the feature/label structs (MLN) or
-    tuples (graph))."""
+    check_network (args[3] is the batch: feature/label structs (MLN) or
+    tuples of them (graph))."""
     cm = cost_fn(step, *args, what=f"{type(net).__name__}:train_step")
     cm.batch = int(batch_size)
     cm.param_bytes = _tree_bytes(net.params_list)
     cm.updater_bytes = _tree_bytes(net.upd_state)
-    cm.data_bytes = _tree_bytes((args[3], args[4]))
+    cm.data_bytes = _tree_bytes(args[3])
     hp, hu = _host_resident_bytes(net)
     cm.host_resident_param_bytes = hp
     cm.host_resident_updater_bytes = hu

@@ -231,7 +231,7 @@ def audit_fn(fn, *example_args,
 def check_donation(donate_argnums: Tuple[int, ...],
                    backend: Optional[str] = None) -> List[Finding]:
     """JX006: on device backends the train step must donate its params
-    and updater-state buffers (netbase._make_step donates argnums 0 and
+    and updater-state buffers (trainstep._make_step donates argnums 0 and
     2) or peak memory holds both the old and new copies."""
     backend = backend or jax.default_backend()
     if backend == "cpu":
@@ -245,7 +245,7 @@ def check_donation(donate_argnums: Tuple[int, ...],
         f"donated on the {backend} backend — both old and new buffers "
         "are live across the update, doubling peak parameter memory",
         "jit the step with donate_argnums=(0, 2) as "
-        "nn/netbase._make_step does")]
+        "nn/trainstep._make_step does")]
 
 
 # -- network-level audit ------------------------------------------------------
@@ -392,7 +392,7 @@ def audit_network(net, *, batch_size: int = 2, timesteps: int = 8,
 
     # donation policy of the step this loss will be jitted into: audit
     # the value the net's step builders RECORDED (every jit site calls
-    # netbase._step_donate_argnums) — if no step was built yet, calling
+    # trainstep._step_donate_argnums) — if no step was built yet, calling
     # the same helper records and returns what the first build will use
     donate = getattr(net, "_donate_argnums", None)
     if donate is None:

@@ -57,11 +57,7 @@ from deeplearning4j_tpu.ops.losses import (
     sparse_head_loss,
 )
 from deeplearning4j_tpu.train.evaluation import Evaluation
-from deeplearning4j_tpu.train.updaters import (
-    normalize_gradients,
-    schedule_lr,
-    updater_from_conf,
-)
+from deeplearning4j_tpu.train.updaters import updater_from_conf
 
 
 def _head_in_blocks(lc) -> bool:
@@ -253,7 +249,6 @@ class ComputationGraph(NetworkBase):
         self._layer_confs: List[L.LayerConf] = [
             conf.vertices[n].layer for n in self.layer_vertex_names
         ]
-        self._train_step_fn = None
         self._output_fn = None
         self._block_scan = None  # None = DL4J_BLOCK_SCAN env decides
         self._block_runs_cache = None
@@ -623,9 +618,6 @@ class ComputationGraph(NetworkBase):
                     lambda a, u=u: a[u], nsj)
         return exit_act, updates
 
-    def _merge_states(self, old, new):
-        return [n if n is not None else o for o, n in zip(old, new)]
-
     # -- loss ----------------------------------------------------------------
 
     def _loss(self, params, states, xs, ys, f_masks, l_masks, rng, training=True):
@@ -694,182 +686,6 @@ class ComputationGraph(NetworkBase):
             return score + _l1_l2_penalty(self._layer_confs, params), \
                 new_states
 
-    # -- train step ----------------------------------------------------------
-
-    def _lr_mult_tree(self):
-        base = self.net_conf.learning_rate
-        out = []
-        for lc, p in zip(self._layer_confs, self.params_list):
-            inner = lc.inner if isinstance(lc, L.FrozenLayer) else lc
-            layer_lr = getattr(inner, "learning_rate", None)
-            bias_lr = getattr(inner, "bias_learning_rate", None)
-            mult = {}
-            for name in p:
-                if name == "b" and bias_lr is not None:
-                    mult[name] = bias_lr / base
-                elif layer_lr is not None:
-                    mult[name] = layer_lr / base
-                else:
-                    mult[name] = 1.0
-            out.append(mult)
-        return out
-
-    def _trainable_mask(self):
-        return [
-            {k: (0.0 if isinstance(lc, L.FrozenLayer) else 1.0) for k in p}
-            for lc, p in zip(self._layer_confs, self.params_list)
-        ]
-
-    @staticmethod
-    def _jas(lst):
-        """Optional list-of-optional-arrays -> device arrays (mask lists
-        may be None wholesale or per-entry)."""
-        if lst is None:
-            return None
-        return [None if a is None else jnp.asarray(a) for a in lst]
-
-    def _seeded_states(self):
-        """state_list copy with {} seeded for recurrent layers (the
-        TBPTT zero-state trigger, shared by the loop and fused paths)."""
-        states = list(self.state_list)
-        for i, lc in enumerate(self._layer_confs):
-            if _is_recurrent(lc) and states[i] is None:
-                states[i] = {}
-        return states
-
-    def _std_loss_builder(self):
-        def loss_builder(p, states, data, rng):
-            xs, ys, fms, lms = data
-            return self._loss(p, states, xs, ys, fms, lms, rng)
-
-        return loss_builder
-
-    def _trunc_loss_builder(self):
-        """TBPTT loss with tbptt_bwd_length < tbptt_fwd_length: slice A
-        advances state under stop_gradient (score counts, no gradient),
-        slice B backprops — same design as MultiLayerNetwork's
-        _trunc_loss_builder, generalized to multi-input/multi-output."""
-
-        def loss_builder(p, states, data, rng):
-            xsA, ysA, fmsA, lmsA, xsB, ysB, fmsB, lmsB = data
-            lossA, statesA = self._loss(p, states, xsA, ysA, fmsA, lmsA,
-                                        rng)
-            carried = self._merge_states(states, statesA)
-            carried = jax.tree_util.tree_map(jax.lax.stop_gradient, carried)
-            lossB, statesB = self._loss(
-                p, carried, xsB, ysB, fmsB, lmsB,
-                None if rng is None else jax.random.fold_in(rng, 1),
-            )
-            nA = max(x.shape[1] for x in xsA if x.ndim == 3)
-            nB = max(x.shape[1] for x in xsB if x.ndim == 3)
-            score = (
-                jax.lax.stop_gradient(lossA) * nA + lossB * nB
-            ) / (nA + nB)
-            return score, self._merge_states(carried, statesB)
-
-        return loss_builder
-
-    def _make_step_body(self, loss_builder=None, collect: bool = False):
-        """Unjitted optimizer-step body around a loss builder
-        (p, states, data, rng) -> (score, new_states) — same tail as
-        MultiLayerNetwork's: gradient masking/normalization, per-leaf lr,
-        updater, param update, plus the in-graph `[loss, grad_norm]`
-        divergence diagnostic returned next to the score (see the MLN
-        docstring). Shared by the single-step, truncated, fused-TBPTT
-        and multi-batch programs."""
-        if loss_builder is None:
-            loss_builder = self._std_loss_builder()
-        gnorm = self.net_conf.gradient_normalization
-        gthresh = self.net_conf.gradient_normalization_threshold
-        mults = self._lr_mult_tree()
-        tmask = self._trainable_mask()
-        updater = self.updater_def
-        minimize = self.net_conf.minimize
-        # in-graph bucketed gradient all-reduce under a mesh plan — same
-        # emission as MultiLayerNetwork._make_step_body (see the comment
-        # there; the schedule lives in parallel/sharded.CollectivePlan)
-        plan = self._mesh_plan
-
-        def step(params, states, upd_state, data, lr, t, rng):
-            def loss_fn(p):
-                return loss_builder(p, states, data, rng)
-
-            (score, new_states), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(params)
-            if plan is not None:
-                with jax.named_scope("reduce_grads"):
-                    grads = plan.reduce_grads(self, grads)
-            merged = self._merge_states(states, new_states)
-            with jax.named_scope("update"):
-                # global grad norm of the RAW gradient (before masking/
-                # clipping), accumulated in f32 — the sentinel diagnostic
-                gsq = jnp.float32(0.0)
-                for g in jax.tree_util.tree_leaves(grads):
-                    gsq = gsq + jnp.sum(jnp.square(g.astype(jnp.float32)))
-                diag = jnp.stack([score.astype(jnp.float32), jnp.sqrt(gsq)])
-                if not minimize:
-                    grads = jax.tree_util.tree_map(lambda g: -g, grads)
-                grads = [
-                    {k: g[k] * m[k] for k in g} for g, m in zip(grads, tmask)
-                ]
-                grads = normalize_gradients(grads, gnorm, gthresh)
-                lr_tree = [
-                    {k: lr * m[k] for k in g} for g, m in zip(grads, mults)
-                ]
-                updates, new_upd = updater.apply_tree(grads, upd_state,
-                                                      lr_tree, t)
-                new_params = jax.tree_util.tree_map(jnp.add, params, updates)
-                if collect:
-                    # per-layer mean |x| scalars for the stats pipeline
-                    # (reference: BaseStatsListener mean magnitudes)
-                    mm = lambda tree: [
-                        {k: jnp.mean(jnp.abs(v)) for k, v in p.items()}
-                        for p in tree
-                    ]
-                    stats = {"grad_mm": mm(grads), "update_mm": mm(updates),
-                             "param_mm": mm(new_params)}
-                    return new_params, merged, new_upd, score, diag, stats
-            return new_params, merged, new_upd, score, diag
-
-        return step
-
-    def _build_train_step(self):
-        body = self._make_step_body(
-            collect=bool(getattr(self, "_collect_stats", False)))
-
-        def step(params, states, upd_state, xs, ys, f_masks, l_masks,
-                 lr, t, rng):
-            return body(params, states, upd_state,
-                        (xs, ys, f_masks, l_masks), lr, t, rng)
-
-        return self._jit_step(step, data_argnums=(3, 4, 5, 6))
-
-    def _fit_step(self, xs, ys, f_masks, l_masks, stateful_states=None):
-        if self._train_step_fn is None:
-            self._train_step_fn = self._build_train_step()
-            self._note_compile("train_step")
-        lr = schedule_lr(self.net_conf, self.iteration)
-        rng = jax.random.fold_in(
-            jax.random.PRNGKey(self.net_conf.seed ^ 0x5EED), self.iteration
-        )
-        states = stateful_states if stateful_states is not None else self.state_list
-        out = self._train_step_fn(
-            self.params_list, states, self.upd_state,
-            [jnp.asarray(x) for x in xs], [jnp.asarray(y) for y in ys],
-            self._jas(f_masks), self._jas(l_masks),
-            jnp.asarray(lr, jnp.float32), jnp.asarray(float(self.iteration)),
-            rng,
-        )
-        params, states, upd, score = out[:4]
-        self._step_diag = out[4]
-        self._last_stats = out[5] if len(out) > 5 else None
-        self.params_list = params
-        self.upd_state = upd
-        self._score = score
-        self.iteration += 1
-        return states, score
-
     # -- fit -----------------------------------------------------------------
 
     def fit(self, data, labels=None, *, epochs: int = 1, batch_size: int = 32,
@@ -905,320 +721,21 @@ class ComputationGraph(NetworkBase):
                              resume_from=resume_from,
                              run_ledger=run_ledger)
 
+    def _batch_data(self, ds):
+        mds = _as_multidataset(ds)
+        return (mds.features, mds.labels, mds.features_masks,
+                mds.labels_masks)
+
     def _fit_dataset(self, ds):
         mds = _as_multidataset(ds)
-        if (
-            self.conf.backprop_type == "tbptt"
-            and any(f.ndim == 3 for f in mds.features)
-        ):
+        data = self._batch_data(mds)
+        if self._is_tbptt(data):
             self._fit_tbptt(mds)
             return
-        states, _ = self._fit_step(
-            mds.features, mds.labels, mds.features_masks, mds.labels_masks
-        )
+        states, _ = self._fit_step(*data)
         self.state_list = states
         self._notify(getattr(mds, "reported_examples", None)
                      or mds.num_examples(), mds)
-
-    # -- multi-batch fused fit (set_fused_steps) -----------------------------
-
-    def _fused_fit_supported(self) -> bool:
-        return True
-
-    def _fit_datasets_fused(self, ds_list):
-        """K same-shape minibatches in ONE jitted dispatch (see
-        NetworkBase.set_fused_steps). TBPTT graphs run per-batch — each
-        batch still fuses ALL its segments into one dispatch via
-        _fit_tbptt_fused; only the cross-batch stacking is MLN-only (the
-        MLN carries the recurrent benchmarks)."""
-        mds_list = [_as_multidataset(d) for d in ds_list]
-        if (
-            self.conf.backprop_type == "tbptt"
-            and any(f.ndim == 3 for f in mds_list[0].features)
-        ):
-            for mds in mds_list:
-                self._fit_tbptt(mds)
-            return
-        K = len(mds_list)
-        cached = getattr(self, "_multi_fit_fn", None)
-        if cached is None or cached[0] != K:
-            self._multi_fit_fn = (K, self._build_multi_fit_step(K))
-        fn = self._multi_fit_fn[1]
-        stack_list = lambda lists: [
-            jnp.stack([jnp.asarray(a) for a in pos]) for pos in zip(*lists)
-        ]
-        stack_masks = lambda lists: (
-            None if lists[0] is None
-            else [None if pos[0] is None
-                  else jnp.stack([jnp.asarray(a) for a in pos])
-                  for pos in zip(*lists)]
-        )
-        xs = stack_list([m.features for m in mds_list])
-        ys = stack_list([m.labels for m in mds_list])
-        fms = stack_masks([m.features_masks for m in mds_list])
-        lms = stack_masks([m.labels_masks for m in mds_list])
-        lrs = jnp.asarray(
-            [schedule_lr(self.net_conf, self.iteration + i)
-             for i in range(K)], jnp.float32)
-        params, states, upd, last, diag = fn(
-            self.params_list, self.state_list, self.upd_state,
-            xs, ys, fms, lms, lrs, jnp.asarray(self.iteration, jnp.uint32))
-        self.params_list = params
-        self.upd_state = upd
-        self.state_list = states
-        self._score = last
-        self._step_diag = diag
-        self._last_stats = None
-        self.iteration += K
-
-    def _build_multi_fit_step(self, K: int):
-        """K optimizer steps as one `lax.scan` over the stacked batches —
-        same per-step lr/t/rng derivation as `_fit_step`, K-1 fewer
-        dispatches (equivalence: tests/test_fused_fit.py)."""
-        assert not getattr(self, "_collect_stats", False)
-        body = self._make_step_body(collect=False)
-        seed_key_base = self.net_conf.seed ^ 0x5EED
-
-        def step(params, states, upd_state, xs, ys, fms, lms, lrs, t0):
-            key = jax.random.PRNGKey(seed_key_base)
-
-            def scan_body(carry, inp):
-                p, st, us = carry
-                xs_i, ys_i, fms_i, lms_i, lr, i = inp
-                rng, t = self._step_rng_and_t(key, t0, i)
-                p, st, us, sc, dg = body(p, st, us,
-                                         (xs_i, ys_i, fms_i, lms_i),
-                                         lr, t, rng)
-                return (p, st, us), (sc, dg)
-
-            (params, states, upd_state), (scores, diags) = jax.lax.scan(
-                scan_body, (params, states, upd_state),
-                (xs, ys, fms, lms, lrs, jnp.arange(K, dtype=jnp.uint32)))
-            diag = jnp.stack([diags[-1, 0], jnp.max(diags[:, 1])])
-            return params, states, upd_state, scores[-1], diag
-
-        # stacked batches: [K, B, ...] — batch dim 1 shards over "data"
-        return self._jit_step(step, data_argnums=(3, 4, 5, 6),
-                              stacked_data=True)
-
-    def _fit_tbptt(self, mds: MultiDataSet):
-        """Truncated BPTT over a MultiDataSet: the time axis of every 3-d
-        feature/label/mask is segmented into tbptt_fwd_length chunks; RNN
-        state carries across segment steps (reference:
-        ComputationGraph.doTruncatedBPTT — same segment loop as the MLN
-        path, generalized to multi-input/multi-output).
-
-        When eligible (no ragged tail, every temporal array shares T, no
-        listeners, no stats collection) all segments run in ONE jitted
-        dispatch — the same fused treatment as
-        MultiLayerNetwork._fit_tbptt_fused; listeners keep the loop path
-        so per-iteration callbacks observe their iteration's params."""
-        T = max(f.shape[1] for f in mds.features if f.ndim == 3)
-        seg = int(self.conf.tbptt_fwd_length)
-        bwd = int(self.conf.tbptt_bwd_length)
-        n_seg = -(-T // seg)
-        uniform_T = all(
-            a.shape[1] == T
-            for group in (mds.features, mds.labels) for a in group
-            if a.ndim == 3
-        ) and all(
-            m.shape[1] == T
-            for group in (mds.features_masks, mds.labels_masks)
-            if group is not None for m in group
-            if m is not None and m.ndim == 2
-        )
-        if (
-            T == n_seg * seg
-            and uniform_T
-            and not self.listeners
-            and not getattr(self, "_collect_stats", False)
-        ):
-            self._fit_tbptt_fused(mds, n_seg, seg, bwd)
-            return
-        states = self._seeded_states()
-
-        def cut_mask(m, sl):
-            if m is None:
-                return None
-            return m if m.ndim == 1 else m[:, sl]  # 1-D = per-example mask
-
-        def cut(sl):
-            feats = [f[:, sl] if f.ndim == 3 else f for f in mds.features]
-            labels = [y[:, sl] if y.ndim == 3 else y for y in mds.labels]
-            fms = None
-            if mds.features_masks is not None:
-                fms = [cut_mask(m, sl) for m in mds.features_masks]
-            lms = None
-            if mds.labels_masks is not None:
-                lms = [cut_mask(m, sl) for m in mds.labels_masks]
-            return (feats, labels, fms, lms)
-
-        for start in range(0, T, seg):
-            end = min(start + seg, T)
-            if bwd < end - start:
-                boundary = end - bwd
-                states, _ = self._fit_step_truncated(
-                    cut(slice(start, boundary)), cut(slice(boundary, end)),
-                    stateful_states=states,
-                )
-            else:
-                states, _ = self._fit_step(
-                    *cut(slice(start, end)), stateful_states=states
-                )
-            self._notify(getattr(mds, "reported_examples", None)
-                     or mds.num_examples(), mds)
-        # persist only non-RNN state (running stats); RNN carry is per-batch
-        self.state_list = [
-            st if not _is_recurrent(lc) else self.state_list[i]
-            for i, (lc, st) in enumerate(zip(self._layer_confs, states))
-        ]
-
-    @staticmethod
-    def _make_seg_data_multi(seg: int, bwd: int):
-        """Multi-input TBPTT time segmentation under jit (the list analog
-        of MultiLayerNetwork._make_seg_data): temporal arrays (3-d
-        features/labels, 2-d masks) get dynamic_slice'd, static arrays
-        (2-d labels, 1-d per-example masks) pass through whole."""
-
-        def seg_slice(a, start, length):
-            return jax.lax.dynamic_slice_in_dim(a, start, length, axis=1)
-
-        def cut_arrays(lst, s0, ln):
-            return [seg_slice(a, s0, ln) if a.ndim == 3 else a for a in lst]
-
-        def cut_masks(lst, s0, ln):
-            if lst is None:
-                return None
-            return [
-                None if m is None
-                else (m if m.ndim == 1 else seg_slice(m, s0, ln))
-                for m in lst
-            ]
-
-        def seg_data(xs, ys, fms, lms, i):
-            start = i * seg
-            if bwd < seg:
-                nA = seg - bwd
-                return (
-                    cut_arrays(xs, start, nA), cut_arrays(ys, start, nA),
-                    cut_masks(fms, start, nA), cut_masks(lms, start, nA),
-                    cut_arrays(xs, start + nA, bwd),
-                    cut_arrays(ys, start + nA, bwd),
-                    cut_masks(fms, start + nA, bwd),
-                    cut_masks(lms, start + nA, bwd),
-                )
-            return (cut_arrays(xs, start, seg), cut_arrays(ys, start, seg),
-                    cut_masks(fms, start, seg), cut_masks(lms, start, seg))
-
-        return seg_data
-
-    def _build_tbptt_fused_step(self, n_seg: int, seg: int, bwd: int):
-        """ALL of a batch's TBPTT segments in ONE jitted dispatch — the
-        ComputationGraph twin of MultiLayerNetwork._build_tbptt_fused_step
-        (same per-segment lr/t/rng, same optimizer tail; equivalence:
-        tests/test_fused_fit.py). Callers guarantee T == n_seg * seg and
-        that stats collection is off."""
-        assert not getattr(self, "_collect_stats", False)
-        body = self._make_step_body(
-            self._trunc_loss_builder() if bwd < seg
-            else self._std_loss_builder()
-        )
-        seed_key_base = self.net_conf.seed ^ 0x5EED
-        seg_data = self._make_seg_data_multi(seg, bwd)
-
-        def step(params, states, upd_state, data, lrs, t0, _rng_unused):
-            xs, ys, fms, lms = data
-            key = jax.random.PRNGKey(seed_key_base)
-
-            def run_seg(params, states, upd_state, i):
-                rng, t = self._step_rng_and_t(key, t0, i)
-                return body(params, states, upd_state,
-                            seg_data(xs, ys, fms, lms, i), lrs[i], t, rng)
-
-            # segment 0 inline: its merged states establish the carry
-            # pytree (zero-state {} -> populated h/c) for the scan
-            params, states, upd_state, s0, d0 = run_seg(
-                params, states, upd_state, 0)
-            if n_seg == 1:
-                return params, states, upd_state, s0, d0
-
-            def scan_body(carry, i):
-                p, st, us = carry
-                p, st, us, score, dg = run_seg(p, st, us, i)
-                return (p, st, us), (score, dg)
-
-            (params, states, upd_state), (scores, diags) = jax.lax.scan(
-                scan_body, (params, states, upd_state),
-                jnp.arange(1, n_seg))
-            diag = jnp.stack([diags[-1, 0],
-                              jnp.maximum(d0[1], jnp.max(diags[:, 1]))])
-            return params, states, upd_state, scores[-1], diag
-
-        return self._jit_step(step)
-
-    def _fit_tbptt_fused(self, mds: MultiDataSet, n_seg: int, seg: int,
-                         bwd: int):
-        sig = (n_seg, seg, bwd)
-        cached = getattr(self, "_fused_tbptt_fn", None)
-        if cached is None or cached[0] != sig:
-            self._fused_tbptt_fn = (
-                sig, self._build_tbptt_fused_step(n_seg, seg, bwd))
-        step_fn = self._fused_tbptt_fn[1]
-        states = self._seeded_states()
-        lrs = jnp.asarray(
-            [schedule_lr(self.net_conf, self.iteration + i)
-             for i in range(n_seg)], jnp.float32)
-        data = ([jnp.asarray(x) for x in mds.features],
-                [jnp.asarray(y) for y in mds.labels],
-                self._jas(mds.features_masks), self._jas(mds.labels_masks))
-        params, states, upd, last, diag = step_fn(
-            self.params_list, states, self.upd_state, data, lrs,
-            jnp.asarray(self.iteration, jnp.uint32), None)
-        self.params_list = params
-        self.upd_state = upd
-        self._score = last
-        self._step_diag = diag
-        self._last_stats = None
-        self.iteration += n_seg
-        # persist only non-RNN state (running stats); RNN carry is per-batch
-        self.state_list = [
-            st if not _is_recurrent(lc) else self.state_list[i]
-            for i, (lc, st) in enumerate(zip(self._layer_confs, states))
-        ]
-
-    def _fit_step_truncated(self, dataA, dataB, stateful_states):
-        """TBPTT segment step with a backward-truncation boundary (the
-        truncated loss builder above) — one jitted call per segment on
-        the loop path."""
-        if getattr(self, "_trunc_step_fn", None) is None:
-            body = self._make_step_body(
-                self._trunc_loss_builder(),
-                collect=bool(getattr(self, "_collect_stats", False)))
-            self._trunc_step_fn = self._jit_step(body)
-            self._note_compile("train_step_truncated")
-
-        lr = schedule_lr(self.net_conf, self.iteration)
-        rng = jax.random.fold_in(
-            jax.random.PRNGKey(self.net_conf.seed ^ 0x5EED), self.iteration
-        )
-        pack = lambda d: (
-            [jnp.asarray(x) for x in d[0]], [jnp.asarray(y) for y in d[1]],
-            self._jas(d[2]), self._jas(d[3]),
-        )
-        out = self._trunc_step_fn(
-            self.params_list, stateful_states, self.upd_state,
-            pack(dataA) + pack(dataB),
-            jnp.asarray(lr, jnp.float32), jnp.asarray(float(self.iteration)),
-            rng,
-        )
-        params, states, upd, score = out[:4]
-        self._step_diag = out[4]
-        self._last_stats = out[5] if len(out) > 5 else None
-        self.params_list = params
-        self.upd_state = upd
-        self._score = score
-        self.iteration += 1
-        return states, score
 
     # -- inference -----------------------------------------------------------
 

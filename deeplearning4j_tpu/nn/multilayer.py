@@ -38,7 +38,7 @@ from deeplearning4j_tpu.data.iterators import (
     ListDataSetIterator,
 )
 from deeplearning4j_tpu.nn.conf import layers as L
-from deeplearning4j_tpu.nn.conf.network import BackpropType, MultiLayerConfiguration
+from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers.core import rnn_output_preout
 from deeplearning4j_tpu.nn.layers.registry import (
     LayerContext,
@@ -47,27 +47,15 @@ from deeplearning4j_tpu.nn.layers.registry import (
     init_layer_state,
 )
 from deeplearning4j_tpu.nn.netbase import NetworkBase
+from deeplearning4j_tpu.nn.trainstep import _is_recurrent
 from deeplearning4j_tpu.ops.losses import example_presence, masked_example_mean, loss_value
 from deeplearning4j_tpu.train.evaluation import Evaluation, RegressionEvaluation
-from deeplearning4j_tpu.train.updaters import (
-    normalize_gradients,
-    schedule_lr,
-    updater_from_conf,
-)
+from deeplearning4j_tpu.train.updaters import schedule_lr, updater_from_conf
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
 _OUTPUT_LAYER_TYPES = (L.OutputLayer, L.RnnOutputLayer, L.LossLayer,
                        L.CenterLossOutputLayer)
-
-
-def _is_recurrent(conf) -> bool:
-    inner = conf.inner if isinstance(conf, L.FrozenLayer) else conf
-    return isinstance(inner, (L.LSTM, L.GravesLSTM))
-
-
-def _is_frozen(conf) -> bool:
-    return isinstance(conf, L.FrozenLayer)
 
 
 def _regularizable(name: str) -> bool:
@@ -142,7 +130,6 @@ class MultiLayerNetwork(NetworkBase):
         self.policy = policy_from_name(self.net_conf.precision)
         self.updater_def = updater_from_conf(self.net_conf)
         self._rnn_states = None  # streaming inference state (rnn_time_step)
-        self._train_step_fn = None
         self._output_fn = None
 
     def _ordered_layer_confs(self):
@@ -211,9 +198,6 @@ class MultiLayerNetwork(NetworkBase):
                     x, ns = forward_layer(conf, params[i], x, ctx)
             new_states[i] = ns
         return x, new_states
-
-    def _merge_states(self, old, new):
-        return [n if n is not None else o for o, n in zip(old, new)]
 
     # -- loss ----------------------------------------------------------------
 
@@ -292,295 +276,6 @@ class MultiLayerNetwork(NetworkBase):
                 )
                 new_states[-1] = {"centers": updated.astype(states[-1]["centers"].dtype)}
         return score, new_states
-
-    # -- train step ----------------------------------------------------------
-
-    def _lr_mult_tree(self):
-        """Per-leaf learning-rate multiplier (per-layer learning_rate and
-        bias_learning_rate overrides, reference: layer conf learningRate)."""
-        base = self.net_conf.learning_rate
-        out = []
-        for conf, p in zip(self.layer_confs, self.params_list):
-            inner = conf.inner if isinstance(conf, L.FrozenLayer) else conf
-            layer_lr = getattr(inner, "learning_rate", None)
-            bias_lr = getattr(inner, "bias_learning_rate", None)
-            mult = {}
-            for name in p:
-                if name == "b" and bias_lr is not None:
-                    mult[name] = bias_lr / base
-                elif layer_lr is not None:
-                    mult[name] = layer_lr / base
-                else:
-                    mult[name] = 1.0
-            out.append(mult)
-        return out
-
-    def _trainable_mask(self):
-        return [
-            {k: (0.0 if _is_frozen(conf) else 1.0) for k in p}
-            for conf, p in zip(self.layer_confs, self.params_list)
-        ]
-
-    def _make_step_body(self, loss_builder, collect: bool = False):
-        """Unjitted optimizer-step body around a loss builder
-        (p, states, data, rng) -> (score, new_states). The tail — gradient
-        masking/normalization, per-leaf lr, updater, param update — is
-        shared by the standard, truncated-backward and fused-TBPTT steps.
-
-        Returns (params, states, upd_state, score, diag[, stats]): `diag`
-        is the in-graph divergence diagnostic `[loss, global grad norm]`
-        — a 2-vector fused into the same program (a few elementwise
-        reductions next to a full backward pass), so the sentinel's
-        per-step judgment costs ONE device read that rides the score
-        fetch instead of a second sync."""
-        gnorm = self.net_conf.gradient_normalization
-        gthresh = self.net_conf.gradient_normalization_threshold
-        mults = self._lr_mult_tree()
-        tmask = self._trainable_mask()
-        updater = self.updater_def
-        minimize = self.net_conf.minimize
-        # mesh-attached nets pin the gradient reduction IN-GRAPH here:
-        # constraining the grads to the parameter shardings makes GSPMD
-        # insert the cross-device psum/mean at the grad site (replicated
-        # params x data-sharded batch), replacing the reference's
-        # host-side parameter averaging. The plan emits it BUCKETED
-        # (reverse-topo flat payloads, parallel/sharded.CollectivePlan):
-        # each bucket's collective depends only on its own leaves, so the
-        # scheduler can overlap early buckets with the remaining backward
-        plan = self._mesh_plan
-
-        def step(params, states, upd_state, data, lr, t, rng):
-            def loss_fn(p):
-                return loss_builder(p, states, data, rng)
-
-            (score, new_states), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(params)
-            if plan is not None:
-                with jax.named_scope("reduce_grads"):
-                    grads = plan.reduce_grads(self, grads)
-            merged = self._merge_states(states, new_states)
-            with jax.named_scope("update"):
-                # global grad norm of the RAW gradient (before masking/
-                # clipping — clipping would hide exactly the explosion the
-                # sentinel watches for), accumulated in f32
-                gsq = jnp.float32(0.0)
-                for g in jax.tree_util.tree_leaves(grads):
-                    gsq = gsq + jnp.sum(jnp.square(g.astype(jnp.float32)))
-                diag = jnp.stack([score.astype(jnp.float32), jnp.sqrt(gsq)])
-                if not minimize:
-                    grads = jax.tree_util.tree_map(lambda g: -g, grads)
-                grads = [
-                    {k: g[k] * m[k] for k in g} for g, m in zip(grads, tmask)
-                ]
-                grads = normalize_gradients(grads, gnorm, gthresh)
-                lr_tree = [
-                    {k: lr * m[k] for k in g} for g, m in zip(grads, mults)
-                ]
-                updates, new_upd = updater.apply_tree(grads, upd_state,
-                                                      lr_tree, t)
-                new_params = jax.tree_util.tree_map(jnp.add, params, updates)
-                if collect:
-                    # per-layer mean |x| scalars for the stats pipeline
-                    # (reference: BaseStatsListener param/grad/update mean
-                    # magnitudes) — fused into the step; tiny reductions
-                    mm = lambda tree: [
-                        {k: jnp.mean(jnp.abs(v)) for k, v in p.items()}
-                        for p in tree
-                    ]
-                    stats = {"grad_mm": mm(grads), "update_mm": mm(updates),
-                             "param_mm": mm(new_params)}
-                    return new_params, merged, new_upd, score, diag, stats
-            return new_params, merged, new_upd, score, diag
-
-        return step
-
-    def _make_step(self, loss_builder):
-        """Jitted single-minibatch optimizer step (donated params/updater
-        buffers on device backends; sharded signature under a mesh plan —
-        see netbase._jit_step)."""
-        step = self._make_step_body(
-            loss_builder, collect=bool(getattr(self, "_collect_stats", False))
-        )
-        return self._jit_step(step)
-
-    def _std_loss_builder(self):
-        def loss_builder(p, states, data, rng):
-            x, y, f_mask, l_mask = data
-            return self._loss(p, states, x, y, f_mask, l_mask, rng)
-
-        return loss_builder
-
-    def _trunc_loss_builder(self):
-        """TBPTT loss with tbptt_bwd_length < tbptt_fwd_length: the
-        segment's leading (fwd-bwd) timesteps run under stop_gradient
-        (state advances, loss counts, but no gradient flows back through
-        them), truncating backprop depth to bwd_length (reference:
-        tBPTTBackwardLength, MultiLayerNetwork.java:1333; the reference
-        zeroes epsilons past bwd steps of the reverse walk — here the cut
-        is a stop_gradient on the carried state at the boundary)."""
-
-        def loss_builder(p, states, data, rng):
-            xA, yA, fmA, lmA, xB, yB, fmB, lmB = data
-            lossA, statesA = self._loss(p, states, xA, yA, fmA, lmA, rng)
-            carried = self._merge_states(states, statesA)
-            carried = jax.tree_util.tree_map(jax.lax.stop_gradient, carried)
-            lossB, statesB = self._loss(
-                p, carried, xB, yB, fmB, lmB,
-                None if rng is None else jax.random.fold_in(rng, 1),
-            )
-            nA, nB = xA.shape[1], xB.shape[1]
-            # slice A contributes to the reported score but NOT to the
-            # gradient (stop_gradient lets XLA prune its whole backward
-            # pass) — backprop depth is exactly bwd_length
-            score = (
-                jax.lax.stop_gradient(lossA) * nA + lossB * nB
-            ) / (nA + nB)
-            return score, self._merge_states(carried, statesB)
-
-        return loss_builder
-
-    def _build_train_step(self):
-        return self._make_step(self._std_loss_builder())
-
-    def _build_truncated_bwd_step(self):
-        return self._make_step(self._trunc_loss_builder())
-
-    @staticmethod
-    def _make_seg_data(seg: int, bwd: int):
-        """TBPTT time-segmentation under jit: returns seg_data(x, y, fm,
-        lm, i) -> the step-body data tuple for segment i (the 8-tuple
-        A/B split when bwd < seg, the plain 4-tuple otherwise). Uses
-        dynamic_slice so `i` may be a traced scan index."""
-
-        def seg_slice(a, start, length):
-            return jax.lax.dynamic_slice_in_dim(a, start, length, axis=1)
-
-        def seg_data(x, y, fm, lm, i):
-            start = i * seg
-            cut_m = lambda m, s0, ln: (
-                None if m is None else (m if m.ndim == 1
-                                        else seg_slice(m, s0, ln))
-            )
-            cut_y = lambda s0, ln: (seg_slice(y, s0, ln) if y.ndim == 3 else y)
-            if bwd < seg:
-                nA = seg - bwd
-                return (
-                    seg_slice(x, start, nA), cut_y(start, nA),
-                    cut_m(fm, start, nA), cut_m(lm, start, nA),
-                    seg_slice(x, start + nA, bwd), cut_y(start + nA, bwd),
-                    cut_m(fm, start + nA, bwd), cut_m(lm, start + nA, bwd),
-                )
-            return (seg_slice(x, start, seg), cut_y(start, seg),
-                    cut_m(fm, start, seg), cut_m(lm, start, seg))
-
-        return seg_data
-
-    def _build_tbptt_fused_step(self, n_seg: int, seg: int, bwd: int):
-        """ALL of a batch's TBPTT segments in ONE jitted dispatch.
-
-        The per-segment loop in `_fit_tbptt` costs several host->device
-        dispatches per segment (time-slices + the step); through a
-        high-latency device link that overhead dwarfs the compute for
-        small recurrent cells (measured: 9.5ms/segment dispatched vs 93us
-        of device time on the char-rnn bench). Here segment 0 runs inline
-        (populating the RNN-state carry structure) and segments 1..n-1 run
-        under `lax.scan`, so the whole fit batch is one dispatch. Exact
-        same math as the loop: same per-segment lr/t/rng, same optimizer
-        tail (equivalence pinned by tests/test_tbptt_fused.py).
-
-        Callers must guarantee T == n_seg * seg (no ragged tail — the
-        fixed-size `dynamic_slice` segmentation cannot express one; the
-        loop path handles it) and that per-iteration stats collection is
-        off (the body is built without `collect`).
-        """
-        assert not getattr(self, "_collect_stats", False), (
-            "fused TBPTT does not collect per-iteration stats; "
-            "_fit_tbptt must use the loop path when collection is on"
-        )
-        body = self._make_step_body(
-            self._trunc_loss_builder() if bwd < seg
-            else self._std_loss_builder()
-        )
-        seed_key_base = self.net_conf.seed ^ 0x5EED
-        seg_data = self._make_seg_data(seg, bwd)
-
-        def step(params, states, upd_state, data, lrs, t0, _rng_unused):
-            x, y, fm, lm = data
-            key = jax.random.PRNGKey(seed_key_base)
-
-            def run_seg(params, states, upd_state, i):
-                rng, t = self._step_rng_and_t(key, t0, i)
-                return body(params, states, upd_state,
-                            seg_data(x, y, fm, lm, i), lrs[i], t, rng)
-
-            # segment 0 inline: its merged states establish the carry
-            # pytree (zero-state {} -> populated h/c) for the scan
-            params, states, upd_state, s0, d0 = run_seg(
-                params, states, upd_state, 0)
-            if n_seg == 1:
-                return params, states, upd_state, s0[None], s0, d0
-
-            def scan_body(carry, i):
-                p, st, us = carry
-                p, st, us, score, dg = run_seg(p, st, us, i)
-                return (p, st, us), (score, dg)
-
-            (params, states, upd_state), (scores, diags) = jax.lax.scan(
-                scan_body, (params, states, upd_state),
-                jnp.arange(1, n_seg))
-            # the final score returned separately so the host can keep a
-            # scalar _score without an extra device-indexing dispatch
-            last = scores[-1]
-            # whole-batch diagnostic: final score, worst grad norm of
-            # any segment (a NaN segment poisons later params, so the
-            # final loss carries the non-finite signal regardless)
-            diag = jnp.stack([diags[-1, 0],
-                              jnp.maximum(d0[1], jnp.max(diags[:, 1]))])
-            scores = jnp.concatenate([s0[None], scores])
-            return params, states, upd_state, scores, last, diag
-
-        return self._jit_step(step)
-
-    def _run_step(self, step_fn, data, stateful_states=None):
-        lr = schedule_lr(self.net_conf, self.iteration)
-        rng = jax.random.fold_in(
-            jax.random.PRNGKey(self.net_conf.seed ^ 0x5EED), self.iteration
-        )
-        states = stateful_states if stateful_states is not None else self.state_list
-        out = step_fn(
-            self.params_list, states, self.upd_state,
-            tuple(None if a is None else jnp.asarray(a) for a in data),
-            jnp.asarray(lr, jnp.float32), jnp.asarray(float(self.iteration)),
-            rng,
-        )
-        params, states, upd, score = out[:4]
-        self._step_diag = out[4]
-        self._last_stats = out[5] if len(out) > 5 else None
-        self.params_list = params
-        self.upd_state = upd
-        self._score = score
-        self.iteration += 1
-        return states, score
-
-    def _fit_step(self, x, y, f_mask, l_mask, stateful_states=None):
-        """One optimizer step. Returns the (device) score."""
-        if self._train_step_fn is None:
-            self._train_step_fn = self._build_train_step()
-            self._note_compile("train_step")
-        return self._run_step(
-            self._train_step_fn, (x, y, f_mask, l_mask), stateful_states
-        )
-
-    def _fit_step_truncated(self, dataA, dataB, stateful_states):
-        """One TBPTT segment step with a backward-truncation boundary
-        between slice A (state-carry, stop-gradient) and slice B."""
-        if getattr(self, "_trunc_step_fn", None) is None:
-            self._trunc_step_fn = self._build_truncated_bwd_step()
-            self._note_compile("train_step_truncated")
-        return self._run_step(
-            self._trunc_step_fn, dataA + dataB, stateful_states
-        )
 
     # -- pretraining ---------------------------------------------------------
 
@@ -726,21 +421,19 @@ class MultiLayerNetwork(NetworkBase):
         y = np.asarray(labels)
         return ListDataSetIterator(DataSet(x, y), batch_size)
 
+    def _batch_data(self, ds: DataSet):
+        return (ds.features, ds.labels, ds.features_mask, ds.labels_mask)
+
     def _fit_dataset(self, ds: DataSet):
         algo = self.net_conf.optimization_algo
         if algo != "sgd":
             self._fit_line_search(ds, algo)
             return
-        tbptt = (
-            self.conf.backprop_type == BackpropType.TRUNCATED_BPTT
-            and ds.features.ndim == 3
-        )
-        if tbptt:
+        data = self._batch_data(ds)
+        if self._is_tbptt(data):
             self._fit_tbptt(ds)
         else:
-            states, score = self._fit_step(
-                ds.features, ds.labels, ds.features_mask, ds.labels_mask
-            )
+            states, score = self._fit_step(*data)
             self.state_list = states
             self._notify(getattr(ds, "reported_examples", None)
                          or ds.num_examples(), ds)
@@ -778,305 +471,9 @@ class MultiLayerNetwork(NetworkBase):
         self._notify(getattr(ds, "reported_examples", None)
                          or ds.num_examples(), ds)
 
-    def _fit_tbptt(self, ds: DataSet):
-        """Truncated BPTT: split time into segments of tbptt_fwd_length and
-        carry RNN state across segments (reference:
-        MultiLayerNetwork.doTruncatedBPTT :1333). When tbptt_bwd_length <
-        tbptt_fwd_length, each segment's gradient is truncated to its last
-        bwd_length timesteps (config tBPTTBackwardLength).
-
-        When the batch has no ragged tail (T divisible by seg), no
-        listeners are attached, and stats collection is off, all segments
-        run in ONE jitted dispatch (`_build_tbptt_fused_step`) — same math,
-        ~n_seg fewer host->device round-trips. Listeners keep the loop path
-        so per-iteration callbacks observe the params of *their* iteration.
-        """
-        T = ds.features.shape[1]
-        seg = int(self.conf.tbptt_fwd_length)
-        bwd = int(self.conf.tbptt_bwd_length)
-        n_seg = -(-T // seg)
-        if (
-            T == n_seg * seg
-            and not self.listeners
-            and not getattr(self, "_collect_stats", False)
-        ):
-            self._fit_tbptt_fused(ds, n_seg, seg, bwd)
-            return
-        # seed zero RNN state for recurrent layers
-        states = list(self.state_list)
-        for i, conf in enumerate(self.layer_confs):
-            if _is_recurrent(conf) and states[i] is None:
-                states[i] = {}
-
-        def cut_mask(m, sl):
-            if m is None:
-                return None
-            return m if m.ndim == 1 else m[:, sl]  # 1-D = per-example mask
-
-        def cut(sl):
-            fm = cut_mask(ds.features_mask, sl)
-            lm = cut_mask(ds.labels_mask, sl)
-            labels = ds.labels[:, sl] if ds.labels.ndim == 3 else ds.labels
-            return (ds.features[:, sl], labels, fm, lm)
-
-        for start in range(0, T, seg):
-            end = min(start + seg, T)
-            if bwd < end - start:
-                boundary = end - bwd
-                states, _ = self._fit_step_truncated(
-                    cut(slice(start, boundary)), cut(slice(boundary, end)),
-                    stateful_states=states,
-                )
-            else:
-                states, _ = self._fit_step(
-                    *cut(slice(start, end)), stateful_states=states
-                )
-            self._notify(getattr(ds, "reported_examples", None)
-                         or ds.num_examples(), ds)
-        # persist only non-RNN state (running stats); RNN carry is per-batch
-        self.state_list = [
-            st if not _is_recurrent(conf) else self.state_list[i]
-            for i, (conf, st) in enumerate(zip(self.layer_confs, states))
-        ]
-
-    def _fit_tbptt_fused(self, ds: DataSet, n_seg: int, seg: int, bwd: int):
-        """Run one TBPTT fit batch through the single-dispatch fused step
-        (see `_build_tbptt_fused_step`). Host work: the lr schedule values
-        for the n_seg optimizer steps and one call."""
-        sig = (n_seg, seg, bwd)
-        cached = getattr(self, "_fused_tbptt_fn", None)
-        if cached is None or cached[0] != sig:
-            self._fused_tbptt_fn = (
-                sig, self._build_tbptt_fused_step(n_seg, seg, bwd)
-            )
-        step_fn = self._fused_tbptt_fn[1]
-        states = list(self.state_list)
-        for i, conf in enumerate(self.layer_confs):
-            if _is_recurrent(conf) and states[i] is None:
-                states[i] = {}
-        lrs = jnp.asarray(
-            [schedule_lr(self.net_conf, self.iteration + i)
-             for i in range(n_seg)],
-            jnp.float32,
-        )
-        data = tuple(
-            None if a is None else jnp.asarray(a)
-            for a in (ds.features, ds.labels, ds.features_mask,
-                      ds.labels_mask)
-        )
-        params, states, upd, _scores, last, diag = step_fn(
-            self.params_list, states, self.upd_state, data, lrs,
-            jnp.asarray(self.iteration, jnp.uint32), None,
-        )
-        self.params_list = params
-        self.upd_state = upd
-        self._score = last
-        self._step_diag = diag
-        self._last_stats = None
-        self.iteration += n_seg
-        # persist only non-RNN state (running stats); RNN carry is per-batch
-        self.state_list = [
-            st if not _is_recurrent(conf) else self.state_list[i]
-            for i, (conf, st) in enumerate(zip(self.layer_confs, states))
-        ]
-
-    # -- multi-batch fused fit (set_fused_steps) -----------------------------
-
     def _fused_fit_supported(self) -> bool:
+        # the line-search solvers take one batch at a time
         return self.net_conf.optimization_algo == "sgd"
-
-    def _fit_datasets_fused(self, ds_list):
-        """K same-shape minibatches in ONE jitted dispatch (see
-        NetworkBase.set_fused_steps). Dispatches to the cross-batch TBPTT
-        program for 3-d TBPTT batches, the stacked-scan program otherwise;
-        anything ineligible (ragged TBPTT tail) falls back per-batch."""
-        d0 = ds_list[0]
-        if (
-            self.conf.backprop_type == BackpropType.TRUNCATED_BPTT
-            and d0.features.ndim == 3
-        ):
-            T = d0.features.shape[1]
-            seg = int(self.conf.tbptt_fwd_length)
-            bwd = int(self.conf.tbptt_bwd_length)
-            n_seg = -(-T // seg)
-            if T != n_seg * seg:
-                for d in ds_list:
-                    self._fit_dataset(d)
-                return
-            self._fit_tbptt_batched(ds_list, n_seg, seg, bwd)
-            return
-        self._fit_std_batched(ds_list)
-
-    @staticmethod
-    def _stack_datasets(ds_list):
-        stack = lambda vals: (
-            None if vals[0] is None
-            else jnp.stack([jnp.asarray(v) for v in vals])
-        )
-        return (
-            stack([d.features for d in ds_list]),
-            stack([d.labels for d in ds_list]),
-            stack([d.features_mask for d in ds_list]),
-            stack([d.labels_mask for d in ds_list]),
-        )
-
-    def _build_multi_fit_step(self, K: int):
-        """K standard optimizer steps as one `lax.scan` over the stacked
-        batches — same per-step lr/t/rng derivation as `_run_step`, K-1
-        fewer dispatches (equivalence: tests/test_fused_fit.py)."""
-        assert not getattr(self, "_collect_stats", False)
-        body = self._make_step_body(self._std_loss_builder())
-        seed_key_base = self.net_conf.seed ^ 0x5EED
-
-        def step(params, states, upd_state, data_stack, lrs, t0):
-            key = jax.random.PRNGKey(seed_key_base)
-
-            def scan_body(carry, inp):
-                p, st, us = carry
-                data_i, lr, i = inp
-                rng, t = self._step_rng_and_t(key, t0, i)
-                p, st, us, sc, dg = body(p, st, us, data_i, lr, t, rng)
-                return (p, st, us), (sc, dg)
-
-            (params, states, upd_state), (scores, diags) = jax.lax.scan(
-                scan_body, (params, states, upd_state),
-                (data_stack, lrs, jnp.arange(K, dtype=jnp.uint32)))
-            diag = jnp.stack([diags[-1, 0], jnp.max(diags[:, 1])])
-            return params, states, upd_state, scores[-1], diag
-
-        # stacked batches: [K, B, ...] — under a mesh plan the batch dim
-        # (1, not 0) shards over the data axis
-        return self._jit_step(step, stacked_data=True)
-
-    def _fit_std_batched(self, ds_list):
-        K = len(ds_list)
-        cached = getattr(self, "_multi_fit_fn", None)
-        if cached is None or cached[0] != K:
-            self._multi_fit_fn = (K, self._build_multi_fit_step(K))
-        fn = self._multi_fit_fn[1]
-        data = self._stack_datasets(ds_list)
-        lrs = jnp.asarray(
-            [schedule_lr(self.net_conf, self.iteration + i)
-             for i in range(K)], jnp.float32)
-        params, states, upd, last, diag = fn(
-            self.params_list, self.state_list, self.upd_state, data, lrs,
-            jnp.asarray(self.iteration, jnp.uint32))
-        self.params_list = params
-        self.upd_state = upd
-        self.state_list = states
-        self._score = last
-        self._step_diag = diag
-        self._last_stats = None
-        self.iteration += K
-
-    def _build_tbptt_batched_step(self, K: int, n_seg: int, seg: int,
-                                  bwd: int):
-        """K TBPTT fit batches (each n_seg segments, RNN state reset at
-        every batch boundary, BN stats carried throughout) in ONE jitted
-        dispatch. Batch 0's segment 0 runs inline to bootstrap the RNN
-        carry structure ({} -> {"h","c"}); batches 1..K-1 scan with a
-        zeros reset — identical math to K calls of `_fit_tbptt` (the
-        layer seeds zero state for {} exactly as `reset` writes zeros;
-        equivalence: tests/test_fused_fit.py)."""
-        assert not getattr(self, "_collect_stats", False)
-        body = self._make_step_body(
-            self._trunc_loss_builder() if bwd < seg
-            else self._std_loss_builder()
-        )
-        seed_key_base = self.net_conf.seed ^ 0x5EED
-        seg_data = self._make_seg_data(seg, bwd)
-        rec = [_is_recurrent(c) for c in self.layer_confs]
-
-        def reset_rnn(states):
-            return [
-                jax.tree_util.tree_map(jnp.zeros_like, st) if is_r else st
-                for st, is_r in zip(states, rec)
-            ]
-
-        def step(params, states, upd_state, data_stack, lrs, t0,
-                 _rng_unused):
-            key = jax.random.PRNGKey(seed_key_base)
-            pick = lambda b: tuple(
-                None if a is None else a[b] for a in data_stack)
-
-            def run_seg(p, st, us, data_b, i_seg, j):
-                rng, t = self._step_rng_and_t(key, t0, j)
-                x, y, fm, lm = data_b
-                return body(p, st, us, seg_data(x, y, fm, lm, i_seg),
-                            lrs[j], t, rng)
-
-            # batch 0 / segment 0 inline: bootstraps the carry structure
-            data0 = pick(0)
-            params, states, upd_state, _, d00 = run_seg(
-                params, states, upd_state, data0, 0, 0)
-            gmax = d00[1]
-            if n_seg > 1:
-                def seg_scan0(carry, i):
-                    p, st, us = carry
-                    p, st, us, sc, dg = run_seg(p, st, us, data0, i, i)
-                    return (p, st, us), dg
-
-                (params, states, upd_state), dgs0 = jax.lax.scan(
-                    seg_scan0, (params, states, upd_state),
-                    jnp.arange(1, n_seg))
-                gmax = jnp.maximum(gmax, jnp.max(dgs0[:, 1]))
-
-            def batch_body(carry, b):
-                p, st, us = carry
-                st = reset_rnn(st)
-                data_b = pick(b)
-
-                def seg_scan(c2, s):
-                    p2, st2, us2 = c2
-                    p2, st2, us2, sc, dg = run_seg(
-                        p2, st2, us2, data_b, s, b * n_seg + s)
-                    return (p2, st2, us2), (sc, dg)
-
-                (p, st, us), (scs, dgs) = jax.lax.scan(
-                    seg_scan, (p, st, us), jnp.arange(n_seg))
-                return (p, st, us), (scs[-1], jnp.max(dgs[:, 1]))
-
-            (params, states, upd_state), (lasts, gmaxes) = jax.lax.scan(
-                batch_body, (params, states, upd_state),
-                jnp.arange(1, K))
-            diag = jnp.stack([lasts[-1],
-                              jnp.maximum(gmax, jnp.max(gmaxes))])
-            return params, states, upd_state, lasts[-1], diag
-
-        return self._jit_step(step, stacked_data=True)
-
-    def _fit_tbptt_batched(self, ds_list, n_seg: int, seg: int, bwd: int):
-        K = len(ds_list)
-        if K == 1:
-            self._fit_tbptt_fused(ds_list[0], n_seg, seg, bwd)
-            return
-        sig = (K, n_seg, seg, bwd)
-        cached = getattr(self, "_tbptt_batched_fn", None)
-        if cached is None or cached[0] != sig:
-            self._tbptt_batched_fn = (
-                sig, self._build_tbptt_batched_step(K, n_seg, seg, bwd))
-        fn = self._tbptt_batched_fn[1]
-        states = list(self.state_list)
-        for i, conf in enumerate(self.layer_confs):
-            if _is_recurrent(conf) and states[i] is None:
-                states[i] = {}
-        data = self._stack_datasets(ds_list)
-        lrs = jnp.asarray(
-            [schedule_lr(self.net_conf, self.iteration + j)
-             for j in range(K * n_seg)], jnp.float32)
-        params, states, upd, last, diag = fn(
-            self.params_list, states, self.upd_state, data, lrs,
-            jnp.asarray(self.iteration, jnp.uint32), None)
-        self.params_list = params
-        self.upd_state = upd
-        self._score = last
-        self._step_diag = diag
-        self._last_stats = None
-        self.iteration += K * n_seg
-        self.state_list = [
-            st if not _is_recurrent(conf) else self.state_list[i]
-            for i, (conf, st) in enumerate(zip(self.layer_confs, states))
-        ]
 
     # -- inference -----------------------------------------------------------
 

@@ -26,6 +26,7 @@ from deeplearning4j_tpu.nn.params import (
     param_table,
     params_to_flat,
 )
+from deeplearning4j_tpu.nn.trainstep import TrainStep
 from deeplearning4j_tpu.utils import blackbox as _blackbox
 from deeplearning4j_tpu.utils import devprof as _devprof
 from deeplearning4j_tpu.utils import faultpoints as _faults
@@ -40,10 +41,12 @@ from deeplearning4j_tpu.train import sentinel as _sentinel
 logger = logging.getLogger("deeplearning4j_tpu")
 
 
-class NetworkBase:
+class NetworkBase(TrainStep):
     """Common trainable-network state + loops. Subclasses implement
-    `_fit_dataset(ds)` (one optimizer step or TBPTT segment loop) and
-    `_ordered_layer_confs()` (layer configs aligned with params_list)."""
+    `_fit_dataset(ds)` (one optimizer step or TBPTT segment loop),
+    `_ordered_layer_confs()` (layer configs aligned with params_list) and
+    what nn/trainstep.TrainStep — the optimizer-step programs and the
+    host code that runs them — asks of an engine."""
 
     def __init__(self):
         self.listeners = []
@@ -79,6 +82,10 @@ class NetworkBase:
         # fuse K consecutive same-shape minibatches into ONE jitted
         # dispatch (set_fused_steps) — the dispatch-latency amortizer
         self._fused_k = 1
+        # the jitted step programs (nn/trainstep): the plain train step
+        # under its own name, every other one by (kind, shape key)
+        self._train_step_fn = None
+        self._step_programs = {}
         # forward (`output`) traces compiled so far — bumped by the
         # subclasses' shape-keyed output caches; serving layers surface it
         # so a compile storm is a metric, not a latency mystery. The lock
@@ -173,51 +180,7 @@ class NetworkBase:
             "compile", compile_kind=kind,
             key=None if key is None else str(key))
 
-    def _step_donate_argnums(self):
-        """donate_argnums for jitted optimizer steps: params (0) and
-        updater state (2) are donated on device backends so the update
-        reuses their buffers instead of holding old+new copies; cpu
-        makes donation a no-op (jax warns), so it is skipped there. The
-        ONE definition every step builder uses — and records on the net,
-        so analysis/jaxpr_audit's JX006 check audits the value the jits
-        actually got, not a parallel reconstruction of this rule."""
-        import jax
-
-        donate = (0, 2) if jax.default_backend() != "cpu" else ()
-        self._donate_argnums = donate
-        return donate
-
-    def _jit_step(self, step, *, data_argnums=(3,), stacked_data=False):
-        """jit an optimizer-step body — the ONE place every step builder
-        (standard, truncated, fused-TBPTT, multi-batch; MultiLayerNetwork
-        and ComputationGraph) gets its jit, so the donation rule AND the
-        mesh sharding policy are single-sourced. Without a mesh plan
-        this is plain `jax.jit(step, donate_argnums=...)`; with one the
-        program is built with explicit NamedSharding in-shardings (batch
-        argnums sharded on the data axis, params/updater per their live
-        placement) and the same donation — the sharded signature JX006
-        audits via the recorded `_donate_argnums`."""
-        import jax
-
-        donate = self._step_donate_argnums()
-        plan = self._mesh_plan
-        if plan is None:
-            return jax.jit(step, donate_argnums=donate)
-        return plan.jit_step(self, step, donate_argnums=donate,
-                             data_argnums=data_argnums,
-                             stacked_data=stacked_data)
-
     # -- multi-device mesh ----------------------------------------------------
-
-    def _reset_step_programs(self):
-        """Drop every cached jitted program (train steps, fused variants,
-        output cache) — placement or signature changed."""
-        self._train_step_fn = None
-        self._output_fn = None
-        for attr in ("_trunc_step_fn", "_fused_tbptt_fn", "_multi_fit_fn",
-                     "_tbptt_batched_fn"):
-            if hasattr(self, attr):
-                setattr(self, attr, None)
 
     def set_mesh(self, mesh=None, *, plan=None, bucket_bytes=None,
                  grad_dtype=None):
@@ -396,8 +359,7 @@ class NetworkBase:
         if flag != self._collect_stats:
             self._collect_stats = flag
             self._train_step_fn = None
-            if hasattr(self, "_trunc_step_fn"):
-                self._trunc_step_fn = None
+            self._step_programs.clear()
         return self
 
     def set_sentinel(self, sentinel):
@@ -444,42 +406,6 @@ class NetworkBase:
         of the train step (see data.prefetch.DevicePrefetchIterator)."""
         self._prefetch_depth = max(1, int(depth))
         return self
-
-    def _fused_fit_supported(self) -> bool:
-        """Whether this network can run `_fit_datasets_fused`."""
-        return False
-
-    def _fit_datasets_fused(self, ds_list):
-        raise NotImplementedError
-
-    @staticmethod
-    def _step_rng_and_t(key, t0, i):
-        """Per-step (rng, t) inside a fused scan: t0 is the iteration
-        counter as EXACT uint32 (float32 would collapse consecutive
-        steps' dropout rng past 2^24 iterations), i the scan index. The
-        ONE derivation every fused program shares with `_run_step`'s
-        per-step fold_in(key, iteration)."""
-        import jax
-        import jax.numpy as jnp
-
-        ti = t0 + jnp.asarray(i, t0.dtype)
-        return jax.random.fold_in(key, ti), ti.astype(jnp.float32)
-
-    def _ds_signature(self, ds):
-        """Shape/mask signature — only identically-shaped consecutive
-        batches are stacked into one fused dispatch."""
-        sh = lambda a: None if a is None else tuple(a.shape)
-        if hasattr(ds, "features_masks"):  # MultiDataSet
-            return (
-                tuple(sh(f) for f in ds.features),
-                tuple(sh(y) for y in ds.labels),
-                None if ds.features_masks is None
-                else tuple(sh(m) for m in ds.features_masks),
-                None if ds.labels_masks is None
-                else tuple(sh(m) for m in ds.labels_masks),
-            )
-        return (sh(ds.features), sh(ds.labels), sh(ds.features_mask),
-                sh(ds.labels_mask))
 
     def _notify(self, batch_size, ds=None):
         if not self.listeners:

@@ -12,7 +12,7 @@ hook into the thing `fit()` does by default on a multi-device platform:
   neither recompiles nor drops to replicated execution;
 * the optimizer step is ONE jitted program built with explicit
   `NamedSharding` in-shardings and the single-sourced donation rule
-  (`netbase._step_donate_argnums`, audited by JX006), with the gradient
+  (`TrainStep._step_donate_argnums`, audited by JX006), with the gradient
   all-reduce pinned **inside the program** by a sharding constraint at
   the grad site — there is no host-side averaging anywhere in the step
   path (the DL4J ParallelWrapper semantics this replaces: per-step
@@ -53,7 +53,6 @@ that need them.
 from __future__ import annotations
 
 import functools
-import inspect
 import os
 import time
 from typing import List, Optional, Tuple
@@ -354,15 +353,16 @@ class MeshPlan:
     # -- the sharded step jit ------------------------------------------------
 
     def jit_step(self, net, step, *, donate_argnums: Tuple[int, ...],
-                 data_argnums: Tuple[int, ...] = (3,),
                  stacked_data: bool = False):
-        """jit an optimizer-step body with explicit NamedSharding
-        in-shardings: per-leaf placements for params (argnum 0) and
-        updater state (argnum 2) — which is what lets tp-sharded weights
-        ride the same program — the batch sharding for the data argnums,
-        replicated for everything else (layer state, lr, t, rng). The
-        donation rule arrives from the ONE definition every step builder
-        uses (`netbase._step_donate_argnums`, recorded on the net for the
+        """jit an optimizer-step program `step(params, states, upd_state,
+        data, lr, t, rng)` (nn/trainstep: every program has these seven
+        arguments) with explicit NamedSharding in-shardings: per-leaf
+        placements for params (argnum 0) and updater state (argnum 2) —
+        which is what lets tp-sharded weights ride the same program — the
+        batch sharding for the batch (argnum 3), replicated for
+        everything else (layer state, lr, t, rng). The donation rule
+        arrives from the ONE definition every step builder uses
+        (`TrainStep._step_donate_argnums`, recorded on the net for the
         JX006 audit); donated in/out layouts match because the step body
         constrains its gradient (and hence its outputs) back to the
         parameter shardings. The body is traced as a partitioned program
@@ -370,18 +370,12 @@ class MeshPlan:
         custom call the partitioner cannot split, so on a mesh of more
         than one device the layers keep their XLA lowering."""
         jax = _jax()
-        n_args = len(inspect.signature(step).parameters)
-        data_sh = self.batch_stacked if stacked_data else self.batch
-        in_shardings = []
-        for i in range(n_args):
-            if i == 0:
-                in_shardings.append(self.tree_shardings(net.params_list))
-            elif i == 2:
-                in_shardings.append(self.tree_shardings(net.upd_state))
-            elif i in data_argnums:
-                in_shardings.append(data_sh)
-            else:
-                in_shardings.append(self.replicated)
+        rep = self.replicated
+        in_shardings = (
+            self.tree_shardings(net.params_list), rep,
+            self.tree_shardings(net.upd_state),
+            self.batch_stacked if stacked_data else self.batch,
+            rep, rep, rep)
         from deeplearning4j_tpu.ops.helpers import partitioned_program
 
         n_devices = int(self.mesh.devices.size)
@@ -393,7 +387,7 @@ class MeshPlan:
             with partitioned_program(n_devices):
                 return step(*args)
 
-        return jax.jit(partitioned, in_shardings=tuple(in_shardings),
+        return jax.jit(partitioned, in_shardings=in_shardings,
                        donate_argnums=donate_argnums)
 
     def grad_shardings(self, net):
@@ -428,8 +422,8 @@ class MeshPlan:
 
     def reduce_grads(self, net, grads):
         """Emit the in-graph gradient reduction inside a step body
-        (called under trace by `_make_step_body`). Monolithic mode is
-        the historical whole-tree `with_sharding_constraint`; bucketed
+        (called under trace by nn/trainstep's `_make_step_body`).
+        Monolithic mode is the historical whole-tree `with_sharding_constraint`; bucketed
         mode concatenates each bucket's flattened leaves into ONE flat
         payload, constrains it replicated (ONE collective per bucket),
         and splits it back — bit-identical for f32 (the per-element

@@ -7,7 +7,7 @@ resume, overload shedding); this module closes the remaining gap:
 corrupts every parameter from that step on without tripping any crash
 guard — the fit "succeeds" and ships garbage. The resilience loop:
 
-* **Detect (in-graph)**: `_make_step_body` (nn/multilayer, nn/compgraph)
+* **Detect (in-graph)**: `_make_step_body` (nn/trainstep, both engines)
   computes a global gradient-norm scalar next to the loss and returns
   both packed as one 2-vector diagnostic (`net._step_diag`) — the check
   rides the score the host was going to observe anyway, so ONE device

@@ -124,7 +124,8 @@ def _rnn_conf():
     )
 
 
-def test_fused_tbptt_cross_batch_matches_loop():
+@pytest.mark.parametrize("engine", ["multilayer", "graph"])
+def test_fused_tbptt_cross_batch_matches_loop(engine):
     rng = np.random.default_rng(2)
     n, t = 64, 12  # batch 16 -> 4 fit batches x 3 segments each
     x = rng.normal(size=(n, t, 3)).astype(np.float32)
@@ -133,10 +134,14 @@ def test_fused_tbptt_cross_batch_matches_loop():
     y[..., 0] = (cs <= 0).astype(np.float32)
     y[..., 1] = (cs > 0).astype(np.float32)
 
-    loop = MultiLayerNetwork(_rnn_conf()).init()
-    fused = MultiLayerNetwork(_rnn_conf()).init().set_fused_steps(2)
+    # the graph reaches the cross-batch program since nn/trainstep
+    make = ((lambda: MultiLayerNetwork(_rnn_conf())) if engine == "multilayer"
+            else (lambda: ComputationGraph(_rnn_graph_conf())))
+    loop = make().init()
+    fused = make().init().set_fused_steps(2)
     for net in (loop, fused):
         net.fit(x, y, epochs=2, batch_size=16, async_prefetch=False)
+    assert ("tbptt_batched", (2, 3, 4, 4)) in fused._step_programs
     # 2 epochs x 4 batches x 3 segments
     assert fused.iteration == loop.iteration == 24
     assert _max_tree_diff(loop.params_list, fused.params_list) < 1e-6

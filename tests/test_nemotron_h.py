@@ -717,8 +717,9 @@ def test_layer_scopes_are_underscore_free_kinds():
     net, _ = _net()
     x, y = _tokens(7)
     text = net._build_train_step().lower(
-        net.params_list, net.state_list, net.upd_state, [jnp.asarray(x)],
-        [jnp.asarray(y)], None, None, jnp.float32(1e-3), jnp.float32(0.0),
+        net.params_list, net.state_list, net.upd_state,
+        ([jnp.asarray(x)], [jnp.asarray(y)], None, None),
+        jnp.float32(1e-3), jnp.float32(0.0),
         jax.random.PRNGKey(0)).as_text(debug_info=True)
     for scope in ("Lb0_mixer_mamba2/ssd_scan", "Lb1_mixer_sparseexperts/"
                   "experts", "Lb1_mixer_sparseexperts/router",

@@ -102,6 +102,97 @@ def test_the_lowered_step_holds_every_layers_scope(build):
     assert not any("update" in n and "jvp(" in n for n in names)
 
 
+# -- one optimizer-step family for both engines (nn/trainstep) ----------------
+
+STEP_FAMILY = (
+    "_make_step_body", "_lr_mult_tree", "_trainable_mask",
+    "_std_loss_builder", "_trunc_loss_builder", "_make_step", "_jit_step",
+    "_build_train_step", "_build_truncated_bwd_step", "_run_step",
+    "_fit_step", "_fit_step_truncated", "_make_seg_data", "_fit_tbptt",
+    "_build_tbptt_fused_step", "_fit_tbptt_fused", "_build_multi_fit_step",
+    "_fit_datasets_fused", "_build_tbptt_batched_step",
+    "_fit_tbptt_batched", "_reset_step_programs",
+)
+
+
+def _tiny_rnn(engine, fwd, bwd):
+    from deeplearning4j_tpu.nn.conf import LSTM, RnnOutputLayer
+
+    b = (NeuralNetConfiguration.builder().seed(5).updater("adam")
+         .learning_rate(0.02))
+    lstm = LSTM(n_out=8, activation="tanh")
+    out = RnnOutputLayer(n_out=2, activation="softmax", loss="mcxent")
+    if engine is MultiLayerNetwork:
+        conf = (b.list().layer(lstm).layer(out)
+                .set_input_type(InputType.recurrent(3))
+                .backprop_type("tbptt").t_bptt_lengths(fwd, bwd).build())
+    else:
+        conf = (b.graph_builder().add_inputs("seq")
+                .add_layer("lstm", lstm, "seq").add_layer("out", out, "lstm")
+                .set_outputs("out").set_input_types(InputType.recurrent(3))
+                .backprop_type("tbptt").t_bptt_lengths(fwd, bwd).build())
+    return engine(conf).init()
+
+
+class _NoOp:
+    """A listener pins `_fit_tbptt` to the per-segment loop."""
+
+    def iteration_done(self, model, iteration, info):
+        pass
+
+    def on_epoch_start(self, model, epoch):
+        pass
+
+    def on_epoch_end(self, model, epoch):
+        pass
+
+
+def _max_diff(a, b):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    return max(float(jnp.max(jnp.abs(x - y))) for x, y in zip(la, lb))
+
+
+@pytest.mark.parametrize("engine", [MultiLayerNetwork, ComputationGraph],
+                         ids=["multilayer", "graph"])
+@pytest.mark.parametrize("case", ["one_family", "fused", "truncated",
+                                  "fused_steps"])
+def test_both_engines_run_the_one_step_family(engine, case):
+    """The engines define no method of the family, and on each engine's
+    tiny recurrent net every variant of the step ends where the
+    per-segment loop does (tolerances: tests/test_tbptt_fused.py,
+    tests/test_fused_fit.py)."""
+    if case == "one_family":
+        from deeplearning4j_tpu.nn.trainstep import TrainStep
+
+        for name in STEP_FAMILY:
+            assert name not in vars(engine), name
+            assert getattr(MultiLayerNetwork, name) \
+                is getattr(ComputationGraph, name) \
+                is getattr(TrainStep, name), name
+        return
+    fwd, bwd = (6, 3) if case == "truncated" else (4, 4)
+    rng = np.random.default_rng(2)
+    x = rng.normal(size=(32, 12, 3)).astype(np.float32)
+    y = np.eye(2, dtype=np.float32)[(np.cumsum(x[..., 0], 1) > 0) * 1]
+    loop = _tiny_rnn(engine, fwd, bwd)
+    loop.add_listener(_NoOp())
+    other = _tiny_rnn(engine, fwd, bwd)
+    if case == "fused_steps":
+        other.set_fused_steps(2)
+    for net in (loop, other):
+        net.fit(x, y, epochs=2, batch_size=16, async_prefetch=False)
+    assert other.iteration == loop.iteration == 2 * 2 * (12 // fwd)
+    kinds = {kind for kind, _ in other._step_programs}
+    assert kinds == {"fused_steps": {"tbptt_batched"}}.get(
+        case, {"tbptt_fused"})
+    assert {kind for kind, _ in loop._step_programs} == (
+        {"truncated"} if case == "truncated" else set())
+    assert _max_diff(loop.params_list, other.params_list) < 1e-6
+    assert _max_diff(loop.upd_state, other.upd_state) < 1e-6
+    assert abs(float(loop._score) - float(other._score)) < 1e-6
+
+
 def test_layer_scope_names():
     assert layer_scope(2, ConvolutionLayer(n_out=1)) == "L2_convolution"
     assert layer_scope("stem_bn", BatchNormalization()) \
