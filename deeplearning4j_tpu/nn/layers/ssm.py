@@ -13,20 +13,33 @@ reference (`benchmark/reference/nemotron_h.recurrence`). Decays, `dt` and the
 carried state
 are float32; the products take operands of the net's compute dtype and
 accumulate in float32.
+
+The two elementwise stages around the scan, `conv_silu` (taps + bias + SiLU)
+and `gate_norm` (skip `D x`, gate `silu(z)`, group RMS norm), have
+hand-written gradients (`jax.custom_vjp`). What they store: `xBC`, `z`, the
+stages' outputs and the cotangents of all of these in the compute dtype; the
+scan's `y`, its cotangent, every statistic and every parameter gradient in
+float32. What they compute in: float32 (float64 where the net runs in it),
+the group statistics as products with a 0/1 membership matrix at
+`Precision.HIGHEST`. Their residuals are their stored inputs; every
+full-size tensor keeps the lane-dense `[b, t, channels]` layout.
+`mamba2_stage_lowering_total{stage, kind="fused_vjp"}` counts each stage
+once per trace.
 """
 
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.nn.conf import layers as L
-from deeplearning4j_tpu.nn.layers.norm import rms_normalize
 from deeplearning4j_tpu.nn.layers.registry import LayerContext, register_layer
 from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.utils import metrics as _metrics
 
 
 def mamba2_sizes(conf: L.Mamba2Layer):
@@ -73,17 +86,6 @@ def mamba2_init(key, conf: L.Mamba2Layer, dtype):
 def mamba2_order(conf):
     return ("W_in", "conv_W", "conv_b", "dt_bias", "A_log", "D",
             "norm_gamma", "W_out")
-
-
-def causal_depthwise_conv1d(x, w, b):
-    """x: [b, t, c], w: [k, c] (tap `k - 1` meets the current position),
-    b: [c] -> [b, t, c] float32: `y_t = b + sum_j w_j x_(t - (k-1) + j)`."""
-    k, t = w.shape[0], x.shape[1]
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    y = b.astype(jnp.float32)
-    for j in range(k):
-        y = y + w[j].astype(jnp.float32) * xp[:, j:j + t]
-    return y
 
 
 def ssd_chunked(x, dt, A, B, C, chunk: int):
@@ -168,6 +170,158 @@ def _ssd_group(args):
         * jnp.exp(s)[..., None]
 
 
+# -- the two elementwise stages, each with its own backward pass ---------------
+#
+# Autodiff of these formulas wrote a float32 tensor for every tap and every
+# view it took (four `f32[b, t, conv channels]` from one fusion, broadcasts of
+# the group statistic, head-shaped `[b, t, H, P]` copies whose 64 lanes are a
+# relayout on the chip): 31 GB a mixer where the stages' own inputs and
+# outputs are 4 (PERF.md, PR 31). The backward passes below compute the
+# float32 intermediates again from the stored inputs, as the inner
+# `jax.checkpoint`s they replace did.
+
+def _acc_dtype(*arrays):
+    """float32, or float64 where the net itself runs in it."""
+    return jnp.result_type(jnp.float32, *arrays)
+
+
+def _taps(x, k, front):
+    """The k views `x_(t + j - front)`, j = 0..k-1, of a [b, t, c] tensor,
+    zeros outside it: `front = k - 1` looks back, `front = 0` ahead."""
+    t = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (front, k - 1 - front), (0, 0)))
+    return [xp[:, j:j + t] for j in range(k)]
+
+
+def _silu_slope(a):
+    """d silu(a) / da."""
+    sig = jax.nn.sigmoid(a)
+    return sig * (1 + a * (1 - sig))
+
+
+def causal_depthwise_conv1d(x, w, b):
+    """x: [b, t, c] as it is stored, w: [k, c] (tap `k - 1` meets the
+    current position), b: [c] -> [b, t, c] float32:
+    `y_t = b + sum_j w_j x_(t - (k-1) + j)`."""
+    k = w.shape[0]
+    acc = _acc_dtype(x, w)
+    y = b.astype(acc)
+    for j, tap in enumerate(_taps(x, k, k - 1)):
+        y = y + w[j].astype(acc) * tap.astype(acc)
+    return y
+
+
+@jax.custom_vjp
+def conv_silu(x, w, b):
+    """`silu(causal_depthwise_conv1d(x, w, b))` in x's dtype, one pass
+    forward; backward one pass that makes `dpre` (the one float32 full-size
+    intermediate) with the tap and bias sums, and one that reads it."""
+    return jax.nn.silu(causal_depthwise_conv1d(x, w, b)).astype(x.dtype)
+
+
+def _conv_silu_fwd(x, w, b):
+    return conv_silu(x, w, b), (x, w, b)
+
+
+def _conv_silu_bwd(res, g):
+    x, w, b = res
+    k = w.shape[0]
+    acc = _acc_dtype(x, w)
+    dpre = g.astype(acc) * _silu_slope(causal_depthwise_conv1d(x, w, b))
+    db = jnp.sum(dpre, axis=(0, 1))
+    dw = jnp.stack([jnp.sum(dpre * tap.astype(acc), axis=(0, 1))
+                    for tap in _taps(x, k, k - 1)])
+    # x_s was read by tap k-1-i of position s + i
+    dx = sum(w[k - 1 - i].astype(acc) * ahead
+             for i, ahead in enumerate(_taps(dpre, k, 0)))
+    return dx.astype(x.dtype), dw.astype(w.dtype), db.astype(b.dtype)
+
+
+conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def _membership(c, groups, dtype):
+    """[c, groups]: 1 where the channel is of the group."""
+    return (jnp.arange(c)[:, None] // (c // groups)
+            == jnp.arange(groups)).astype(dtype)
+
+
+def _group_mean(a, groups):
+    """[..., c] -> [..., groups]: each group's mean. A product with the
+    membership matrix and no reshape: splitting the channel axis of a
+    full-size tensor is a relayout on the chip."""
+    c = a.shape[-1]
+    return jnp.matmul(a, _membership(c, groups, a.dtype),
+                      precision=lax.Precision.HIGHEST) * (groups / c)
+
+
+def _to_channels(a, c):
+    """[..., groups] -> [..., c]: each group's value at its channels (the
+    chip's compiler fuses no broadcast across the reshape that would merge
+    groups and channels, it does fuse this product)."""
+    return jnp.matmul(a, _membership(c, a.shape[-1], a.dtype).T,
+                      precision=lax.Precision.HIGHEST)
+
+
+def _gated(y, x, z, D):
+    """`v = (y + D x) silu(z)`, its factors and D at every channel, in the
+    accumulator dtype."""
+    acc = _acc_dtype(y, x, z, D)
+    d = jnp.repeat(D.astype(acc), y.shape[-1] // D.shape[0])
+    u = y.astype(acc) + d * x.astype(acc)
+    s = jax.nn.silu(z.astype(acc))
+    return u * s, u, s, d
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def gate_norm(y, x, z, D, gamma, eps, groups):
+    """`rms_normalize((y + D x) silu(z), gamma, eps, groups)` in z's dtype.
+    y: [b, t, c] float32 from the scan, x and z: [b, t, c] as they are
+    stored, D: [heads] (a head is `c / heads` channels), gamma: [c]."""
+    v, _, _, _ = _gated(y, x, z, D)
+    r = lax.rsqrt(_group_mean(v * v, groups) + eps)
+    return (v * _to_channels(r, v.shape[-1])
+            * gamma.astype(v.dtype)).astype(z.dtype)
+
+
+def _gate_norm_fwd(y, x, z, D, gamma, eps, groups):
+    return gate_norm(y, x, z, D, gamma, eps, groups), (y, x, z, D, gamma)
+
+
+def _gate_norm_bwd(eps, groups, res, g):
+    """With `n = v r`: `dv = r (dn - n mean_g(dn n))`, and `mean_g(dn n)`
+    is `r mean_g(dn v)`: both statistics are means over `v`, computed
+    again from the stored inputs."""
+    y, x, z, D, gamma = res
+    v, u, s, d = _gated(y, x, z, D)
+    acc, c = v.dtype, v.shape[-1]
+    gf = g.astype(acc)
+    dn = gf * gamma.astype(acc)
+    r = lax.rsqrt(_group_mean(v * v, groups) + eps)
+    r_c = _to_channels(r, c)
+    dv = r_c * dn - v * _to_channels(r * r * r * _group_mean(dn * v, groups),
+                                     c)
+    du = dv * s
+    dz = dv * u * _silu_slope(z.astype(acc))
+    dgamma = jnp.sum(gf * v * r_c, axis=(0, 1))
+    dD = jnp.sum(du * x.astype(acc), axis=(0, 1)) \
+        .reshape(D.shape[0], -1).sum(1)
+    return (du.astype(y.dtype), (du * d).astype(x.dtype), dz.astype(z.dtype),
+            dD.astype(D.dtype), dgamma.astype(gamma.dtype))
+
+
+gate_norm.defvjp(_gate_norm_fwd, _gate_norm_bwd)
+
+
+def _count_stage(stage: str) -> None:
+    """Trace-time, like conv._count_pool_lowering: one event per stage per
+    trace."""
+    _metrics.get_registry().counter(
+        "mamba2_stage_lowering_total",
+        "Mamba-2 elementwise stages traced, by backward lowering",
+        ("stage", "kind")).labels(stage, "fused_vjp").inc()
+
+
 def mamba2_forward(conf: L.Mamba2Layer, params, x, ctx: LayerContext):
     """x: [b, t, n_in] -> [b, t, n_out] in x's dtype."""
     if ctx.mask is not None:
@@ -186,25 +340,20 @@ def mamba2_forward(conf: L.Mamba2Layer, params, x, ctx: LayerContext):
     dt = jax.nn.softplus(mm(u, w_in[:, d_inner + conv_dim:])
                          + params["dt_bias"].astype(jnp.float32))
     z, xbc = z_xbc[..., :d_inner], z_xbc[..., d_inner:]
-    # conv + silu and, below, gate + norm keep their narrow inputs for the
-    # backward pass and compute their float32 intermediates again
-    xbc = jax.checkpoint(lambda a, w, c: jax.nn.silu(
-        causal_depthwise_conv1d(a, w, c)).astype(cd))(
-            xbc, params["conv_W"], params["conv_b"])
-    xs = xbc[..., :d_inner].reshape(bsz, t, H, P)
+    _count_stage("conv_silu")
+    xbc = conv_silu(xbc, params["conv_W"], params["conv_b"])
+    xs = xbc[..., :d_inner]
     B = xbc[..., d_inner:d_inner + bc].reshape(bsz, t, G, N)
     C = xbc[..., d_inner + bc:].reshape(bsz, t, G, N)
     A = -jnp.exp(params["A_log"].astype(jnp.float32))
     with jax.named_scope("ssd_scan"):
-        y = ssd_chunked(xs, dt, A, B, C, int(conf.chunk_size))
-
-    def gate_and_norm(y, xs, z, D, gamma):
-        y = y + D.astype(jnp.float32)[:, None] * xs.astype(jnp.float32)
-        y = y.reshape(bsz, t, d_inner) * jax.nn.silu(z.astype(jnp.float32))
-        return rms_normalize(y, gamma, conf.norm_eps, G).astype(cd)
-
-    y = jax.checkpoint(gate_and_norm)(y, xs, z, params["D"],
-                                      params["norm_gamma"])
+        # the head-shaped views are the scan's own: around it a channel
+        # stays a channel
+        y = ssd_chunked(xs.reshape(bsz, t, H, P), dt, A, B, C,
+                        int(conf.chunk_size)).reshape(bsz, t, d_inner)
+    _count_stage("gate_norm")
+    y = gate_norm(y, xs, z, params["D"], params["norm_gamma"],
+                  conf.norm_eps, G)
     out = mm(y, params["W_out"].astype(cd))
     return out.astype(x.dtype), None
 

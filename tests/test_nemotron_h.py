@@ -176,6 +176,145 @@ def test_chunked_scan_gradients_against_the_literal_recurrence():
         assert _rel(g, w) < 1e-4
 
 
+# -- the mixer's two elementwise stages, gradients written by hand -----------------
+
+# (batch, positions, channels, conv width) and (batch, positions, heads, head
+# size, groups): positions under the conv's width + 1 and over it, nothing a
+# multiple of 8 or 128, one group, one batch row
+CONV_CASES = {
+    "t29_k4": (2, 29, 24, 4), "t5_k4": (2, 5, 24, 4), "t29_k2": (2, 29, 24, 2),
+    "t5_k2_batch1": (1, 5, 40, 2), "t3_under_k4": (1, 3, 24, 4),
+    "c136_over_a_lane_tile": (2, 29, 136, 4)}
+GATE_CASES = {
+    "g1": (2, 29, 6, 4, 1), "g3": (2, 29, 6, 4, 3), "g8": (2, 29, 8, 5, 8),
+    "g3_t5_batch1": (1, 5, 6, 4, 3), "g8_one_head_a_group": (2, 5, 8, 3, 8),
+    "c136_over_a_lane_tile": (1, 29, 17, 8, 1)}
+
+
+def _conv_stage(shape, dtype=jnp.float32):
+    b, t, c, k = shape
+    ks = jax.random.split(jax.random.PRNGKey(b + t + c + k), 3)
+    args = (jax.random.normal(ks[0], (b, t, c)).astype(dtype),
+            jax.random.normal(ks[1], (k, c)) * 0.5,
+            jax.random.normal(ks[2], (c,)) * 0.1)
+    literal = lambda x, w, c: jax.nn.silu(ref.causal_conv1d(
+        x.astype(jnp.float32), w, c)).astype(x.dtype)
+    return ssm.conv_silu, literal, args
+
+
+def _gate_stage(shape, dtype=jnp.float32):
+    from deeplearning4j_tpu.nn.layers.norm import rms_normalize
+
+    b, t, H, P, G = shape
+    ks = jax.random.split(jax.random.PRNGKey(b + t + H + P + G), 5)
+    c = H * P
+    args = (jax.random.normal(ks[0], (b, t, c)),
+            jax.random.normal(ks[1], (b, t, c)).astype(dtype),
+            jax.random.normal(ks[2], (b, t, c)).astype(dtype),
+            1.0 + 0.3 * jax.random.normal(ks[3], (H,)),
+            1.0 + 0.3 * jax.random.normal(ks[4], (c,)))
+
+    def literal(y, x, z, D, gamma):   # the head-shaped view autodiff took
+        y = y.reshape(b, t, H, P) + D[:, None] * x.reshape(
+            b, t, H, P).astype(jnp.float32)
+        v = y.reshape(b, t, c) * jax.nn.silu(z.astype(jnp.float32))
+        return rms_normalize(v, gamma, 1e-5, G).astype(z.dtype)
+
+    stage = lambda *a: ssm.gate_norm(*a, 1e-5, G)
+    return stage, literal, args
+
+
+STAGE_CASES = {**{f"conv_silu-{k}": (_conv_stage, v)
+                  for k, v in CONV_CASES.items()},
+               **{f"gate_norm-{k}": (_gate_stage, v)
+                  for k, v in GATE_CASES.items()}}
+
+
+@pytest.mark.parametrize("case", sorted(STAGE_CASES))
+def test_a_stage_and_every_gradient_against_autodiff_of_its_formula(case):
+    make, shape = STAGE_CASES[case]
+    stage, literal, args = make(shape)
+    want, pull_want = jax.vjp(literal, *args)
+    got, pull_got = jax.vjp(stage, *args)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert _rel(got, want) < 1e-6
+    ct = jax.random.normal(jax.random.PRNGKey(9), want.shape)
+    for g, w in zip(pull_got(ct), pull_want(ct)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert _rel(g, w) < 2e-5, case
+
+
+def _float_operands_of_arithmetic(jaxpr, found):
+    """Dtypes that the arithmetic of a jaxpr (everything but moving,
+    slicing and converting) takes its floating operands in."""
+    moving = {"convert_element_type", "pad", "slice", "reshape", "squeeze",
+              "broadcast_in_dim", "concatenate", "transpose", "copy",
+              "select_n", "iota", "eq", "jit", "pjit", "custom_vjp_call",
+              "custom_jvp_call"}
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _float_operands_of_arithmetic(sub, found)
+        if eqn.primitive.name not in moving:
+            found |= {str(v.aval.dtype) for v in eqn.invars
+                      if hasattr(v.aval, "dtype")
+                      and jnp.issubdtype(v.aval.dtype, jnp.floating)}
+    return found
+
+
+@pytest.mark.parametrize("make,shape", [(_conv_stage, CONV_CASES["t29_k4"]),
+                                        (_gate_stage, GATE_CASES["g3"])],
+                         ids=["conv_silu", "gate_norm"])
+def test_a_stage_stores_what_it_stored_and_computes_in_float32(make, shape):
+    """bf16 in, bf16 out and bf16 cotangents for what is stored in bf16
+    (`xBC`, `z`, `x`); float32 for the scan's `y`, its cotangent and every
+    parameter gradient; no arithmetic on bf16 operands inside."""
+    stage, literal, args = make(shape, jnp.bfloat16)
+    out, pull = jax.vjp(stage, *args)
+    assert out.dtype == jnp.bfloat16
+    grads = pull(jnp.ones_like(out))
+    assert [g.dtype for g in grads] == [a.dtype for a in args]
+    stored = [a.dtype for a in args if a.ndim == 3]
+    assert stored in ([jnp.bfloat16],
+                      [jnp.float32, jnp.bfloat16, jnp.bfloat16])
+    assert all(a.dtype == jnp.float32 for a in args if a.ndim < 3)
+    both = jax.make_jaxpr(lambda *a: jax.vjp(stage, *a)[1](
+        jnp.ones_like(out)))(*args)
+    assert _float_operands_of_arithmetic(both.jaxpr, set()) == {"float32"}
+    # and the rounding of the stored tensors is all that separates it from
+    # the formula
+    want, pull_want = jax.vjp(literal, *args)
+    assert _rel(out.astype(jnp.float32), want.astype(jnp.float32)) < 1e-2
+    for g, w in zip(grads, pull_want(jnp.ones_like(out))):
+        assert _rel(g.astype(jnp.float32), w.astype(jnp.float32)) < 2e-2
+
+
+def _stage_counts():
+    values = get_registry().scalar_values()
+    return tuple(int(values.get(
+        f'mamba2_stage_lowering_total{{stage="{stage}",kind="fused_vjp"}}',
+        0)) for stage in ("conv_silu", "gate_norm"))
+
+
+def test_each_stage_is_counted_once_a_mixer_a_trace():
+    """The cell's nine blocks (four of them mixers) at test width count 4
+    and 4, recomputed or not; a net without mixers counts nothing."""
+    from deeplearning4j_tpu.analysis.costmodel import train_step_args
+    from deeplearning4j_tpu.models.vgg16 import vgg16_conf
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+
+    def traced(net):
+        before = _stage_counts()
+        step, args = train_step_args(net, batch_size=2)
+        jax.make_jaxpr(step)(*args)
+        return tuple(a - b for a, b in zip(_stage_counts(), before))
+
+    cell = ComputationGraph(tiny_nemotron_h_conf(
+        hybrid_override_pattern="MEMEM*EME")).init()
+    assert traced(cell) == (4, 4)
+    vgg = MultiLayerNetwork(vgg16_conf(1000, 32, "bf16")).init()
+    assert traced(vgg) == (0, 0)
+
+
 def test_a_position_never_reads_a_later_one():
     """Causality of the mixer, the conv included: changing the tokens from
     position 20 on leaves the first 20 outputs as they were."""

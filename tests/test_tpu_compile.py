@@ -13,7 +13,9 @@ The last section holds the XLA side of the step program to the compiler
 the same way: what `nn/layers/conv._pool` counts on (a max pool's backward
 as one elementwise fusion, no select-and-scatter, nothing full-size
 written twice) is a property of the chip's fusion pass, and only a
-compile for the chip shows it.
+compile for the chip shows it. The same for the Mamba-2 mixer's two
+elementwise stages (`nn/layers/ssm.conv_silu`, `gate_norm`): that their
+hand-written passes stay single lane-dense passes is the compiler's doing.
 
 This is the only test file that describes the chip, and it does so inside
 a module-scoped fixture: only one process may load the TPU library, so a
@@ -326,14 +328,24 @@ def _vgg_front_hlo(one_chip, pool):
         .compile().as_text()
 
 
+def _named_entry_ops(hlo):
+    """name -> (result type, opcode, operand names, `op_name`) of the entry
+    computation."""
+    entry = hlo[hlo.index("ENTRY"):]
+    ops = {}
+    for name, result, op, args, rest in re.findall(
+            r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*?) ([\w\-]+)\((.*?)\)(?:, (.*))?$",
+            entry[:entry.index("\n}")], re.M):
+        scope = re.search(r'op_name="([^"]*)"', rest)
+        ops[name] = (result, op, re.findall(r"%[\w.\-]+", args),
+                     scope.group(1) if scope else "")
+    return ops
+
+
 def _entry_ops(hlo):
     """(name, result type, opcode, operands) of the entry computation."""
-    entry = hlo[hlo.index("ENTRY"):]
-    ops = re.findall(
-        r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*?) ([\w\-]+)\((.*?)\)(?:, |$)",
-        entry, re.M)
-    return [(name, result, op, [a.strip() for a in args.split(",")])
-            for name, result, op, args in ops]
+    return [(name, result, op, args)
+            for name, (result, op, args, _) in _named_entry_ops(hlo).items()]
 
 
 def test_tiling_max_pool_backward_is_one_select_fusion(one_chip):
@@ -373,3 +385,85 @@ def test_overlapping_max_pool_keeps_select_and_scatter(one_chip):
     ops = _entry_ops(_vgg_front_hlo(one_chip, pool))
     assert sum(op == "select-and-scatter" for _, _, op, _ in ops) == 2
     assert not any("s8[128," in result for _, result, _, _ in ops)
+
+
+# -- the Mamba-2 mixer's elementwise stages as the chip's compiler fuses them ----
+
+_WIDTH = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1, "u8": 1,
+          "pred": 1}
+_MOVES_NOTHING = {"parameter", "get-tuple-element", "bitcast", "tuple",
+                  "constant", "after-all"}
+_LAYOUT_ONLY = {"bitcast", "copy", "reshape", "transpose"}
+
+
+def _nbytes(result):
+    """Bytes of an HLO result type, every member of a tuple counted."""
+    total = 0
+    for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", result):
+        n = _WIDTH.get(dtype, 0)
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        total += n
+    return total
+
+
+def _mixer_hlo(one_chip):
+    """Optimized HLO of one mixer at the Nemotron cell's shapes as a block
+    of the step runs it: forward, the forward again under `jax.checkpoint`
+    and the backward pass, bf16 products on a float32 residual stream."""
+    from deeplearning4j_tpu.nn.conf import layers as L
+    from deeplearning4j_tpu.nn.layers import ssm
+    from deeplearning4j_tpu.nn.layers.registry import LayerContext
+
+    conf = L.Mamba2Layer(n_in=2688, n_out=2688, n_heads=64, head_dim=64,
+                         state_size=128, n_groups=8, conv_kernel=4,
+                         chunk_size=128, norm_eps=1e-5, weight_init="xavier")
+    ctx = LayerContext(training=True, compute_dtype=BF16)
+
+    def loss(params, x):
+        mixer = jax.checkpoint(
+            lambda p, x: ssm.mamba2_forward(conf, p, x, ctx)[0])
+        return jnp.sum(jnp.square(x + mixer(params, x)))
+
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: ssm.mamba2_init(jax.random.PRNGKey(0), conf, jnp.float32)))
+    x = jax.ShapeDtypeStruct((4, 4096, 2688), jnp.float32, sharding=one_chip)
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x) \
+        .compile().as_text()
+
+
+def test_mixer_stages_stay_lane_dense_single_passes(one_chip):
+    ops = _named_entry_ops(_mixer_hlo(one_chip))
+    in_scan = lambda name: "ssd_scan" in ops[name][3]
+    users = {}
+    for name, (_, _, operands, _) in ops.items():
+        for a in operands:
+            users.setdefault(a, []).append(name)
+
+    def only_feeds_the_scan(name):
+        """Through layout changes alone into ops of the scan's scope."""
+        return bool(users.get(name)) and all(
+            in_scan(u) or (ops[u][1] in _LAYOUT_ONLY
+                           and only_feeds_the_scan(u))
+            for u in users[name])
+
+    full = 4 * 4096 * 4096 * 4            # f32[4,4096,4096] or another view
+    outside = 0
+    for name, (result, op, operands, scope) in ops.items():
+        # the conv's backward keeps ONE float32 tensor of its size (dpre)
+        assert result.count("f32[4,4096,6144]") <= 1, name
+        # the group statistic goes back to the channels inside a fusion
+        assert not (op == "broadcast" and "f32[4,4096,8,512]" in result), name
+        # no head-shaped view of a full-size tensor but the scan's own
+        if op in ("reshape", "copy", "transpose") \
+                and result.startswith("f32") and _nbytes(result) == full:
+            assert in_scan(name) or only_feeds_the_scan(name), (name, scope)
+        if op not in _MOVES_NOTHING and not in_scan(name):
+            outside += sum(_nbytes(ops[a][0]) for a in operands if a in ops)
+            outside += 0 if op.endswith("-start") else _nbytes(result)
+    # operand and result bytes of every top-level op outside the `ssd_scan`
+    # scope (a fused slice counts its whole operand): 21.4 GB as committed,
+    # 35.7 GB with the stages left to autodiff (the parent of PR 31)
+    assert outside < 23.5e9, outside
