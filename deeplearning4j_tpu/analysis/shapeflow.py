@@ -368,7 +368,7 @@ def check_compgraph(conf: ComputationGraphConfiguration) -> List[Finding]:
         loc = f"vertex:{name}"
         its = [types.get(i) for i in conf.vertex_inputs.get(name, [])]
         if isinstance(v, LayerVertex):
-            if len(its) > 1:
+            if len(its) > 1 and len(its) != v.layer.n_inputs():
                 findings.append(Finding(
                     "SF002", ERROR, loc,
                     "a LayerVertex consumes exactly one activation but has "

@@ -93,7 +93,7 @@ def _vertex_signature(v):
 
     if isinstance(v, LayerVertex):
         lc = v.layer
-        if v.preprocessor is not None:
+        if v.preprocessor is not None or lc.n_inputs() > 1:
             return None
         if _is_recurrent(lc) or isinstance(lc, _OUTPUT_LAYER_TYPES):
             return None
@@ -412,6 +412,7 @@ class ComputationGraph(NetworkBase):
                     timesteps=timesteps,
                     state=st,
                     compute_dtype=self.policy.compute_dtype,
+                    extra_inputs=tuple(xs[1:]),
                 )
                 if (
                     preout_outputs

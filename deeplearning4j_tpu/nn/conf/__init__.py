@@ -19,6 +19,7 @@ from deeplearning4j_tpu.nn.conf.layers import (
     DropoutLayer,
     EmbeddingLayer,
     EmbeddingSequenceLayer,
+    ExpertRouterLayer,
     GlobalPoolingLayer,
     GravesBidirectionalLSTM,
     GravesLSTM,

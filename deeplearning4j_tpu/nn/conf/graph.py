@@ -408,7 +408,14 @@ class GraphBuilder:
         if not inputs:
             raise ValueError(f"layer {name!r} needs at least one input")
         self._check_new(name, inputs)
-        if len(inputs) > 1:
+        if layer.n_inputs() > 1:
+            # a layer that takes its second activation as it is (the
+            # expert layer's router logits): wired, not merged
+            if len(inputs) != layer.n_inputs():
+                raise ValueError(
+                    f"layer {name!r} takes {layer.n_inputs()} inputs, "
+                    f"{len(inputs)} were given")
+        elif len(inputs) > 1:
             # a layer consumes exactly one activation: auto-insert a
             # MergeVertex over multiple inputs, as the reference does
             # (ComputationGraphConfiguration.java:580-584)

@@ -68,6 +68,12 @@ class LayerConf:
     def has_params(self) -> bool:
         return True
 
+    def n_inputs(self) -> int:
+        """Activations a graph vertex hands this layer. More than one: the
+        first is the layer's input, the rest reach its forward as
+        `LayerContext.extra_inputs` (no MergeVertex is put in front)."""
+        return 1
+
 
 @dataclasses.dataclass(kw_only=True)
 class BaseLayerConf(LayerConf):
@@ -531,15 +537,24 @@ class RMSNorm(LayerConf):
 class GroupedQueryAttentionLayer(BaseRecurrentLayerConf):
     """Causal self-attention whose `n_heads` query heads share
     `n_kv_heads` key-value heads (each read by `n_heads // n_kv_heads`
-    query heads), heads of `head_dim`; no bias, no positional term.
-    `[b, t, n_in] -> [b, t, n_out]`. With `n_kv_heads == n_heads` and
-    `head_dim == n_out // n_heads` it computes what a bias-free
-    `SelfAttentionLayer` does."""
+    query heads), heads of `head_dim`; no bias. `[b, t, n_in] -> [b, t,
+    n_out]`. With `n_kv_heads == n_heads` and `head_dim == n_out //
+    n_heads` it computes what a bias-free `SelfAttentionLayer` does.
+
+    `window` (positions; None: all that came before) lets the query at
+    `p` see the keys `p - window + 1 .. p`, its own among them. `rope_theta`
+    (None: no positional term) rotates queries and keys by their position
+    `0 .. t - 1` over the whole `head_dim`, dimension `i` paired with `i +
+    head_dim / 2` (the half-split pairing), at frequencies `rope_theta **
+    (-2 i / head_dim)`. Both are a layer's own, so that one net may hold
+    layers of either kind."""
 
     n_heads: int = 4
     n_kv_heads: int = 1
     head_dim: int = 64
     causal: bool = True
+    window: Optional[int] = None
+    rope_theta: Optional[float] = None
 
 
 @register_config("layer.mamba2")
@@ -571,12 +586,19 @@ class SparseExpertsLayer(BaseRecurrentLayerConf):
     """Sparse-expert feed-forward that is told which experts it holds.
 
     The router scores every token over all `router_width` experts
-    (sigmoid, float32), picks the `experts_per_token` largest, weights them
-    `scaling * s_i / sum of the chosen s` and computes the part of the
-    result that the experts in `experts_held` give (each `n_in -> width ->
-    n_in` with `activation`), plus one shared expert of `shared_width` for
-    every token. What the experts held elsewhere would add is left out: on
-    one chip the layer runs without its exchange.
+    (`score`: `sigmoid` or `softmax`, float32), picks the
+    `experts_per_token` largest, weights them `scaling * s_i / sum of the
+    chosen s` (under `softmax` that is the softmax over the chosen logits)
+    and computes the part of the result that the experts in `experts_held`
+    give (each `n_in -> width -> n_in`: `W2 act(W1 u)`, or with `gated`
+    the three-matrix `W2 (act(W1 u) * (W3 u))`), plus one shared expert of
+    `shared_width` for every token. What the experts held elsewhere would
+    add is left out: on one chip the layer runs without its exchange.
+
+    With `router_input` the layer holds no router of its own: the logits
+    `[b, t, router_width]` arrive as the vertex's second input (an
+    `ExpertRouterLayer` that may read another activation than the experts
+    do, as in models whose router sits before the attention block).
 
     Dropless on static shapes: assignments to held experts are gathered,
     grouped by expert, into a buffer of `capacity_factor` times the uniform
@@ -601,7 +623,24 @@ class SparseExpertsLayer(BaseRecurrentLayerConf):
     shared_width: int = 0
     scaling: float = 1.0
     capacity_factor: float = 8.0
+    gated: bool = False
+    score: str = "sigmoid"
+    router_input: bool = False
 
     def held(self) -> List[int]:
         return list(range(self.router_width)) if self.experts_held is None \
             else [int(e) for e in self.experts_held]
+
+    def n_inputs(self) -> int:
+        return 2 if self.router_input else 1
+
+
+@register_config("layer.expert_router")
+@dataclasses.dataclass(kw_only=True)
+class ExpertRouterLayer(BaseRecurrentLayerConf):
+    """The router of a sparse-expert layer as a vertex of its own: the
+    logits `x W`, `[b, t, n_in] -> [b, t, n_out]` (`n_out` the router's
+    width), in float32 at full precision whatever the net's compute type:
+    near-ties among the largest would otherwise flip with the operands'
+    rounding. A `SparseExpertsLayer(router_input=True)` takes them as its
+    second input."""

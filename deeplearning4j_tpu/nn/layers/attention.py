@@ -19,6 +19,7 @@ from deeplearning4j_tpu.nn.layers.registry import LayerContext, register_layer
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.activations import apply_activation
 from deeplearning4j_tpu.parallel.sequence import full_attention
+from deeplearning4j_tpu.utils import metrics as _metrics
 
 
 def attention_init(key, conf: L.SelfAttentionLayer, dtype):
@@ -102,17 +103,44 @@ def gqa_init(key, conf: L.GroupedQueryAttentionLayer, dtype):
             "Wv": mk(ks[2], n_in, KV * D), "Wo": mk(ks[3], H * D, n_out)}
 
 
-def _attend_block(q, k, v, start: int, causal: bool):
+# Keys above which a block's softmax takes its row maximum in a pass of its
+# own. `jax.nn.softmax` lets the chip's compiler fuse the maximum with the
+# subtraction; from 5,376 keys on it finds no tiling for that fusion (its
+# cost estimate overflows) and the one it falls back to took 41 ms for a
+# block of 256 queries on 8,192 keys where 4,352 keys take 1.1 (PERF.md, PR
+# 32). Blocks of fewer keys keep the program they had.
+WIDE_KEYS = 5120
+
+
+def _softmax_max_apart(s):
+    """softmax over the last axis, float32, the maximum behind an
+    `optimization_barrier` so that it stays a reduction of its own."""
+    m = jax.lax.optimization_barrier(jax.lax.stop_gradient(
+        jnp.max(s, axis=-1, keepdims=True)))
+    e = jnp.exp(s - m)
+    return e / jnp.sum(e, axis=-1, keepdims=True)
+
+
+def _attend_block(q, k, v, start: int, causal: bool, key_start: int = 0,
+                  window=None):
     """One block of queries against the keys it may see. q: [b, tq, KV, G,
-    D] (its first position is `start`), k/v: [b, tk, KV, D]; scores and
-    softmax in float32, both products on operands of the inputs' dtype."""
+    D] (its first position is `start`), k/v: [b, tk, KV, D] (their first
+    position is `key_start`); scores, mask and softmax in float32, both
+    products on operands of the inputs' dtype. With `window` a query sees
+    the `window` keys that end at its own position."""
     scale = 1.0 / (q.shape[-1] ** 0.5)
     s = jnp.einsum("bqkgd,bskd->bkgqs", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         qpos = start + jnp.arange(q.shape[1])[:, None]
-        s = jnp.where(qpos >= jnp.arange(k.shape[1])[None, :], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
+        if window is None:    # the mask as it was traced before windows
+            seen = qpos >= jnp.arange(k.shape[1])[None, :]
+        else:
+            kpos = key_start + jnp.arange(k.shape[1])[None, :]
+            seen = (qpos >= kpos) & (qpos - kpos < window)
+        s = jnp.where(seen, s, -jnp.inf)
+    p = _softmax_max_apart(s) if k.shape[1] > WIDE_KEYS \
+        else jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32)
 
@@ -122,31 +150,140 @@ def _attend_block(q, k, v, start: int, causal: bool):
 QUERY_BLOCK = 256
 
 
-def grouped_query_attention(q, k, v, *, causal: bool):
+def _key_start(start: int, window, block: int) -> int:
+    """First key a query block at `start` is multiplied with: the start of
+    the key block that holds the oldest key its first query sees."""
+    if window is None:
+        return 0
+    return max(0, (start - int(window) + 1) // block * block)
+
+
+def key_block_pairs(t: int, window, block: int = None, causal: bool = True):
+    """(multiplied, skipped): the (query block, key block) pairs over `t`
+    positions that the blocked product multiplies, and those under the
+    diagonal that a window lets a causal layer leave out."""
+    block = block or QUERY_BLOCK
+    if not causal:    # every block of queries meets every key
+        return (-(-t // block)) ** 2, 0
+    multiplied = skipped = 0
+    for start in range(0, t, block):
+        first = _key_start(start, window, block)
+        skipped += first // block
+        multiplied += -(-(min(start + block, t) - first) // block)
+    return multiplied, skipped
+
+
+def _count_lowering(conf, t: int) -> None:
+    """Trace-time, like conv._count_pool_lowering: the layer once a trace,
+    and the key blocks its product multiplies or leaves out."""
+    reg = _metrics.get_registry()
+    reg.counter(
+        "attention_lowering_total",
+        "grouped-query attention layers traced, by what a query sees "
+        "(window or full) and the positional term (rope or none)",
+        ("kind", "positions")).labels(
+            "full" if conf.window is None else "window",
+            "none" if conf.rope_theta is None else "rope").inc()
+    pairs = reg.counter(
+        "attention_key_blocks_total",
+        "(query block, key block) pairs of the traced attention layers' "
+        "blocked products: multiplied, or under the diagonal and skipped "
+        "because they lie wholly before the window", ("state",))
+    multiplied, skipped = key_block_pairs(t, conf.window,
+                                          causal=conf.causal)
+    pairs.labels("multiplied").inc(multiplied)
+    pairs.labels("skipped").inc(skipped)
+
+
+def grouped_query_attention(q, k, v, *, causal: bool, window=None):
     """q: [b, t, H, D], k/v: [b, t, KV, D] -> [b, t, H, D] float32; query
     head `h` reads key-value head `h // (H // KV)`. Queries are taken
     `QUERY_BLOCK` at a time against the keys up to the block's end (a
-    causal layer never multiplies the blocks above the diagonal), each
-    block under `jax.checkpoint` so that one block's scores live at once."""
+    causal layer never multiplies the blocks above the diagonal, a window
+    layer none that lie wholly before the window either), each block under
+    `jax.checkpoint` so that one block's scores live at once. Past the
+    window every whole block of a window layer meets the same number of
+    keys: those blocks are one scanned body, traced and compiled once."""
     b, t, H, D = q.shape
     KV = k.shape[2]
+    if window is not None and not causal:
+        raise ValueError("a window is a causal layer's")
     q = q.reshape(b, t, KV, H // KV, D)
+    blocks = [(start, min(start + QUERY_BLOCK, t))
+              for start in range(0, t, QUERY_BLOCK)]
+    # past the window every whole block sees `back` keys behind its own
+    steady = []
+    if window is not None:
+        back = -((1 - int(window)) // QUERY_BLOCK) * QUERY_BLOCK
+        steady = [(s, e) for s, e in blocks
+                  if s >= back and e - s == QUERY_BLOCK]
+        steady = steady if len(steady) > 1 else []
     outs = []
-    for start in range(0, t, QUERY_BLOCK):
-        end = min(start + QUERY_BLOCK, t)
+    for start, end in blocks:
+        if steady and start == steady[0][0]:
+            outs.append(_steady_blocks(q, k, v, steady, back, int(window)))
+        if (start, end) in steady:
+            continue
+        first = _key_start(start, window, QUERY_BLOCK)
         seen = end if causal else t
-        block = partial(_attend_block, start=start, causal=causal)
+        block = partial(_attend_block, start=start, causal=causal,
+                        key_start=first, window=window)
         if t > QUERY_BLOCK:
             block = jax.checkpoint(block)
-        outs.append(block(q[:, start:end], k[:, :seen], v[:, :seen]))
+        outs.append(block(q[:, start:end], k[:, first:seen],
+                          v[:, first:seen]))
     o = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
     return o.reshape(b, t, H, D)
+
+
+def _steady_blocks(q, k, v, steady, back: int, window: int):
+    """The query blocks `steady` (consecutive, whole, each `back` positions
+    behind its first key) as one `lax.map` over blocks: the body slices its
+    keys from `k`/`v` where the block's window starts, and its mask is the
+    same for every block."""
+    b, _, KV, G, D = q.shape
+    n, lo = len(steady), steady[0][0]
+    keys = back + QUERY_BLOCK
+
+    @jax.checkpoint
+    def body(args):
+        q_blk, key_start = args
+        k_blk = jax.lax.dynamic_slice_in_dim(k, key_start, keys, axis=1)
+        v_blk = jax.lax.dynamic_slice_in_dim(v, key_start, keys, axis=1)
+        # positions relative to the first key: the mask does not move
+        return _attend_block(q_blk, k_blk, v_blk, start=back, causal=True,
+                             key_start=0, window=window)
+
+    q_blocks = jnp.moveaxis(
+        q[:, lo:lo + n * QUERY_BLOCK].reshape(b, n, QUERY_BLOCK, KV, G, D),
+        1, 0)
+    starts = jnp.arange(n, dtype=jnp.int32) * QUERY_BLOCK + (lo - back)
+    out = jax.lax.map(body, (q_blocks, starts))
+    return jnp.moveaxis(out, 0, 1).reshape(b, n * QUERY_BLOCK, KV, G, D)
+
+
+def rope(x, theta: float):
+    """Rotary positions over the whole head: x [b, t, heads, D] at
+    positions `0 .. t - 1`, dimension `i` paired with `i + D / 2`
+    (`rotate_half`), angle `p * theta ** (-2 i / D)`. Computed in float32,
+    returned in x's dtype."""
+    t, D = x.shape[1], x.shape[-1]
+    half = D // 2
+    inv_freq = 1.0 / (float(theta) ** (
+        jnp.arange(half, dtype=jnp.float32) * 2.0 / D))
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
 
 
 def gqa_forward(conf: L.GroupedQueryAttentionLayer, params, x,
                 ctx: LayerContext):
     """x: [b, t, n_in] -> [b, t, n_out] in x's dtype; the products run in
-    the net's compute dtype with float32 accumulation."""
+    the net's compute dtype with float32 accumulation, the rotation in
+    float32."""
     if ctx.mask is not None:
         raise NotImplementedError(
             "GroupedQueryAttentionLayer takes no time mask (packed or padded "
@@ -158,8 +295,15 @@ def gqa_forward(conf: L.GroupedQueryAttentionLayer, params, x,
     proj = lambda name, heads: jnp.matmul(
         u, params[name].astype(cd), preferred_element_type=jnp.float32
     ).astype(cd).reshape(B, T, heads, D)
-    o = grouped_query_attention(
-        proj("Wq", H), proj("Wk", KV), proj("Wv", KV), causal=conf.causal)
+    q, k, v = proj("Wq", H), proj("Wk", KV), proj("Wv", KV)
+    if conf.rope_theta is not None:
+        with jax.named_scope("rope"):
+            q, k = rope(q, conf.rope_theta), rope(k, conf.rope_theta)
+    with jax.named_scope("full_attention" if conf.window is None
+                         else "window_attention"):
+        o = grouped_query_attention(q, k, v, causal=conf.causal,
+                                    window=conf.window)
+    _count_lowering(conf, T)
     y = jnp.matmul(o.astype(cd).reshape(B, T, H * D), params["Wo"].astype(cd),
                    preferred_element_type=jnp.float32)
     return y.astype(x.dtype), None

@@ -1,8 +1,12 @@
 """The sparse-expert feed-forward (config: SparseExpertsLayer).
 
-    s = sigmoid(u W_router)              over all `router_width` experts, f32
+    l = u W_router                       over all `router_width` experts, f32
+                                         (or the vertex's second input:
+                                         `router_input`, an ExpertRouterLayer)
+    s = sigmoid(l) | softmax(l)          `score`
     chosen = the `experts_per_token` largest s;  w_i = scaling s_i / sum s
     out = sum over chosen i held here of w_i W2_i act(W1_i u)
+          (`gated`: w_i W2_i (act(W1_i u) * (W3_i u)))
           + Ws2 act(Ws1 u)               the shared expert, every token
 
 The layer is told which experts it holds (`experts_held`) and computes their
@@ -46,6 +50,7 @@ from deeplearning4j_tpu.ops.activations import apply_activation
 from deeplearning4j_tpu.utils import metrics as _metrics
 
 _ROWS = 128   # a held expert's capacity is a multiple of this many rows
+SCORES = ("sigmoid", "softmax")
 
 
 def expert_capacity(conf: L.SparseExpertsLayer, tokens: int) -> int:
@@ -68,13 +73,19 @@ def experts_init(key, conf: L.SparseExpertsLayer, dtype):
             0 <= e < int(conf.router_width) for e in held):
         raise ValueError(f"experts_held {held} are not distinct experts "
                          f"below router_width {conf.router_width}")
-    ks = jax.random.split(key, 5)
+    if conf.score not in SCORES:
+        raise ValueError(f"SparseExpertsLayer: score {conf.score!r} is not "
+                         f"one of {SCORES}")
+    ks = jax.random.split(key, 6)
     mk = lambda k, shape, i, o: init_weights(
         k, shape, i, o, conf.weight_init, conf.dist, dtype)
-    p = {"W_router": mk(ks[0], (n_in, int(conf.router_width)), n_in,
-                        int(conf.router_width)),
-         "W1": mk(ks[1], (len(held), n_in, width), n_in, width),
+    p = {"W1": mk(ks[1], (len(held), n_in, width), n_in, width),
          "W2": mk(ks[2], (len(held), width, n_in), width, n_in)}
+    if not conf.router_input:
+        p["W_router"] = mk(ks[0], (n_in, int(conf.router_width)), n_in,
+                           int(conf.router_width))
+    if conf.gated:
+        p["W3"] = mk(ks[5], (len(held), n_in, width), n_in, width)
     if conf.shared_width:
         sw = int(conf.shared_width)
         p["Ws1"] = mk(ks[3], (n_in, sw), n_in, sw)
@@ -83,7 +94,8 @@ def experts_init(key, conf: L.SparseExpertsLayer, dtype):
 
 
 def experts_order(conf):
-    return ("W_router", "W1", "W2") + (
+    return (() if conf.router_input else ("W_router",)) + ("W1", "W2") + (
+        ("W3",) if conf.gated else ()) + (
         ("Ws1", "Ws2") if conf.shared_width else ())
 
 
@@ -103,6 +115,12 @@ def route(conf: L.SparseExpertsLayer, scores):
     top, idx = jax.lax.top_k(scores, int(conf.experts_per_token))
     return idx.astype(jnp.int32), \
         float(conf.scaling) * top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+def router_logits(x, w):
+    """`x W` in float32 at full precision, whatever the net computes in."""
+    return jnp.matmul(x.astype(jnp.float32), w.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
@@ -127,9 +145,18 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
         # the router reads the layer's input as it came (float32 on a
         # float32 residual stream) at full precision: near-ties among the
         # k largest would otherwise flip with the operands' rounding
-        scores = jax.nn.sigmoid(jnp.matmul(
-            xf.astype(jnp.float32), params["W_router"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
+        if conf.router_input:
+            logits = ctx.extra_inputs[0].reshape(
+                tokens, int(conf.router_width)).astype(jnp.float32)
+        else:
+            logits = router_logits(xf, params["W_router"])
+        if conf.score == "softmax":
+            # over the chosen it is the softmax of their logits; the row's
+            # largest taken out first, so that no exponential overflows
+            scores = jnp.exp(logits - jax.lax.stop_gradient(
+                jnp.max(logits, axis=-1, keepdims=True)))
+        else:
+            scores = jax.nn.sigmoid(logits)
         idx, w = route(conf, scores)
         # where each assignment goes: the slot of its expert here (none:
         # held elsewhere) and its rank among that expert's assignments.
@@ -170,12 +197,16 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
             "rows": jnp.asarray(cap, jnp.int32)}
 
     w1, w2 = params["W1"].astype(cd), params["W2"].astype(cd)
+    w3 = params["W3"].astype(cd) if conf.gated else None
 
     def grouped():
         rows = u[slot_token].reshape(n_held, cap, d)
         hidden = act(jnp.einsum("ecd,edf->ecf", rows, w1,
-                                preferred_element_type=jnp.float32)
-                     ).astype(cd)
+                                preferred_element_type=jnp.float32))
+        if conf.gated:
+            hidden = hidden * jnp.einsum("ecd,edf->ecf", rows, w3,
+                                         preferred_element_type=jnp.float32)
+        hidden = hidden.astype(cd)
         out_rows = jnp.einsum("ecf,efd->ecd", hidden, w2,
                               preferred_element_type=jnp.float32)
         out_rows = out_rows.reshape(n_held * cap, d) * slot_w[:, None]
@@ -186,14 +217,19 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
         # every held expert over every token, weighted by the router's
         # weight where the token chose it and by nought elsewhere
         def one(y, expert):
-            w1_e, w2_e, number = expert
+            w1_e, w2_e, number = expert[:3]
             weight = jnp.sum(jnp.where(idx == number, w, 0.0), axis=-1)
-            return y + weight[:, None] * mm(act(mm(u, w1_e)).astype(cd),
-                                            w2_e), None
+
+            def hidden():
+                h = act(mm(u, w1_e))
+                return (h * mm(u, expert[3]) if conf.gated else h).astype(cd)
+
+            return y + weight[:, None] * mm(hidden(), w2_e), None
 
         y, _ = jax.lax.scan(jax.checkpoint(one),
                             jnp.zeros((tokens, d), jnp.float32),
-                            (w1, w2, jnp.asarray(held, jnp.int32)))
+                            (w1, w2, jnp.asarray(held, jnp.int32))
+                            + ((w3,) if conf.gated else ()))
         return y
 
     with jax.named_scope("experts"):
@@ -293,3 +329,20 @@ def publish_expert_books(confs, books) -> Dict[str, float]:
 register_layer(L.SparseExpertsLayer, experts_init, experts_forward,
                order_fn=experts_order, state_fn=experts_state,
                publish_fn=publish_expert_books)
+
+
+# -- the router as a vertex of its own ------------------------------------------
+
+def router_init(key, conf: L.ExpertRouterLayer, dtype):
+    n_in, n_out = int(conf.n_in), int(conf.n_out)
+    return {"W": init_weights(key, (n_in, n_out), n_in, n_out,
+                              conf.weight_init, conf.dist, dtype)}
+
+
+def router_forward(conf: L.ExpertRouterLayer, params, x, ctx: LayerContext):
+    """x: [b, t, n_in] -> the logits [b, t, n_out] in float32."""
+    return router_logits(x, params["W"]), None
+
+
+register_layer(L.ExpertRouterLayer, router_init, router_forward,
+               order_fn=lambda conf: ("W",))

@@ -42,6 +42,9 @@ class LayerContext:
     # the net's PrecisionPolicy compute dtype, for layers that choose the
     # dtype of their matrix products themselves (None: follow the input)
     compute_dtype: Optional[Any] = None
+    # a graph vertex's activations beyond the first, for a layer whose
+    # conf says `n_inputs() > 1` (the expert layer's router logits)
+    extra_inputs: Tuple[Any, ...] = ()
 
 
 def register_layer(conf_cls, init_fn, forward_fn, order_fn=None, state_fn=None,

@@ -16,6 +16,8 @@ written twice) is a property of the chip's fusion pass, and only a
 compile for the chip shows it. The same for the Mamba-2 mixer's two
 elementwise stages (`nn/layers/ssm.conv_silu`, `gate_norm`): that their
 hand-written passes stay single lane-dense passes is the compiler's doing.
+And for a window layer of attention at the SmallThinker cell's shapes: that
+the blocks past the window are one loop and no score tensor spans all keys.
 
 This is the only test file that describes the chip, and it does so inside
 a module-scoped fixture: only one process may load the TPU library, so a
@@ -467,3 +469,81 @@ def test_mixer_stages_stay_lane_dense_single_passes(one_chip):
     # scope (a fused slice counts its whole operand): 21.4 GB as committed,
     # 35.7 GB with the stages left to autodiff (the parent of PR 31)
     assert outside < 23.5e9, outside
+
+
+# -- window attention at the SmallThinker cell's shapes ---------------------------
+
+def _attention_hlo(one_chip, **kind):
+    """Optimized HLO and memory of one attention layer at the SmallThinker
+    cell's shapes as a sub-block of the step runs it: forward, the forward
+    again under `jax.checkpoint`, backward."""
+    from deeplearning4j_tpu.nn.conf import layers as L
+    from deeplearning4j_tpu.nn.layers import attention
+    from deeplearning4j_tpu.nn.layers.registry import LayerContext
+
+    conf = L.GroupedQueryAttentionLayer(
+        n_in=2560, n_out=2560, n_heads=28, n_kv_heads=4, head_dim=128,
+        weight_init="xavier", **kind)
+    ctx = LayerContext(training=True, compute_dtype=BF16)
+
+    def loss(params, x):
+        layer = jax.checkpoint(
+            lambda p, x: attention.gqa_forward(conf, p, x, ctx)[0])
+        return jnp.sum(jnp.square(x + layer(params, x)))
+
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: attention.gqa_init(jax.random.PRNGKey(0), conf, jnp.float32)))
+    x = jax.ShapeDtypeStruct((2, 8192, 2560), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x) \
+        .compile()
+    return compiled.as_text(), compiled.memory_analysis()
+
+
+_NO_TILING = '"estimated_cycles":"9223372036854775807"'
+
+
+def test_the_full_layers_wide_blocks_find_a_tiling(one_chip):
+    """A block of the full layer meets up to 8,192 keys. With the row
+    maximum fused into `jax.nn.softmax`'s subtraction the compiler finds no
+    tiling from 5,376 keys on (its cost estimate overflows; on the chip such
+    a fusion took 41 ms where 4,352 keys take 1.1: PERF.md, PR 32), so those
+    blocks take the maximum in a pass of its own (`attention.WIDE_KEYS`)."""
+    from deeplearning4j_tpu.nn.layers import attention
+
+    hlo, memory = _attention_hlo(one_chip)
+    assert "full_attention" in hlo and "rope" not in hlo
+    assert re.search(r"f32\[2,4,7,256,8192\]", hlo)
+    assert _NO_TILING not in hlo
+    assert memory.temp_size_in_bytes < 3.0e9
+    # the compiler still has the fault this works around: when it is gone,
+    # `WIDE_KEYS` and `_softmax_max_apart` can go too
+    was = attention.WIDE_KEYS
+    attention.WIDE_KEYS = 1 << 30
+    try:
+        fused, _ = _attention_hlo(one_chip)
+    finally:
+        attention.WIDE_KEYS = was
+    assert fused.count(_NO_TILING) == 24      # 12 wide blocks, twice each
+
+
+def test_a_window_layer_is_one_scanned_body_past_the_window(one_chip):
+    """One window layer of the cell `smallthinker_21b_train_b2_s8192` as a
+    sub-block of the step runs it (forward, the forward again under
+    `jax.checkpoint`, backward): the chip's compiler takes the rotation,
+    the sixteen unrolled blocks and the scanned body of the other sixteen,
+    and a block's float32 scores `[2, 4, 7, 256, <= 4352]` are the largest
+    thing alive, not a layer's."""
+    hlo, memory = _attention_hlo(one_chip, window=4096, rope_theta=1.5e6)
+    # the steady blocks: one loop forward, one recomputed, one backward
+    loops = [l for l in hlo.splitlines()
+             if re.search(r"= .* while\(", l) and "window_attention" in l]
+    assert len(loops) == 3, len(loops)
+    assert "rope" in hlo
+    # no score tensor over all 8,192 keys: the band is followed
+    assert not re.search(r"f32\[2,4,7,256,8192\]", hlo)
+    assert re.search(r"f32\[2,4,7,256,4352\]", hlo)
+    # a block's scores are 0.25 GB; a layer's would be 7.7
+    assert _NO_TILING not in hlo
+    assert memory.temp_size_in_bytes < 3.0e9
