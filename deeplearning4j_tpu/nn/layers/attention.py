@@ -1,10 +1,27 @@
-"""Multi-head self-attention layer impl (config: SelfAttentionLayer).
+"""Attention layer impls (configs: SelfAttentionLayer,
+GroupedQueryAttentionLayer).
 
-Single-device forward uses parallel/sequence.full_attention; the SAME math
-runs sequence-parallel over a mesh via ring_self_attention (parallel/
-sequence.py) — tests prove block-ring == full. Time masking multiplies
-attention scores' keys (masked keys unattendable) and zeroes masked
-outputs, matching the framework's RNN masking semantics.
+SelfAttentionLayer: single-device forward uses parallel/sequence.
+full_attention; the SAME math runs sequence-parallel over a mesh via
+ring_self_attention (parallel/sequence.py) — tests prove block-ring ==
+full. Time masking multiplies attention scores' keys (masked keys
+unattendable) and zeroes masked outputs, matching the framework's RNN
+masking semantics.
+
+GroupedQueryAttentionLayer (causal, full or windowed, optional rotary
+positions, no time mask): projections and `rope` are XLA's; the inner part
+(scores, band mask, softmax, mix: `grouped_query_attention`) is one
+algorithm with two lowerings, chosen by what the trace can observe. The
+fused kernel (ops/pallas_attention.py, helper slot "gqa_attention") serves
+a one-device program on a TPU with bf16 operands, a causal layer,
+`head_dim` a multiple of 128 and a sequence that is a multiple of 128 (up
+to 16,384 positions at heads of 128: the backward keeps a key-value head's
+`dk` and `dv` in VMEM); no `[queries, keys]` array reaches HBM there. Everything else — the CPU,
+float32 operands, other head sizes or lengths, a program partitioned over
+a mesh — takes the built-in blocked XLA lowering below (`QUERY_BLOCK`
+queries at a time under `jax.checkpoint`, the blocks past the window one
+scanned body). `helper_hit_total` / `helper_fallback_total{op=
+"gqa_attention"}` say which ran, once a layer a trace.
 """
 
 from __future__ import annotations
@@ -18,6 +35,7 @@ from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.layers.registry import LayerContext, register_layer
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.activations import apply_activation
+from deeplearning4j_tpu.ops.helpers import HelperError, get_helper
 from deeplearning4j_tpu.parallel.sequence import full_attention
 from deeplearning4j_tpu.utils import metrics as _metrics
 
@@ -108,7 +126,9 @@ def gqa_init(key, conf: L.GroupedQueryAttentionLayer, dtype):
 # subtraction; from 5,376 keys on it finds no tiling for that fusion (its
 # cost estimate overflows) and the one it falls back to took 41 ms for a
 # block of 256 queries on 8,192 keys where 4,352 keys take 1.1 (PERF.md, PR
-# 32). Blocks of fewer keys keep the program they had.
+# 32). Blocks of fewer keys keep the program they had. Since PR 33 this
+# guards the built-in lowering alone: where the fused kernel serves a
+# layer, no block's softmax is XLA's.
 WIDE_KEYS = 5120
 
 
@@ -197,17 +217,31 @@ def _count_lowering(conf, t: int) -> None:
 
 def grouped_query_attention(q, k, v, *, causal: bool, window=None):
     """q: [b, t, H, D], k/v: [b, t, KV, D] -> [b, t, H, D] float32; query
-    head `h` reads key-value head `h // (H // KV)`. Queries are taken
-    `QUERY_BLOCK` at a time against the keys up to the block's end (a
-    causal layer never multiplies the blocks above the diagonal, a window
-    layer none that lie wholly before the window either), each block under
-    `jax.checkpoint` so that one block's scores live at once. Past the
-    window every whole block of a window layer meets the same number of
-    keys: those blocks are one scanned body, traced and compiled once."""
-    b, t, H, D = q.shape
-    KV = k.shape[2]
+    head `h` reads key-value head `h // (H // KV)`. The fused kernel where
+    its probe takes the shapes (ops/pallas_attention.py), else the blocked
+    XLA lowering."""
     if window is not None and not causal:
         raise ValueError("a window is a causal layer's")
+    helper = get_helper("gqa_attention", q_shape=tuple(q.shape),
+                        dtype=q.dtype, causal=causal, window=window)
+    if helper is not None:
+        try:
+            return helper(q, k, v, causal=causal, window=window)
+        except HelperError:
+            pass    # disabled and logged: the built-in lowering below
+    return _blocked_attention(q, k, v, causal=causal, window=window)
+
+
+def _blocked_attention(q, k, v, *, causal: bool, window=None):
+    """The built-in lowering. Queries are taken `QUERY_BLOCK` at a time
+    against the keys up to the block's end (a causal layer never multiplies
+    the blocks above the diagonal, a window layer none that lie wholly
+    before the window either), each block under `jax.checkpoint` so that
+    one block's scores live at once. Past the window every whole block of a
+    window layer meets the same number of keys: those blocks are one
+    scanned body, traced and compiled once."""
+    b, t, H, D = q.shape
+    KV = k.shape[2]
     q = q.reshape(b, t, KV, H // KV, D)
     blocks = [(start, min(start + QUERY_BLOCK, t))
               for start in range(0, t, QUERY_BLOCK)]

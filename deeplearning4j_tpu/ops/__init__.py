@@ -17,4 +17,8 @@ from deeplearning4j_tpu.ops.helpers import (
 
 # vendor kernels register themselves on import; with one installation a
 # kernel module that cannot be imported is a bug, so this raises
-from deeplearning4j_tpu.ops import pallas_conv_bn, pallas_lstm  # noqa: F401,E402
+from deeplearning4j_tpu.ops import (  # noqa: F401,E402
+    pallas_attention,
+    pallas_conv_bn,
+    pallas_lstm,
+)

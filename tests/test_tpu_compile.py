@@ -547,3 +547,44 @@ def test_a_window_layer_is_one_scanned_body_past_the_window(one_chip):
     # a block's scores are 0.25 GB; a layer's would be 7.7
     assert _NO_TILING not in hlo
     assert memory.temp_size_in_bytes < 3.0e9
+
+
+# -- the fused attention kernel at both decoder cells' shapes -----------------------
+
+FUSED_ATTENTION = {
+    "smallthinker_window": ((2, 8192, 28, 4), 4096),
+    "smallthinker_full": ((2, 8192, 28, 4), None),
+    "nemotron_full": ((4, 4096, 32, 2), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FUSED_ATTENTION))
+def test_fused_attention_compiles_for_v5e(case, one_chip):
+    """`ops/pallas_attention.gqa_attention` as a recomputed block runs it
+    (forward, the forward again under `jax.checkpoint`, backward) at the
+    shapes its probe takes from the two decoder cells: the chip's compiler
+    takes both kernels at tiles of 1,024 with `dk` and `dv` of one key-value
+    head resident, and nothing of `[queries, keys]` is left in the program
+    around them."""
+    from deeplearning4j_tpu.ops import pallas_attention
+
+    (b, t, heads, kv_heads), window = FUSED_ATTENTION[case]
+    assert pallas_attention._block(t) == 1024
+
+    def loss(q, k, v):
+        attend = jax.checkpoint(lambda q, k, v: pallas_attention.gqa_attention(
+            q, k, v, causal=True, window=window))
+        return jnp.sum(jnp.square(attend(q, k, v)))
+
+    arg = lambda n: jax.ShapeDtypeStruct((b, t, n, 128), BF16,
+                                         sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg(heads), arg(kv_heads), arg(kv_heads)).compile()
+    hlo = compiled.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call"', hlo)
+    assert len(calls) == 3, len(calls)       # forward twice, backward once
+    assert "gqa_fwd" in hlo and "gqa_bwd" in hlo
+    # no array with two sequence-sized dimensions: the scores stay in VMEM
+    assert not re.search(rf"\[[\d,]*{t},[\d,]*{t}[\d,]*\]", hlo)
+    # q, k, v, o, the cotangents and two statistics a query: under 1 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
