@@ -449,17 +449,35 @@ def test_an_unknown_workload_is_refused():
 
 # -- BENCHMARK.json and the files found by its names ----------------------------
 
-def test_benchmark_json_has_exactly_the_contracts_keys():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def check_keys_and_counts(bench):
+    """What the contract says of the file as a whole: its keys, the run's
+    length, and how many entries each list may hold (never how many it
+    holds today)."""
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert 1 <= BENCH["run_seconds"] <= 51
-    cells = len(BENCH["workloads"])
-    assert (2 + 14 * 24) * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 \
+    assert 1 <= bench["run_seconds"] <= 51
+    assert (2 + 14 * 24) * (bench["run_seconds"] + 60) + 24 * 180 + 1200 \
         <= 43200, "run_seconds does not fit a full check of 24 cells"
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) \
+    cells = len(bench["workloads"])
+    assert 1 <= len(bench["configs"]) <= 24 and 1 <= cells <= 24
+    assert 1 <= len(bench["end_to_end"]) <= 16
+    assert 1 <= len(bench["per_layer"]) <= 128
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) \
         <= max(1, cells // 4)
     assert any(m["name"] == "setup_s" and m["bound"] <= 0.1
-               for m in BENCH["end_to_end"])
+               for m in bench["end_to_end"])
+    for key in ("configs", "workloads"):
+        names = [e["name"] for e in bench[key]]
+        assert len(set(names)) == len(names), f"a name twice in {key}"
+    names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
+    assert len(set(names)) == len(names), "a metric's name twice"
+    assert {c["name"] for c in bench["configs"]} == {
+        w["config"] for w in bench["workloads"]}, \
+        "every configuration is used by some cell"
+
+
+def test_benchmark_json_has_exactly_the_contracts_keys():
+    check_keys_and_counts(BENCH)
 
 
 # What the contract asks of a cell whatever its model, as functions of a
@@ -569,9 +587,35 @@ def check_cell(root, cell):
     return loaded
 
 
+GENERIC = {"data_wait_ms.train", "dispatch_ms.train", "step_mfu_pct.train",
+           "device_step_ms.train", "device_idle_pct.train",
+           "peak_hbm_gib.train"}
+
+
+def check_cell_metrics(root, cell, own=()):
+    """The per-layer metrics a traced run of `cell` is asked for: the six
+    that list no cell, the cell's `own`, and beside them exactly the
+    entries that list the cell or list none and move a metric the cell
+    reports. However many entries a later PR appends, for whichever cells."""
+    bench = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    loaded = bench_run.load_cell(root, cell)
+    moved = {m["name"] for m in loaded["end_to_end"]}
+    assert moved >= {"train_examples_per_s_per_chip", "setup_s"}
+    names = [m["name"] for m in loaded["per_layer"]]
+    assert len(set(names)) == len(names)
+    assert GENERIC | set(own) <= set(names)
+    assert set(names) == {
+        m["name"] for m in bench["per_layer"]
+        if cell in m.get("workloads", [cell]) and m["moves"] in moved}
+    for name in own:
+        entry = next(m for m in bench["per_layer"] if m["name"] == name)
+        assert cell in entry["workloads"], name
+    return loaded
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_every_cell_loads_by_name(cell):
-    loaded = bench_run.load_cell(ROOT, cell)
+    loaded = check_cell_metrics(ROOT, cell)
     config = loaded["config"]
     assert config["assumed"]
     limits = config["limits"]
@@ -712,9 +756,7 @@ def test_a_scratch_cell_that_breaks_the_contract_fails(config, traffic, why,
         check_cell(root, "seq_cut_train")
 
 
-@pytest.mark.parametrize("entry", BENCH["end_to_end"] + BENCH["per_layer"],
-                         ids=lambda m: m["name"])
-def test_metric_entries_keep_to_the_contract(entry):
+def check_metric_entry(bench, entry):
     assert NAME.match(entry["name"]) and UNIT.match(entry["unit"])
     assert entry["better"] in ("lower", "higher")
     assert entry["source"] in ("device_trace", "program_span",
@@ -726,27 +768,37 @@ def test_metric_entries_keep_to_the_contract(entry):
         assert 0.01 <= entry["bound"] <= 0.1
         assert entry["source"] in ("host_clock", "device_trace")
     else:
-        assert entry["moves"] in {m["name"] for m in BENCH["end_to_end"]}
+        assert entry["moves"] in {m["name"] for m in bench["end_to_end"]}
         assert "\n" not in entry["layer"] and len(entry["layer"]) <= 200
     for cell in entry.get("workloads", []):
-        assert cell in {w["name"] for w in BENCH["workloads"]}
+        assert cell in {w["name"] for w in bench["workloads"]}
 
 
-@pytest.mark.parametrize("entry", BENCH["configs"] + BENCH["workloads"],
-                         ids=lambda e: e["name"])
-def test_config_and_cell_entries_keep_to_the_contract(entry):
+@pytest.mark.parametrize("entry", BENCH["end_to_end"] + BENCH["per_layer"],
+                         ids=lambda m: m["name"])
+def test_metric_entries_keep_to_the_contract(entry):
+    check_metric_entry(BENCH, entry)
+
+
+def check_config_or_cell_entry(root, bench, entry):
     assert NAME.match(entry["name"])
     assert 1 <= len(entry["why"]) <= 200 and "\n" not in entry["why"]
     if "file" in entry:
         assert set(entry) == {"name", "source", "file", "reduced", "why"}
         assert any(entry["file"].startswith(p + "/")
-                   for p in BENCH["paths"])
-        assert json.load(open(os.path.join(ROOT, entry["file"])))[
+                   for p in bench["paths"])
+        assert json.load(open(os.path.join(root, entry["file"])))[
             "reduced"] == entry["reduced"]
     else:
         assert set(entry) == {"name", "config", "traffic", "chips", "why"}
         assert entry["chips"] in (1, 4)
-        assert entry["config"] in {c["name"] for c in BENCH["configs"]}
+        assert entry["config"] in {c["name"] for c in bench["configs"]}
+
+
+@pytest.mark.parametrize("entry", BENCH["configs"] + BENCH["workloads"],
+                         ids=lambda e: e["name"])
+def test_config_and_cell_entries_keep_to_the_contract(entry):
+    check_config_or_cell_entry(ROOT, BENCH, entry)
 
 
 def test_peaks_table_holds_the_v5e_with_its_source():
@@ -880,10 +932,14 @@ def test_a_traced_run_reports_the_per_layer_metrics_it_can_read(tmp_path):
     out = _run(TINY_RESNET, tmp_path, seconds=1.0, trace=True)
     line = out["line"]
     assert line["correct"]
-    # the CPU trace has no device plane: the trace's readers read nothing
-    # and are left out; the counters' and the clock's are there
-    assert set(line["metrics"]) == {"data_wait_ms.train", "dispatch_ms.train",
+    # the CPU trace has no device plane: the readers of the trace and of
+    # the spans under it read nothing and are left out, whichever cells
+    # they list; the counters' and the clock's are there
+    assert set(line["metrics"]) >= {"data_wait_ms.train", "dispatch_ms.train",
                                     "step_mfu_pct.train"}
+    source = {m["name"]: m["source"] for m in BENCH["per_layer"]}
+    assert not [name for name in line["metrics"]
+                if source[name] in ("device_trace", "program_span")]
     assert out["info"]["trace_steps"] > 0
 
 

@@ -1,7 +1,8 @@
 """The cell `nemotron3_nano_train_b4_s4096` and what it brought under
 `benchmark/`: the configuration and its cut, the reference's layer list, the
-readers of the pending per-layer metrics and the roofline arithmetic. CPU
-only; nothing here loads the TPU library."""
+readers of the cell's own per-layer metrics with their `BENCHMARK.json`
+entries, and the roofline arithmetic. CPU only; nothing here loads the TPU
+library."""
 
 from __future__ import annotations
 
@@ -28,8 +29,10 @@ CONFIG = json.load(open(os.path.join(
     ROOT, "benchmark", "configs", "nemotron3_nano_30b_a3b.json")))
 TRAFFIC = json.load(open(os.path.join(
     ROOT, "benchmark", "traffic", "train_fit_seq4096_b4.json")))
-PENDING = json.load(open(os.path.join(
-    ROOT, "benchmark", "pending_per_layer.json")))["per_layer"]
+# the cell's own per-layer metrics (readers since PR 28, entries since PR 34)
+OWN = ["ssm_ms.train", "experts_ms.train", "attention_ms.train",
+       "head_loss_ms.train", "ssm_scan_roofline_pct.train",
+       "experts_roofline_pct.train", "expert_load_max_over_mean.train"]
 FIXTURE = os.path.join(ROOT, "benchmark", "fixtures",
                        "nemotron_h_scoped_trace.json")
 READINGS = json.load(open(os.path.join(
@@ -51,17 +54,18 @@ def _harness():
 
 # -- the configuration and its cut ---------------------------------------------------
 
-def test_the_cell_keeps_to_the_contract_by_files_alone():
-    loaded = _harness().check_cell(ROOT, CELL)
+def check_the_cell_by_files_alone(root):
+    h = _harness()
+    loaded = h.check_cell(root, CELL)
     assert loaded["cell"]["chips"] == 1
     assert loaded["traffic"]["rows_block"] == 1
-    assert {m["name"] for m in loaded["end_to_end"]} == {
-        "train_examples_per_s_per_chip", "setup_s"}
-    # the six per-layer metrics that list no cells are read here too
-    assert {m["name"] for m in loaded["per_layer"]} == {
-        "data_wait_ms.train", "dispatch_ms.train", "step_mfu_pct.train",
-        "device_step_ms.train", "device_idle_pct.train",
-        "peak_hbm_gib.train"}
+    # the six per-layer metrics that list no cells are read here too, and
+    # beside the cell's own whatever else lists it
+    h.check_cell_metrics(root, CELL, own=OWN)
+
+
+def test_the_cell_keeps_to_the_contract_by_files_alone():
+    check_the_cell_by_files_alone(ROOT)
 
 
 def test_no_width_is_cut_and_the_cut_is_stated():
@@ -251,12 +255,11 @@ TINY = {
 def test_a_tiny_cell_runs_and_is_correct(tmp_path):
     """fit() on int32 ids through the harness's own `run_cell`, the first
     three steps against the reference in blocks of two rows, the books read
-    after the window, the pending readers silent without a trace."""
+    after the window; an untraced run reads no per-layer metric."""
     h = _harness()
     traffic = dict(h.TINY_TRAFFIC, batch_per_chip=4, seq_len=32,
                    rows_block=2)
     loaded = h._loaded(TINY, traffic=traffic)
-    loaded["per_layer"] = loaded["per_layer"] + PENDING
     import time
 
     out = bench_run.run_cell(
@@ -290,29 +293,31 @@ def test_a_planted_fault_fails_the_tiny_cell():
     assert same["loss_gap"] < 1e-6 and same["grad_median_gap"] < 1e-5
 
 
-# -- the pending readers ---------------------------------------------------------------
+# -- the cell's own readers and their entries -----------------------------------------
 
-def test_every_pending_entry_has_a_reader_and_keeps_to_the_contract():
+def check_the_cells_entries(bench):
+    """The seven entries follow PR 25's eight, in the order their readers
+    came in (PR 28), each listing this cell first."""
     h = _harness()
-    names = [m["name"] for m in PENDING]
-    assert names == ["ssm_ms.train", "experts_ms.train",
-                     "attention_ms.train", "head_loss_ms.train",
-                     "ssm_scan_roofline_pct.train",
-                     "experts_roofline_pct.train",
-                     "expert_load_max_over_mean.train"]
-    listed = {m["name"] for m in h.BENCH["per_layer"]}
-    for m in PENDING:
-        assert m["name"] not in listed
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names[14:21] == OWN
+    for m in bench["per_layer"][14:21]:
         assert h.NAME.match(m["name"]) and h.UNIT.match(m["unit"])
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-        assert m["workloads"] == [CELL]
+        assert m["workloads"][:1] == [CELL]
         assert m["moves"] == "train_examples_per_s_per_chip"
         assert callable(bench_run.load_reader(m["name"]))
+        # a share of a roofline is named for it and is a percentage
+        assert ("roofline" in m["name"]) == (m["unit"] == "%")
 
 
-@pytest.mark.parametrize("name", [m["name"] for m in PENDING])
-def test_a_pending_reader_without_a_trace_reads_nothing(name):
+def test_every_entry_of_the_cell_has_a_reader_and_keeps_to_the_contract():
+    check_the_cells_entries(_harness().BENCH)
+
+
+@pytest.mark.parametrize("name", OWN)
+def test_a_scoped_reader_without_a_trace_reads_nothing(name):
     facts = {"registry_after": {}, "trace_dir": None,
              "peak_flops_per_s": 197e12}
     assert bench_run.load_reader(name)(facts, None) is None
@@ -367,13 +372,13 @@ def test_parts_of_the_step_on_made_up_rows():
                     reason="the recorded step is written by the chip run")
 def test_the_readers_on_the_recorded_chip_step():
     """Three whole steps of the cell on the v5e (my chip run, PR 28): every
-    pending trace reader finds its layers and scopes, the parts do not pass
-    the whole, and both roofline shares lie under 100%."""
+    trace reader of the cell finds its layers and scopes, the parts do not
+    pass the whole, and both roofline shares lie under 100%."""
     from benchmark import trace_reduce
-    from benchmark.tools import pending_metrics
+    from benchmark.tools import span_dump
 
     doc = json.load(open(FIXTURE))
-    rows = pending_metrics.expand(doc)
+    rows = span_dump.expand(doc)
     whole = trace_reduce.reduce_rows([r[:5] for r in rows])
     assert whole["main_module"] == "jit_step"
     assert whole["main_module_runs"] == 3

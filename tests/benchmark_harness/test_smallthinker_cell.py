@@ -1,8 +1,8 @@
 """The cell `smallthinker_21b_train_b2_s8192` and what it brought under
 `benchmark/`: the configuration and its cut, the reference's layer list with
 the band's FLOP entry, the three new readers, the roofline arithmetic of
-`rooflines_decoder.py` and the second pending file with its tool. CPU only;
-nothing here loads the TPU library."""
+`rooflines_decoder.py` and the cell's per-layer entries in `BENCHMARK.json`.
+CPU only; nothing here loads the TPU library."""
 
 from __future__ import annotations
 
@@ -23,7 +23,6 @@ if ROOT not in sys.path:
 from benchmark import flops, rooflines_decoder, run as bench_run  # noqa: E402
 from benchmark import scope_reduce  # noqa: E402
 from benchmark.reference import smallthinker as ref  # noqa: E402
-from benchmark.tools import pending_metrics_all  # noqa: E402
 from benchmark.traffic import fit_loop  # noqa: E402
 
 CELL = "smallthinker_21b_train_b2_s8192"
@@ -31,12 +30,11 @@ CONFIG = json.load(open(os.path.join(
     ROOT, "benchmark", "configs", "smallthinker_21b_a3b.json")))
 TRAFFIC = json.load(open(os.path.join(
     ROOT, "benchmark", "traffic", "train_fit_seq8192_b2.json")))
-PENDING = json.load(open(os.path.join(
-    ROOT, "benchmark", "pending_per_layer.smallthinker.json")))["per_layer"]
 READINGS = json.load(open(os.path.join(
     ROOT, "benchmark", "fixtures", "smallthinker_control_readings.json")))
 NEW = ["window_attention_ms.train", "window_attention_roofline_pct.train",
        "gated_experts_roofline_pct.train"]
+# readers that came with the Nemotron cell and are generic over layer kinds
 REUSED = ["attention_ms.train", "experts_ms.train", "head_loss_ms.train",
           "expert_load_max_over_mean.train"]
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
@@ -68,28 +66,36 @@ def _harness():
 
 # -- the configuration and its cut ---------------------------------------------------
 
-def test_the_cell_keeps_to_the_contract_by_files_alone():
-    loaded = _harness().check_cell(ROOT, CELL)
+def check_the_cell_by_files_alone(root):
+    h = _harness()
+    loaded = h.check_cell(root, CELL)
     assert loaded["cell"]["chips"] == 1
     assert loaded["traffic"]["rows_block"] == 1
-    assert {m["name"] for m in loaded["end_to_end"]} == {
-        "train_examples_per_s_per_chip", "setup_s"}
-    assert {m["name"] for m in loaded["per_layer"]} == {
-        "data_wait_ms.train", "dispatch_ms.train", "step_mfu_pct.train",
-        "device_step_ms.train", "device_idle_pct.train",
-        "peak_hbm_gib.train"}
+    # the six per-layer metrics that list no cells, the cell's seven, and
+    # whatever else lists it
+    h.check_cell_metrics(root, CELL, own=NEW + REUSED)
+
+
+def test_the_cell_keeps_to_the_contract_by_files_alone():
+    check_the_cell_by_files_alone(ROOT)
+
+
+def check_the_first_three_configurations_and_cells(bench):
+    """The three configurations and cells that stood after PR 32, where
+    they stood; a later PR's lie behind them."""
+    assert [c["name"] for c in bench["configs"][:3]] == [
+        "vgg16", "nemotron3_nano_30b_a3b", "smallthinker_21b_a3b"]
+    assert [w["name"] for w in bench["workloads"][:3]] == [
+        "vgg16_train_b128", "nemotron3_nano_train_b4_s4096", CELL]
+    cell = {w["name"]: w for w in bench["workloads"]}[CELL]
+    assert cell == dict(cell, config="smallthinker_21b_a3b",
+                        traffic="train_fit_seq8192_b2", chips=1)
+    assert bench["run_seconds"] == 10
 
 
 def test_benchmark_json_gained_one_configuration_and_one_cell():
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    assert [c["name"] for c in bench["configs"]] == [
-        "vgg16", "nemotron3_nano_30b_a3b", "smallthinker_21b_a3b"]
-    assert [w["name"] for w in bench["workloads"]] == [
-        "vgg16_train_b128", "nemotron3_nano_train_b4_s4096", CELL]
-    assert bench["workloads"][-1] == dict(
-        bench["workloads"][-1], config="smallthinker_21b_a3b",
-        traffic="train_fit_seq8192_b2", chips=1)
-    assert len(bench["per_layer"]) == 14 and bench["run_seconds"] == 10
+    check_the_first_three_configurations_and_cells(
+        json.load(open(os.path.join(ROOT, "BENCHMARK.json"))))
 
 
 def test_no_width_is_cut_and_the_cut_is_stated():
@@ -304,14 +310,12 @@ TINY = {
 
 def test_a_tiny_cell_runs_and_is_correct(tmp_path):
     """fit() on int32 ids through the harness's own `run_cell`, the first
-    three steps against the reference in blocks of two rows, the pending
-    readers of both files silent without a trace."""
+    three steps against the reference in blocks of two rows; an untraced
+    run reads no per-layer metric."""
     h = _harness()
     traffic = dict(h.TINY_TRAFFIC, batch_per_chip=4, seq_len=32,
                    rows_block=2)
     loaded = h._loaded(TINY, traffic=traffic)
-    loaded["per_layer"] = loaded["per_layer"] \
-        + pending_metrics_all.pending_for(CELL)
     out = bench_run.run_cell(
         loaded, seed=2 ** 31 + 5, seconds=0.4, trace=False, device=h.V5E,
         peaks=bench_run.load_peaks(), root=str(tmp_path),
@@ -345,52 +349,40 @@ def test_a_planted_fault_and_a_lower_precision_fail_the_tiny_cell():
     assert rounded["grad_median_gap"] > TINY["limits"]["grad_median_gap"]
 
 
-# -- the pending entries, their readers and the tool -----------------------------------
+# -- the cell's entries and their readers -----------------------------------------------
 
-def test_the_second_pending_file_keeps_to_the_contract():
+def check_the_cells_entries(bench):
+    """The three entries that came with this cell follow the Nemotron
+    cell's seven, and the four generic ones of those seven list this cell
+    too."""
     h = _harness()
-    assert [m["name"] for m in PENDING] == NEW + REUSED
-    listed = {m["name"] for m in h.BENCH["per_layer"]}
-    first = {m["name"]: m for m in json.load(open(os.path.join(
-        ROOT, "benchmark", "pending_per_layer.json")))["per_layer"]}
-    for m in PENDING:
-        assert m["name"] not in listed
+    entries = bench["per_layer"]
+    assert [m["name"] for m in entries[21:24]] == NEW
+    first = {m["name"]: m for m in entries[14:21]}
+    assert set(REUSED) <= set(first)
+    for m in entries[21:24] + [first[name] for name in REUSED]:
         assert h.NAME.match(m["name"]) and h.UNIT.match(m["unit"])
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-        assert m["workloads"] == [CELL]
+        assert CELL in m["workloads"]
         assert m["moves"] == "train_examples_per_s_per_chip"
         assert callable(bench_run.load_reader(m["name"]))
-        if m["name"] in REUSED:     # the same entry but for its cells
-            assert dict(first[m["name"]], workloads=None) \
-                == dict(m, workloads=None)
-        else:
-            assert m["name"] not in first
-    assert {m["name"] for m in PENDING if m["name"].endswith(
+    for m in entries[21:24]:
+        assert m["workloads"][:1] == [CELL]
+        # a layer the Nemotron cell's entries already name, letter for letter
+        assert m["layer"] in {e["layer"] for e in first.values()}
+    assert {m["name"] for m in entries[21:24] if m["name"].endswith(
         "_roofline_pct.train")} == {NEW[1], NEW[2]}
-    assert all(m["unit"] == "%" for m in PENDING
+    assert all(m["unit"] == "%" for m in entries[21:24]
                if "roofline" in m["name"])
-    layers = {m["layer"] for m in PENDING}
-    assert layers <= {m["layer"] for m in first.values()}
 
 
-def test_the_tool_reads_every_pending_file_and_each_metric_once():
-    mine = [m["name"] for m in pending_metrics_all.pending_for(CELL)]
-    assert mine == NEW + REUSED
-    theirs = [m["name"] for m in pending_metrics_all.pending_for(
-        "nemotron3_nano_train_b4_s4096")]
-    from benchmark.tools import pending_metrics
-
-    assert theirs == [m["name"] for m in pending_metrics.pending_for(
-        "nemotron3_nano_train_b4_s4096")]
-    assert pending_metrics_all.pending_for("vgg16_train_b128") == []
-    # the tool leaves `pending_metrics` as it found it
-    assert pending_metrics.pending_for.__module__ \
-        == "benchmark.tools.pending_metrics"
+def test_the_cells_entries_keep_to_the_contract():
+    check_the_cells_entries(_harness().BENCH)
 
 
 @pytest.mark.parametrize("name", NEW + REUSED)
-def test_a_pending_reader_without_a_trace_reads_nothing(name):
+def test_a_scoped_reader_without_a_trace_reads_nothing(name):
     facts = {"registry_after": {}, "trace_dir": None,
              "peak_flops_per_s": 197e12}
     assert bench_run.load_reader(name)(facts, None) is None
