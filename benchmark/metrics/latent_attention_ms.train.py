@@ -1,0 +1,12 @@
+"""Latent-attention layers: device time a step in the layers of kind
+`latentattention` (projections, the latent's norm, the partial rotation, the
+inner part and the out-projection), forward plus backward with what the
+backward pass recomputes, from the scoped trace. Nothing to read where no
+event carries such a layer's scope."""
+
+from benchmark import scope_reduce
+
+
+def read(facts, trace):
+    return scope_reduce.ms_per_step(
+        facts, trace, scope_reduce.of_layer_kinds("latentattention"))

@@ -20,10 +20,12 @@ from deeplearning4j_tpu.nn.conf.layers import (
     EmbeddingLayer,
     EmbeddingSequenceLayer,
     ExpertRouterLayer,
+    GatedMLPLayer,
     GlobalPoolingLayer,
     GravesBidirectionalLSTM,
     GravesLSTM,
     GroupedQueryAttentionLayer,
+    LatentAttentionLayer,
     LocalResponseNormalization,
     LossLayer,
     LSTM,

@@ -557,6 +557,46 @@ class GroupedQueryAttentionLayer(BaseRecurrentLayerConf):
     rope_theta: Optional[float] = None
 
 
+@register_config("layer.latent_attention")
+@dataclasses.dataclass(kw_only=True)
+class LatentAttentionLayer(BaseRecurrentLayerConf):
+    """Causal multi-head attention whose keys and values come from one
+    low-rank latent (the DeepSeek family's latent attention, no query
+    bottleneck); no bias. `[b, t, n_in] -> [b, t, n_out]`:
+
+        q  = u Wq                        n_heads x (qk_nope + qk_rope)
+        c  = u Wkv_a                     [latent kv_lora_rank | k_rope]
+        kv = RMSNorm(latent; kv_norm, eps) Wkv_b
+                                         n_heads x (qk_nope k | v_head_dim v)
+        k  = [k_nope | k_rope, the one rotary key, for every head]
+        out = softmax_causal(q k^T (qk_nope + qk_rope)^-0.5) v Wo
+
+    Queries and keys are `qk_nope_head_dim + qk_rope_head_dim` wide, values
+    `v_head_dim`. `rope_theta` (None: no positional term) rotates the last
+    `qk_rope_head_dim` dimensions of every query head and the shared
+    `k_rope` by their position `0 .. t - 1` at frequencies `rope_theta **
+    (-2 j / qk_rope_head_dim)`, over the adjacent pairs `(2 j, 2 j + 1)`
+    (the family's `rope_interleave`)."""
+
+    n_heads: int = 4
+    qk_nope_head_dim: int = 32
+    qk_rope_head_dim: int = 16
+    v_head_dim: int = 32
+    kv_lora_rank: int = 64
+    rope_theta: Optional[float] = 1e4
+    eps: float = 1e-6
+
+
+@register_config("layer.gated_mlp")
+@dataclasses.dataclass(kw_only=True)
+class GatedMLPLayer(BaseRecurrentLayerConf):
+    """The gated feed-forward `W_down(act(W_gate u) * (W_up u))` at every
+    position, `n_in -> width -> n_out`, no bias: a decoder's dense MLP, or
+    its shared experts as one MLP of their summed width."""
+
+    width: int = 0
+
+
 @register_config("layer.mamba2")
 @dataclasses.dataclass(kw_only=True)
 class Mamba2Layer(BaseRecurrentLayerConf):
@@ -626,6 +666,7 @@ class SparseExpertsLayer(BaseRecurrentLayerConf):
     gated: bool = False
     score: str = "sigmoid"
     router_input: bool = False
+    select_bias: bool = False
 
     def held(self) -> List[int]:
         return list(range(self.router_width)) if self.experts_held is None \

@@ -1,5 +1,5 @@
 """Attention layer impls (configs: SelfAttentionLayer,
-GroupedQueryAttentionLayer).
+GroupedQueryAttentionLayer, LatentAttentionLayer).
 
 SelfAttentionLayer: single-device forward uses parallel/sequence.
 full_attention; the SAME math runs sequence-parallel over a mesh via
@@ -13,15 +13,21 @@ positions, no time mask): projections and `rope` are XLA's; the inner part
 (scores, band mask, softmax, mix: `grouped_query_attention`) is one
 algorithm with two lowerings, chosen by what the trace can observe. The
 fused kernel (ops/pallas_attention.py, helper slot "gqa_attention") serves
-a one-device program on a TPU with bf16 operands, a causal layer,
-`head_dim` a multiple of 128 and a sequence that is a multiple of 128 (up
-to 16,384 positions at heads of 128: the backward keeps a key-value head's
-`dk` and `dv` in VMEM); no `[queries, keys]` array reaches HBM there. Everything else — the CPU,
+a one-device program on a TPU with bf16 operands, a causal layer, value
+heads of whole lanes (128), query/key heads of whole half-lanes and a
+sequence that is a multiple of 128 (up to 16,384 positions at heads of 128:
+the backward keeps a key-value head's `dk` and `dv` in VMEM); no `[queries,
+keys]` array reaches HBM there. Everything else — the CPU,
 float32 operands, other head sizes or lengths, a program partitioned over
 a mesh — takes the built-in blocked XLA lowering below (`QUERY_BLOCK`
 queries at a time under `jax.checkpoint`, the blocks past the window one
 scanned body). `helper_hit_total` / `helper_fallback_total{op=
 "gqa_attention"}` say which ran, once a layer a trace.
+
+LatentAttentionLayer (the DeepSeek family's low-rank key-value attention):
+its own projections, latent norm and partial rotation around the same
+`grouped_query_attention`, with a group of one and queries and keys wider
+(`qk_nope + qk_rope`) than values.
 """
 
 from __future__ import annotations
@@ -32,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf import layers as L
+from deeplearning4j_tpu.nn.layers.norm import rms_normalize
 from deeplearning4j_tpu.nn.layers.registry import LayerContext, register_layer
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.activations import apply_activation
@@ -193,37 +200,39 @@ def key_block_pairs(t: int, window, block: int = None, causal: bool = True):
     return multiplied, skipped
 
 
-def _count_lowering(conf, t: int) -> None:
+def _count_lowering(t: int, *, window, positions: str,
+                    causal: bool = True) -> None:
     """Trace-time, like conv._count_pool_lowering: the layer once a trace,
     and the key blocks its product multiplies or leaves out."""
     reg = _metrics.get_registry()
     reg.counter(
         "attention_lowering_total",
-        "grouped-query attention layers traced, by what a query sees "
-        "(window or full) and the positional term (rope or none)",
+        "grouped-query and latent attention layers traced, by what a query "
+        "sees (window or full) and the positional term (rope over the whole "
+        "head, rope_partial over a slice of it, or none)",
         ("kind", "positions")).labels(
-            "full" if conf.window is None else "window",
-            "none" if conf.rope_theta is None else "rope").inc()
+            "full" if window is None else "window", positions).inc()
     pairs = reg.counter(
         "attention_key_blocks_total",
         "(query block, key block) pairs of the traced attention layers' "
         "blocked products: multiplied, or under the diagonal and skipped "
         "because they lie wholly before the window", ("state",))
-    multiplied, skipped = key_block_pairs(t, conf.window,
-                                          causal=conf.causal)
+    multiplied, skipped = key_block_pairs(t, window, causal=causal)
     pairs.labels("multiplied").inc(multiplied)
     pairs.labels("skipped").inc(skipped)
 
 
 def grouped_query_attention(q, k, v, *, causal: bool, window=None):
-    """q: [b, t, H, D], k/v: [b, t, KV, D] -> [b, t, H, D] float32; query
-    head `h` reads key-value head `h // (H // KV)`. The fused kernel where
-    its probe takes the shapes (ops/pallas_attention.py), else the blocked
-    XLA lowering."""
+    """q: [b, t, H, D], k: [b, t, KV, D], v: [b, t, KV, Dv] -> [b, t, H,
+    Dv] float32; query head `h` reads key-value head `h // (H // KV)`, the
+    scores are scaled by `D ** -0.5`. The fused kernel where its probe
+    takes the shapes (ops/pallas_attention.py), else the blocked XLA
+    lowering."""
     if window is not None and not causal:
         raise ValueError("a window is a causal layer's")
     helper = get_helper("gqa_attention", q_shape=tuple(q.shape),
-                        dtype=q.dtype, causal=causal, window=window)
+                        dtype=q.dtype, causal=causal, window=window,
+                        v_head_dim=int(v.shape[-1]))
     if helper is not None:
         try:
             return helper(q, k, v, causal=causal, window=window)
@@ -267,7 +276,7 @@ def _blocked_attention(q, k, v, *, causal: bool, window=None):
         outs.append(block(q[:, start:end], k[:, first:seen],
                           v[:, first:seen]))
     o = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    return o.reshape(b, t, H, D)
+    return o.reshape(b, t, H, v.shape[-1])
 
 
 def _steady_blocks(q, k, v, steady, back: int, window: int):
@@ -293,14 +302,24 @@ def _steady_blocks(q, k, v, steady, back: int, window: int):
         1, 0)
     starts = jnp.arange(n, dtype=jnp.int32) * QUERY_BLOCK + (lo - back)
     out = jax.lax.map(body, (q_blocks, starts))
-    return jnp.moveaxis(out, 0, 1).reshape(b, n * QUERY_BLOCK, KV, G, D)
+    return jnp.moveaxis(out, 0, 1).reshape(b, n * QUERY_BLOCK, KV, G,
+                                           v.shape[-1])
 
 
-def rope(x, theta: float):
-    """Rotary positions over the whole head: x [b, t, heads, D] at
-    positions `0 .. t - 1`, dimension `i` paired with `i + D / 2`
-    (`rotate_half`), angle `p * theta ** (-2 i / D)`. Computed in float32,
-    returned in x's dtype."""
+def rope(x, theta: float, *, start: int = 0, interleaved: bool = False):
+    """Rotary positions: x [b, t, heads, D] at positions `0 .. t - 1`. Over
+    the whole head, dimension `i` paired with `i + D / 2` (`rotate_half`),
+    angle `p * theta ** (-2 i / D)`. With `start` only the dimensions from
+    `start` on are rotated (`D` is then their number) and the others pass
+    through; with `interleaved` the pairs are the adjacent dimensions `(2 j,
+    2 j + 1)` and come back apart, the rotated `2 j` in the first half and
+    the rotated `2 j + 1` in the second: a permutation of the head's
+    dimensions that queries and keys share, so their products do not see
+    it. Computed in float32, returned in x's dtype."""
+    if start:
+        return jnp.concatenate(
+            [x[..., :start],
+             rope(x[..., start:], theta, interleaved=interleaved)], axis=-1)
     t, D = x.shape[1], x.shape[-1]
     half = D // 2
     inv_freq = 1.0 / (float(theta) ** (
@@ -308,7 +327,8 @@ def rope(x, theta: float):
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
+    x1, x2 = (xf[..., 0::2], xf[..., 1::2]) if interleaved \
+        else (xf[..., :half], xf[..., half:])
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
 
@@ -337,7 +357,8 @@ def gqa_forward(conf: L.GroupedQueryAttentionLayer, params, x,
                          else "window_attention"):
         o = grouped_query_attention(q, k, v, causal=conf.causal,
                                     window=conf.window)
-    _count_lowering(conf, T)
+    _count_lowering(T, window=conf.window, causal=conf.causal,
+                    positions="none" if conf.rope_theta is None else "rope")
     y = jnp.matmul(o.astype(cd).reshape(B, T, H * D), params["Wo"].astype(cd),
                    preferred_element_type=jnp.float32)
     return y.astype(x.dtype), None
@@ -345,3 +366,69 @@ def gqa_forward(conf: L.GroupedQueryAttentionLayer, params, x,
 
 register_layer(L.GroupedQueryAttentionLayer, gqa_init, gqa_forward,
                order_fn=lambda conf: ("Wq", "Wk", "Wv", "Wo"))
+
+
+# -- latent (low-rank key-value) causal attention -------------------------------
+
+def latent_init(key, conf: L.LatentAttentionLayer, dtype):
+    n_in, n_out, H = int(conf.n_in), int(conf.n_out), int(conf.n_heads)
+    nope, rot = int(conf.qk_nope_head_dim), int(conf.qk_rope_head_dim)
+    dv, rank = int(conf.v_head_dim), int(conf.kv_lora_rank)
+    if rot % 2:
+        raise ValueError(f"qk_rope_head_dim {rot} is not made of pairs")
+    ks = jax.random.split(key, 4)
+    mk = lambda k, i, o: init_weights(k, (i, o), i, o, conf.weight_init,
+                                      conf.dist, dtype)
+    return {"Wq": mk(ks[0], n_in, H * (nope + rot)),
+            "Wkv_a": mk(ks[1], n_in, rank + rot),
+            "kv_norm": jnp.ones((rank,), dtype),
+            "Wkv_b": mk(ks[2], rank, H * (nope + dv)),
+            "Wo": mk(ks[3], H * dv, n_out)}
+
+
+def latent_forward(conf: L.LatentAttentionLayer, params, x,
+                   ctx: LayerContext):
+    """x: [b, t, n_in] -> [b, t, n_out] in x's dtype; the products run in
+    the net's compute dtype with float32 accumulation, the latent's norm and
+    the rotation in float32. The inner part is `grouped_query_attention`
+    with a group of one: queries and keys of `qk_nope + qk_rope`, values of
+    `v_head_dim`."""
+    if ctx.mask is not None:
+        raise NotImplementedError(
+            "LatentAttentionLayer takes no time mask (packed or padded "
+            "sequences): SelfAttentionLayer masks keys")
+    B, T, _ = x.shape
+    H, nope, rot = int(conf.n_heads), int(conf.qk_nope_head_dim), \
+        int(conf.qk_rope_head_dim)
+    dv, rank = int(conf.v_head_dim), int(conf.kv_lora_rank)
+    cd = ctx.compute_dtype or x.dtype
+    mm = lambda a, name: jnp.matmul(a, params[name].astype(cd),
+                                    preferred_element_type=jnp.float32)
+    u = x.astype(cd)
+    q = mm(u, "Wq").astype(cd).reshape(B, T, H, nope + rot)
+    with jax.named_scope("latent_kv"):
+        c = mm(u, "Wkv_a")                           # float32 [b, t, rank + rot]
+        latent = rms_normalize(c[..., :rank], params["kv_norm"], conf.eps)
+        kv = mm(latent.astype(cd), "Wkv_b").astype(cd).reshape(
+            B, T, H, nope + dv)
+        k_rope = c[..., rank:].astype(cd).reshape(B, T, 1, rot)
+    if conf.rope_theta is not None:
+        with jax.named_scope("rope"):
+            q = rope(q, conf.rope_theta, start=nope, interleaved=True)
+            k_rope = rope(k_rope, conf.rope_theta, interleaved=True)
+    with jax.named_scope("latent_kv"):
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, (B, T, H, rot))],
+            axis=-1)
+        v = kv[..., nope:]
+    with jax.named_scope("latent_attention"):
+        o = grouped_query_attention(q, k, v, causal=True)
+    _count_lowering(T, window=None, positions="none"
+                    if conf.rope_theta is None else "rope_partial")
+    y = mm(o.astype(cd).reshape(B, T, H * dv), "Wo")
+    return y.astype(x.dtype), None
+
+
+register_layer(L.LatentAttentionLayer, latent_init, latent_forward,
+               order_fn=lambda conf: ("Wq", "Wkv_a", "kv_norm", "Wkv_b",
+                                      "Wo"))

@@ -1,5 +1,5 @@
 """Core feed-forward layers: dense, output heads, embedding, activation,
-dropout, autoencoder.
+dropout, the gated dense MLP, autoencoder.
 
 Reference impls: nn/layers/feedforward/dense/DenseLayer.java (preOutput =
 input·W + b then activation, BaseLayer.java), BaseOutputLayer.java,
@@ -184,6 +184,34 @@ def embedding_sequence_forward(conf, params, x, ctx: LayerContext):
 
 register_layer(L.EmbeddingSequenceLayer, embedding_sequence_init,
                embedding_sequence_forward, order_fn=lambda conf: ("W",))
+
+
+# -- gated dense MLP -----------------------------------------------------------
+
+def gated_mlp_init(key, conf: L.GatedMLPLayer, dtype):
+    n_in, n_out, width = int(conf.n_in), int(conf.n_out), int(conf.width)
+    ks = jax.random.split(key, 3)
+    mk = lambda k, i, o: init_weights(k, (i, o), i, o, conf.weight_init,
+                                      conf.dist, dtype)
+    return {"W_gate": mk(ks[0], n_in, width), "W_up": mk(ks[1], n_in, width),
+            "W_down": mk(ks[2], width, n_out)}
+
+
+def gated_mlp_forward(conf: L.GatedMLPLayer, params, x, ctx: LayerContext):
+    """x: [..., n_in] -> [..., n_out] in x's dtype: `W_down(act(W_gate u) *
+    (W_up u))`, the three products in the net's compute dtype with float32
+    accumulation, the activation and the gate's product in float32."""
+    cd = ctx.compute_dtype or x.dtype
+    mm = lambda a, name: jnp.matmul(a, params[name].astype(cd),
+                                    preferred_element_type=jnp.float32)
+    u = x.astype(cd)
+    hidden = apply_activation(conf.activation, mm(u, "W_gate")) \
+        * mm(u, "W_up")
+    return mm(hidden.astype(cd), "W_down").astype(x.dtype), None
+
+
+register_layer(L.GatedMLPLayer, gated_mlp_init, gated_mlp_forward,
+               order_fn=lambda conf: ("W_gate", "W_up", "W_down"))
 
 
 # -- autoencoder (supervised path) ------------------------------------------

@@ -4,7 +4,8 @@
                                          (or the vertex's second input:
                                          `router_input`, an ExpertRouterLayer)
     s = sigmoid(l) | softmax(l)          `score`
-    chosen = the `experts_per_token` largest s;  w_i = scaling s_i / sum s
+    chosen = the `experts_per_token` largest s (`select_bias`: largest
+             s + b_select);  w_i = scaling s_i / sum of the chosen s
     out = sum over chosen i held here of w_i W2_i act(W1_i u)
           (`gated`: w_i W2_i (act(W1_i u) * (W3_i u)))
           + Ws2 act(Ws1 u)               the shared expert, every token
@@ -86,6 +87,12 @@ def experts_init(key, conf: L.SparseExpertsLayer, dtype):
                            int(conf.router_width))
     if conf.gated:
         p["W3"] = mk(ks[5], (len(held), n_in, width), n_in, width)
+    if conf.select_bias:
+        # a weight of the checkpoint like any other, from the seed; ks[0]
+        # is the router's, which such a layer may not hold
+        p["b_select"] = init_weights(
+            jax.random.fold_in(ks[0], 1), (int(conf.router_width),), n_in,
+            int(conf.router_width), conf.weight_init, conf.dist, jnp.float32)
     if conf.shared_width:
         sw = int(conf.shared_width)
         p["Ws1"] = mk(ks[3], (n_in, sw), n_in, sw)
@@ -96,6 +103,7 @@ def experts_init(key, conf: L.SparseExpertsLayer, dtype):
 def experts_order(conf):
     return (() if conf.router_input else ("W_router",)) + ("W1", "W2") + (
         ("W3",) if conf.gated else ()) + (
+        ("b_select",) if conf.select_bias else ()) + (
         ("Ws1", "Ws2") if conf.shared_width else ())
 
 
@@ -108,13 +116,23 @@ def experts_state(conf: L.SparseExpertsLayer, dtype):
             "rows": jnp.zeros((), jnp.int32)}
 
 
-def route(conf: L.SparseExpertsLayer, scores):
+def route(conf: L.SparseExpertsLayer, scores, b_select=None):
     """scores: [tokens, router_width] float32 -> (chosen experts [tokens, k]
     int32, their weights [tokens, k] float32): the k largest scores,
-    normalised over the k chosen (held here or not) and scaled."""
-    top, idx = jax.lax.top_k(scores, int(conf.experts_per_token))
-    return idx.astype(jnp.int32), \
-        float(conf.scaling) * top / jnp.sum(top, axis=-1, keepdims=True)
+    normalised over the k chosen (held here or not) and scaled. With
+    `b_select` [router_width] the k largest of `scores + b_select` are
+    chosen and the weights are the scores' own at those experts, over
+    their sum and the family's `1e-20`; nothing differentiable reads
+    `b_select`."""
+    if b_select is None:
+        top, idx = jax.lax.top_k(scores, int(conf.experts_per_token))
+        return idx.astype(jnp.int32), \
+            float(conf.scaling) * top / jnp.sum(top, axis=-1, keepdims=True)
+    _, idx = jax.lax.top_k(scores + b_select.astype(scores.dtype),
+                           int(conf.experts_per_token))
+    top = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx.astype(jnp.int32), float(conf.scaling) * top / (
+        jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
 
 
 def router_logits(x, w):
@@ -157,7 +175,7 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
                 jnp.max(logits, axis=-1, keepdims=True)))
         else:
             scores = jax.nn.sigmoid(logits)
-        idx, w = route(conf, scores)
+        idx, w = route(conf, scores, params.get("b_select"))
         # where each assignment goes: the slot of its expert here (none:
         # held elsewhere) and its rank among that expert's assignments.
         # By comparison and running sum, not by table look-up: a gather or

@@ -125,6 +125,7 @@ def rectifiedtanh(x):
     return jnp.maximum(0.0, jnp.tanh(x))
 
 
+@_simple("silu")   # the name the transformer families' configs give it
 @_simple("swish")
 def swish(x):
     return jax.nn.silu(x)

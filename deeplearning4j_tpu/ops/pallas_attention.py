@@ -211,65 +211,64 @@ def _params(n_axes: int):
         vmem_limit_bytes=VMEM_LIMIT)
 
 
-def _forward(q, k, v, window, block):
-    """q [b, H, t, D], k/v [b, KV, t, D] -> o [b, H, t, D] float32 and the
-    log-sum-exp of each query's scaled scores [b, H, 1, t]."""
+def _forward(q, k, v, window, block, scale):
+    """q [b, H, t, D], k [b, KV, t, D], v [b, KV, t, Dv] -> o [b, H, t, Dv]
+    float32 and the log-sum-exp of each query's scaled scores [b, H, 1,
+    t]."""
     b, H, t, D = q.shape
+    Dv = v.shape[-1]
     G = H // k.shape[1]
     qi, kj, flags = band_pairs(t, window, block)
-    q_spec = pl.BlockSpec((None, None, block, D),
-                          lambda b_, h, p, qi, kj, fl: (b_, h, qi[p], 0))
-    kv_spec = pl.BlockSpec((None, None, block, D),
-                           lambda b_, h, p, qi, kj, fl: (b_, h // G, kj[p], 0))
+    rows = lambda width, index: pl.BlockSpec((None, None, block, width), index)
+    of_query = lambda b_, h, p, qi, kj, fl: (b_, h, qi[p], 0)
+    of_key = lambda b_, h, p, qi, kj, fl: (b_, h // G, kj[p], 0)
     row_spec = pl.BlockSpec((None, None, 1, block),
                             lambda b_, h, p, qi, kj, fl: (b_, h, 0, qi[p]))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=D ** -0.5, window=window),
+        functools.partial(_fwd_kernel, scale=scale, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(b, H, len(qi)),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=[q_spec, row_spec],
+            in_specs=[rows(D, of_query), rows(D, of_key), rows(Dv, of_key)],
+            out_specs=[rows(Dv, of_query), row_spec],
             scratch_shapes=[pltpu.VMEM((block, LANES), jnp.float32),
                             pltpu.VMEM((block, LANES), jnp.float32),
-                            pltpu.VMEM((block, D), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((b, H, t, D), jnp.float32),
+                            pltpu.VMEM((block, Dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, H, t, Dv), jnp.float32),
                    jax.ShapeDtypeStruct((b, H, 1, t), jnp.float32)],
         compiler_params=_params(3), name="gqa_fwd", interpret=_interpret(),
     )(qi, kj, flags, q, k, v)
 
 
-def _backward(q, k, v, o, lse, do, window, block):
+def _backward(q, k, v, o, lse, do, window, block, scale):
     """One kernel for `dq`, `dk` and `dv`, query-major over the pairs of one
     query head after another: `dq` accumulates over a query block's keys in
     a `[block, D]` scratch, `dk` and `dv` over all the queries of the `G`
-    heads that share the key-value head in two `[t, D]` float32 scratches
-    that stay in VMEM (8 MB at 8,192 positions)."""
+    heads that share the key-value head in a `[t, D]` and a `[t, Dv]`
+    float32 scratch that stay in VMEM (8 MB at 8,192 positions of 128)."""
     b, H, t, D = q.shape
+    Dv = v.shape[-1]
     KV = k.shape[1]
     G = H // KV
     di = jnp.sum(o * do, axis=-1)                       # [b, H, t] float32
     qi, kj, flags = band_pairs(t, window, block)
-    q_spec = pl.BlockSpec(
-        (None, None, block, D),
-        lambda b_, h, g, p, qi, kj, fl: (b_, h * G + g, qi[p], 0))
-    kv_spec = pl.BlockSpec(
-        (None, None, block, D),
-        lambda b_, h, g, p, qi, kj, fl: (b_, h, kj[p], 0))
+    rows = lambda width, index: pl.BlockSpec((None, None, block, width), index)
+    of_query = lambda b_, h, g, p, qi, kj, fl: (b_, h * G + g, qi[p], 0)
+    of_key = lambda b_, h, g, p, qi, kj, fl: (b_, h, kj[p], 0)
     row_spec = pl.BlockSpec(
         (None, None, 1, block),
         lambda b_, h, g, p, qi, kj, fl: (b_, h * G + g, 0, qi[p]))
-    whole_kv = pl.BlockSpec((None, None, t, D),
-                            lambda b_, h, g, p, qi, kj, fl: (b_, h, 0, 0))
+    whole = lambda width: pl.BlockSpec(
+        (None, None, t, width), lambda b_, h, g, p, qi, kj, fl: (b_, h, 0, 0))
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=D ** -0.5, window=window,
-                          group=G),
+        functools.partial(_bwd_kernel, scale=scale, window=window, group=G),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(b, KV, G, len(qi)),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-            out_specs=[q_spec, whole_kv, whole_kv],
+            in_specs=[rows(D, of_query), rows(D, of_key), rows(Dv, of_key),
+                      rows(Dv, of_query), row_spec, row_spec],
+            out_specs=[rows(D, of_query), whole(D), whole(Dv)],
             scratch_shapes=[pltpu.VMEM((block, D), jnp.float32),
                             pltpu.VMEM((t, D), jnp.float32),
-                            pltpu.VMEM((t, D), jnp.float32)]),
+                            pltpu.VMEM((t, Dv), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
@@ -277,32 +276,34 @@ def _backward(q, k, v, o, lse, do, window, block):
     )(qi, kj, flags, q, k, v, do.astype(v.dtype), lse, di[:, :, None, :])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _attend(q, k, v, window, block):
-    return _forward(q, k, v, window, block)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _attend(q, k, v, window, block, scale):
+    return _forward(q, k, v, window, block, scale)[0]
 
 
-def _attend_fwd(q, k, v, window, block):
-    o, lse = _forward(q, k, v, window, block)
+def _attend_fwd(q, k, v, window, block, scale):
+    o, lse = _forward(q, k, v, window, block, scale)
     return o, (q, k, v, o, lse)
 
 
-def _attend_bwd(window, block, res, do):
-    return _backward(*res, do, window, block)
+def _attend_bwd(window, block, scale, res, do):
+    return _backward(*res, do, window, block, scale)
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
 def gqa_attention(q, k, v, *, causal: bool, window=None):
-    """`attention.grouped_query_attention`'s contract: q [b, t, H, D], k/v
-    [b, t, KV, D] -> [b, t, H, D] float32, query head `h` on key-value
-    head `h // (H // KV)`. The kernels take heads in front of positions;
-    the transposes are XLA's."""
+    """`attention.grouped_query_attention`'s contract: q [b, t, H, D], k
+    [b, t, KV, D], v [b, t, KV, Dv] -> [b, t, H, Dv] float32, query head
+    `h` on key-value head `h // (H // KV)`, the scores scaled by `D **
+    -0.5` of the width the layer handed over. The kernels take heads in
+    front of positions; the transposes are XLA's."""
     assert causal, "the probe declines a layer that is not causal"
     heads_first = lambda x: jnp.transpose(x, (0, 2, 1, 3))
     o = _attend(heads_first(q), heads_first(k), heads_first(v),
-                None if window is None else int(window), _block(q.shape[1]))
+                None if window is None else int(window), _block(q.shape[1]),
+                q.shape[-1] ** -0.5)
     return heads_first(o)
 
 
@@ -310,19 +311,24 @@ def _block(t: int) -> Optional[int]:
     return next((n for n in BLOCKS if t % n == 0), None)
 
 
-def supported(*, q_shape, dtype, causal, **_):
+def supported(*, q_shape, dtype, causal, v_head_dim=None, **_):
     """A pure function of backend, shapes and dtype: a TPU (or the
-    interpreter in a CPU test), a causal layer, bf16 operands, `head_dim`
-    whole lanes, a sequence the smallest block divides and whose `dk` and
-    `dv` the backward can keep in VMEM (16,384 positions at 128)."""
+    interpreter in a CPU test), a causal layer, bf16 operands, value heads
+    of whole lanes and query/key heads of whole half-lanes (a block's last
+    dimension is then the whole head: 192 beside 128 is the latent layer's),
+    a sequence the smallest block divides and whose `dk` and `dv` the
+    backward can keep in VMEM (16,384 positions at 128 + 128, 12,288 at
+    192 + 128)."""
     _, t, _, head_dim = q_shape
+    v_head_dim = head_dim if v_head_dim is None else v_head_dim
     if not (_interpret() or jax.default_backend() == "tpu"):
         return False
     if not causal or jnp.dtype(dtype) != jnp.bfloat16:
         return False
-    if head_dim % LANES or _block(t) is None:
+    if head_dim % (LANES // 2) or v_head_dim % LANES or _block(t) is None:
         return False
-    return t * head_dim * (2 * 4 + 4 * 2) <= RESIDENT_LIMIT
+    # each a float32 scratch and a double-buffered bf16 output block
+    return t * (head_dim + v_head_dim) * (4 + 2 * 2) <= RESIDENT_LIMIT
 
 
 def register():

@@ -255,8 +255,12 @@ def test_queue_full_rejection_and_predicted_late():
                 t.start()
                 threads.append(t)
                 # let the pipeline drain each submission as far as it
-                # can before the next (deterministic stage occupancy)
-                _wait_until(lambda: pi.metrics()["requests"] == i + 1)
+                # can before the next (deterministic stage occupancy): the
+                # first three leave the queue for the forward, the handoff
+                # and the collector's hand. Counting the request alone let
+                # a loaded box submit r5 while r3 still sat in the queue
+                _wait_until(lambda: pi.metrics()["requests"] == i + 1
+                            and pi._q.qsize() == max(0, i - 2))
             assert _wait_until(lambda: pi._q.qsize() >= 2), \
                 "pipeline never backed up"
             with pytest.raises(RequestRejected) as ei:

@@ -313,3 +313,148 @@ def test_one_trace_counts_one_full_and_three_window_hits(monkeypatch):
     assert fused['attention_lowering_total{kind="window",positions="rope"}'] \
         == 3
     assert fused['attention_key_blocks_total{state="multiplied"}'] == 4
+
+
+# -- two head sizes: queries and keys wider than values (latent attention) ----------
+
+TWO_SIZES = {"latent_192_128": (192, 128), "half_lane_64_128": (64, 128),
+             "lanes_256_128": (256, 128)}
+
+
+def _two_size_inputs(case: str):
+    """q, k, v and an output cotangent with queries and keys of `dqk` and
+    values of `dv`, four heads on two key-value heads."""
+    dqk, dv = TWO_SIZES[case]
+    ks = jax.random.split(jax.random.PRNGKey(dqk), 4)
+    return (jax.random.normal(ks[0], (1, 256, 4, dqk), BF16),
+            jax.random.normal(ks[1], (1, 256, 2, dqk), BF16),
+            jax.random.normal(ks[2], (1, 256, 2, dv), BF16),
+            jax.random.normal(ks[3], (1, 256, 4, dv), jnp.float32))
+
+
+@functools.lru_cache(maxsize=None)
+def _two_size_readings(case: str):
+    """(output, dq, dk, dv) of the fused kernel, the built-in lowering and
+    the dense float32 form on those inputs."""
+    q, k, v, w = _two_size_inputs(case)
+
+    def reading(attend):
+        o, pull = jax.vjp(attend, q, k, v)
+        return tuple(np.asarray(x, np.float32) for x in (o,) + pull(w))
+
+    was = P._INTERPRET, P.BLOCKS
+    P._INTERPRET, P.BLOCKS = True, (128,)
+    try:
+        fused = reading(lambda q, k, v: P.gqa_attention(
+            q, k, v, causal=True, window=None))
+    finally:
+        P._INTERPRET, P.BLOCKS = was
+    return {
+        "fused": fused,
+        "builtin": reading(lambda q, k, v: A._blocked_attention(
+            q, k, v, causal=True, window=None)),
+        "dense": reading(lambda q, k, v: _dense(q, k, v, None)),
+    }
+
+
+@pytest.mark.parametrize("against", ["builtin", "dense"])
+@pytest.mark.parametrize("case", sorted(TWO_SIZES))
+def test_fused_kernel_with_two_head_sizes_agrees(case, against):
+    """Queries and keys of another width than values, and a width that is
+    not whole lanes (192, 64): the output is `[b, t, H, Dv]`, `dq` and `dk`
+    are as wide as q and k, and all four meet the built-in lowering and the
+    dense float32 form (whose scale is `Dqk ** -0.5`, the true width)."""
+    dqk, dv = TWO_SIZES[case]
+    readings = _two_size_readings(case)
+    shapes = [(1, 256, 4, dv), (1, 256, 4, dqk), (1, 256, 2, dqk),
+              (1, 256, 2, dv)]
+    for name, got, want, shape in zip(
+            ("o", "dq", "dk", "dv"), readings["fused"], readings[against],
+            shapes):
+        assert got.shape == want.shape == shape, name
+        gap = np.linalg.norm(got - want) / np.linalg.norm(want)
+        assert gap < 8e-3, (name, gap)
+
+
+def test_the_scale_is_the_true_widths_and_padding_must_pass_it_in(
+        interpreted):
+    """The kernel multiplies the scale it is handed, and the helper hands
+    it `q.shape[-1] ** -0.5` of what the layer gave it: zero-padded
+    operands under the same scale are the same attention, under the padded
+    width's own scale they are another one."""
+    dqk = 192
+    readings = _two_size_readings("latent_192_128")
+    q, k, v, _ = _two_size_inputs("latent_192_128")
+    heads_first = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+    pad = lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, 256 - dqk)))
+    padded = lambda scale: np.asarray(heads_first(P._attend(
+        heads_first(pad(q)), heads_first(pad(k)), heads_first(v), None, 128,
+        scale)), np.float32)
+    same = padded(dqk ** -0.5)
+    assert np.linalg.norm(same - readings["fused"][0]) \
+        < 1e-5 * np.linalg.norm(same)
+    other = padded(256 ** -0.5)
+    assert np.linalg.norm(other - readings["fused"][0]) \
+        > 1e-2 * np.linalg.norm(same)
+
+
+LATENT = dict(q_shape=(2, 8192, 32, 192), dtype=BF16, causal=True,
+              window=None, v_head_dim=128)
+
+
+def test_probe_takes_the_latent_layers_shapes(monkeypatch):
+    """What the latent-attention cell traces: queries and keys of 192,
+    values of 128, a group of one."""
+    monkeypatch.setattr(P, "_INTERPRET", True)
+    assert P.supported(**LATENT) is True
+    helper, moved = _ask(**LATENT)
+    assert helper is not None
+    assert moved == {"hits": {"full": 1}, "auto_disable": {},
+                     "fallbacks": {}}
+    # a call that names no value width has the queries' (the older layers)
+    assert P.supported(**SMALLTHINKER, window=None) is True
+
+
+@pytest.mark.parametrize("change", [
+    {"v_head_dim": 64},                       # values of half a lane
+    {"v_head_dim": 192},
+    {"q_shape": (2, 8192, 32, 96)},           # not whole half-lanes
+    {"q_shape": (2, 16384, 32, 192)},         # dk, dv pass the VMEM they get
+], ids=["v_64", "v_192", "qk_96", "seq_16384"])
+def test_probe_declines_two_sizes_by_shape(change, monkeypatch):
+    monkeypatch.setattr(P, "_INTERPRET", True)
+    ctx = {**LATENT, **change}
+    assert P.supported(**ctx) is False
+    helper, moved = _ask(**ctx)
+    assert helper is None
+    assert moved["fallbacks"] == {"unsupported": {"full": 1}}
+
+
+def test_a_latent_layer_runs_the_kernel_under_checkpoint(interpreted):
+    """`LatentAttentionLayer` in bf16 at 256 positions, heads of 128 + 64
+    and 128: one `full` hit a trace, the same value and gradients as the
+    built-in lowering gives within bf16 rounding."""
+    conf = L.LatentAttentionLayer(
+        n_in=64, n_out=64, n_heads=2, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=32,
+        rope_theta=1e6, weight_init="xavier")
+    params = A.latent_init(jax.random.PRNGKey(0), conf, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 256, 64))
+    ctx = LayerContext(compute_dtype=BF16)
+    loss = lambda p, x: jnp.sum(jnp.sin(jax.checkpoint(
+        lambda p, x: A.latent_forward(conf, p, x, ctx)[0])(p, x)))
+    before = helper_books()
+    got, got_g = jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+    moved = helper_books(before)
+    assert moved["hits"] == {"full": 1} and not moved["fallbacks"]
+    P._INTERPRET = False            # the CPU then declines: the built-in
+    try:
+        want, want_g = jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+    finally:
+        P._INTERPRET = True
+    assert abs(float(got) - float(want)) < 2e-2 * abs(float(want)) + 0.5
+    for a, b in zip(jax.tree_util.tree_leaves(got_g),
+                    jax.tree_util.tree_leaves(want_g)):
+        assert a.shape == b.shape
+        assert float(jnp.linalg.norm(a - b)) \
+            < 2e-2 * float(jnp.linalg.norm(b)) + 1e-4

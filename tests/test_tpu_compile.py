@@ -555,6 +555,8 @@ FUSED_ATTENTION = {
     "smallthinker_window": ((2, 8192, 28, 4), 4096),
     "smallthinker_full": ((2, 8192, 28, 4), None),
     "nemotron_full": ((4, 4096, 32, 2), None),
+    # the latent layer: queries and keys of 192, values of 128, a group of 1
+    "kanana_latent": ((2, 8192, 32, 32), None, (192, 128)),
 }
 
 
@@ -562,13 +564,14 @@ FUSED_ATTENTION = {
 def test_fused_attention_compiles_for_v5e(case, one_chip):
     """`ops/pallas_attention.gqa_attention` as a recomputed block runs it
     (forward, the forward again under `jax.checkpoint`, backward) at the
-    shapes its probe takes from the two decoder cells: the chip's compiler
+    shapes its probe takes from the three decoder cells: the chip's compiler
     takes both kernels at tiles of 1,024 with `dk` and `dv` of one key-value
     head resident, and nothing of `[queries, keys]` is left in the program
     around them."""
     from deeplearning4j_tpu.ops import pallas_attention
 
-    (b, t, heads, kv_heads), window = FUSED_ATTENTION[case]
+    (b, t, heads, kv_heads), window, *sizes = FUSED_ATTENTION[case]
+    d_qk, d_v = sizes[0] if sizes else (128, 128)
     assert pallas_attention._block(t) == 1024
 
     def loss(q, k, v):
@@ -576,10 +579,10 @@ def test_fused_attention_compiles_for_v5e(case, one_chip):
             q, k, v, causal=True, window=window))
         return jnp.sum(jnp.square(attend(q, k, v)))
 
-    arg = lambda n: jax.ShapeDtypeStruct((b, t, n, 128), BF16,
-                                         sharding=one_chip)
+    arg = lambda n, d: jax.ShapeDtypeStruct((b, t, n, d), BF16,
+                                            sharding=one_chip)
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        arg(heads), arg(kv_heads), arg(kv_heads)).compile()
+        arg(heads, d_qk), arg(kv_heads, d_qk), arg(kv_heads, d_v)).compile()
     hlo = compiled.as_text()
     calls = re.findall(r'custom_call_target="tpu_custom_call"', hlo)
     assert len(calls) == 3, len(calls)       # forward twice, backward once
@@ -587,4 +590,6 @@ def test_fused_attention_compiles_for_v5e(case, one_chip):
     # no array with two sequence-sized dimensions: the scores stay in VMEM
     assert not re.search(rf"\[[\d,]*{t},[\d,]*{t}[\d,]*\]", hlo)
     # q, k, v, o, the cotangents and two statistics a query: under 1 GB
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    # (1.3 at the latent layer's 32 heads of 192 and 128)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        1.3e9 if sizes else 1.0e9)
