@@ -22,7 +22,7 @@ held expert is sent more than `capacity` tokens takes the exact path instead
 counted, never silent. The layer's books are layer state (`routed`: tokens
 sent to each of the `router_width` experts; `overflow`: assignments beyond
 the buffer, each served by the exact path; `peak`: the fullest held expert's
-load in one step, of the `rows` its buffer has), carried like batch norm's
+load in one step, of the `rows` its buffer has; `tiles`), carried like batch norm's
 running statistics and published by `publish_expert_books`, the kind's
 publish hook (the net calls it where utils/devprof already blocks, and at
 the end of `fit()`).
@@ -32,7 +32,20 @@ the buffers stay one per held expert: on the chip the same path outside the
 `lax.cond` ran 27 ms a step slower (the compiler then fuses the optimizer's
 update into the weight-gradient products and lays the buffers out worse),
 and one buffer shared by the held experts under `lax.ragged_dot` won only at
-half the rows, which the fullest layer fills to 86% (PERF.md, PR 29).
+half the rows, which the fullest layer fills to 86% (PERF.md, PR 29). What
+multiplies the buffers is one algorithm with two lowerings, asked for once
+a layer a trace at the op slot `grouped_experts` (ops/helpers): on a
+one-device TPU program with bf16 products, `n_in` and `width` of whole
+lanes and one expert's matrices inside the kernels' VMEM, the fused kernels
+of ops/pallas_experts.py, which follow each held expert's load (`count`:
+row tiles past it are never multiplied, the float32 hidden arrays stay on
+the chip; PERF.md, PR 36); else the three einsums over every row. The probe
+declines a width that is not whole lanes (the Nemotron cell's 1,856), any
+other compute dtype, the CPU and a partitioned program;
+`helper_hit_total` / `helper_fallback_total{op="grouped_experts"}` say
+which ran. The fifth book, `tiles`, counts the buffers' tiles of 128 rows
+that a step's routing filled and left empty, whichever lowering runs
+(`experts_row_tiles_total{state}`).
 """
 
 from __future__ import annotations
@@ -48,6 +61,7 @@ from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.layers.registry import LayerContext, register_layer
 from deeplearning4j_tpu.nn.weights import init_weights
 from deeplearning4j_tpu.ops.activations import apply_activation
+from deeplearning4j_tpu.ops.helpers import HelperError, get_helper
 from deeplearning4j_tpu.utils import metrics as _metrics
 
 _ROWS = 128   # a held expert's capacity is a multiple of this many rows
@@ -113,7 +127,8 @@ def experts_state(conf: L.SparseExpertsLayer, dtype):
     return {"routed": jnp.zeros((int(conf.router_width),), jnp.int32),
             "overflow": jnp.zeros((), jnp.int32),
             "peak": jnp.zeros((), jnp.int32),
-            "rows": jnp.zeros((), jnp.int32)}
+            "rows": jnp.zeros((), jnp.int32),
+            "tiles": jnp.zeros((2,), jnp.int32)}
 
 
 def route(conf: L.SparseExpertsLayer, scores, b_select=None):
@@ -210,15 +225,32 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
             "routed": jnp.sum(flat[:, None] == jnp.arange(
                 int(conf.router_width), dtype=jnp.int32), axis=0,
                 dtype=jnp.int32),
-            "overflow": overflow,
-            "peak": jnp.max(jnp.sum(onehot, axis=0, dtype=jnp.int32)),
-            "rows": jnp.asarray(cap, jnp.int32)}
+            "overflow": overflow}
+        # each held expert's load: its assignments fill its rows from 0
+        count = jnp.sum(onehot, axis=0, dtype=jnp.int32)
+        books["peak"] = jnp.max(count)
+        books["rows"] = jnp.asarray(cap, jnp.int32)
+        # whatever multiplies the buffers: the tiles of `_ROWS` rows that
+        # hold an assignment, and those that hold none
+        filled_tiles = jnp.sum((jnp.minimum(count, cap) + _ROWS - 1) // _ROWS)
+        books["tiles"] = jnp.stack([
+            filled_tiles, n_held * (-(-cap // _ROWS)) - filled_tiles])
 
     w1, w2 = params["W1"].astype(cd), params["W2"].astype(cd)
     w3 = params["W3"].astype(cd) if conf.gated else None
 
-    def grouped():
-        rows = u[slot_token].reshape(n_held, cap, d)
+    helper = get_helper("grouped_experts", rows_shape=(n_held, cap, d),
+                        width=int(conf.width), dtype=cd, gated=bool(conf.gated),
+                        activation=conf.activation)
+
+    def products(rows):
+        if helper is not None:
+            try:
+                return helper(rows, w1, w3, w2, slot_w, count,
+                              activation=conf.activation).reshape(
+                                  n_held * cap, d)
+            except HelperError:
+                pass    # disabled and logged: the three einsums below
         hidden = act(jnp.einsum("ecd,edf->ecf", rows, w1,
                                 preferred_element_type=jnp.float32))
         if conf.gated:
@@ -227,7 +259,10 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
         hidden = hidden.astype(cd)
         out_rows = jnp.einsum("ecf,efd->ecd", hidden, w2,
                               preferred_element_type=jnp.float32)
-        out_rows = out_rows.reshape(n_held * cap, d) * slot_w[:, None]
+        return out_rows.reshape(n_held * cap, d) * slot_w[:, None]
+
+    def grouped():
+        out_rows = products(u[slot_token].reshape(n_held, cap, d))
         return jnp.zeros((tokens, d), jnp.float32).at[slot_token].add(
             out_rows)
 
@@ -263,7 +298,8 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
         books = {"routed": state["routed"] + books["routed"],
                  "overflow": state["overflow"] + books["overflow"],
                  "peak": jnp.maximum(state["peak"], books["peak"]),
-                 "rows": jnp.maximum(state["rows"], books["rows"])}
+                 "rows": jnp.maximum(state["rows"], books["rows"]),
+                 "tiles": state["tiles"] + books["tiles"]}
     return y.reshape(shape).astype(x.dtype), books
 
 
@@ -293,6 +329,12 @@ def _instruments():
             "the worst layer's and step's since the books were last "
             "published: how near the layer came to the exact path (above "
             "1 it took it)").labels(),
+        "tiles": reg.counter(
+            "experts_row_tiles_total",
+            "tiles of 128 rows of the held experts' buffers, by whether a "
+            "step's routing put an assignment into them (filled) or none "
+            "(empty): what a lowering that follows each expert's load "
+            "multiplies, and what it may skip", ("state",)),
         "load": reg.gauge(
             "experts_load_max_over_mean",
             "the fullest held expert's assignments over the mean of the "
@@ -308,6 +350,7 @@ def publish_expert_books(confs, books) -> Dict[str, float]:
     already blocks (devprof's sampled steps, the end of `fit()`), never on
     a plain step, and zeroes the books afterwards."""
     held_n = foreign_n = overflow = peak = 0
+    tiles = np.zeros((2,), np.int64)
     fill = 0.0
     loads: List[np.ndarray] = []
     for conf, b in zip(confs, books):
@@ -318,13 +361,17 @@ def publish_expert_books(confs, books) -> Dict[str, float]:
         held_n += int(mine.sum())
         foreign_n += int(routed.sum() - mine.sum())
         overflow += int(b["overflow"])
+        tiles += np.asarray(b["tiles"], np.int64)
         loads.append(mine)
     ins = _instruments()
     ins["assignments"].labels("1").inc(held_n)
     ins["assignments"].labels("0").inc(foreign_n)
     ins["overflow"].inc(overflow)
+    ins["tiles"].labels("filled").inc(int(tiles[0]))
+    ins["tiles"].labels("empty").inc(int(tiles[1]))
     out = {"held": held_n, "foreign": foreign_n, "overflow": overflow,
-           "peak": peak, "fill": fill}
+           "peak": peak, "fill": fill, "tiles_filled": int(tiles[0]),
+           "tiles_empty": int(tiles[1])}
     ins["peak"].set(peak)
     ins["fill"].set(fill)
     if held_n:

@@ -20,5 +20,6 @@ from deeplearning4j_tpu.ops.helpers import (
 from deeplearning4j_tpu.ops import (  # noqa: F401,E402
     pallas_attention,
     pallas_conv_bn,
+    pallas_experts,
     pallas_lstm,
 )

@@ -382,7 +382,10 @@ def test_helper_counter_family_label_cardinality_bounded():
 
     allowed_slugs = {"conv1x1", "conv1x1s2", "conv3x3", "conv3x3s2",
                      "conv7x7s2", "conv_other", "bn_apply", "bn_bwd",
-                     "lstm_seq", "lstm_step"}
+                     "lstm_seq", "lstm_step",
+                     # attention's and the expert layers' slots, where a
+                     # decoder test ran in this process before
+                     "full", "window", "gated", "two_matrix"}
     reg = metrics_mod.get_registry()
     seen = 0
     for name in ("helper_hit_total", "helper_fallback_total",

@@ -593,3 +593,47 @@ def test_fused_attention_compiles_for_v5e(case, one_chip):
     # (1.3 at the latent layer's 32 heads of 192 and 128)
     assert compiled.memory_analysis().temp_size_in_bytes < (
         1.3e9 if sizes else 1.0e9)
+
+
+# -- the fused grouped experts at the two gated cells' shapes ---------------------
+
+FUSED_EXPERTS = {
+    # held experts, rows of a buffer, n_in, width, activation
+    "kanana": (16, 6144, 2048, 768, "silu", True),
+    "smallthinker": (8, 16384, 2560, 768, "relu", True),
+    # a two-matrix layer of whole lanes (the Nemotron cell's 1,856 is not)
+    "two_matrix": (8, 6144, 2688, 1792, "relu2", False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FUSED_EXPERTS))
+def test_fused_experts_compile_for_v5e(case, one_chip):
+    """`ops/pallas_experts.grouped_experts` as a recomputed block runs it,
+    at the shapes its probe takes from the gated decoder cells: the chip's
+    compiler takes the three kernels at the probe's own row tile with an
+    expert's matrices and `dW2` resident in one buffer each, and no float32
+    array of `[held, rows, width]` is left in the program around them."""
+    from deeplearning4j_tpu.ops import pallas_experts
+
+    held, cap, d, width, activation, gated = FUSED_EXPERTS[case]
+    assert pallas_experts._tile(cap, d, width, gated) == (
+        256 if gated else 128)
+
+    def grads(rows, w1, w3, w2, slot_w, count, ct):
+        run = jax.checkpoint(
+            lambda r, a, b, c, s: pallas_experts.grouped_experts(
+                r, a, b, c, s, count, activation=activation))
+        out, pull = jax.vjp(run, rows, w1, w3, w2, slot_w)
+        return (out,) + pull(ct)
+
+    arg = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(grads).lower(
+        arg((held, cap, d)), arg((held, d, width)),
+        arg((held, d, width)) if gated else None, arg((held, width, d)),
+        arg((held * cap,), jnp.float32), arg((held,), jnp.int32),
+        arg((held, cap, d), jnp.float32)).compile()
+    hlo = compiled.as_text()
+    for name in ("experts_fwd", "experts_bwd_rows", "experts_bwd_weights"):
+        assert name in hlo
+    assert not re.search(rf"f32\[{held},{cap},{width}\]", hlo)
