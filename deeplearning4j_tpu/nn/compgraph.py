@@ -39,8 +39,6 @@ from deeplearning4j_tpu.nn.conf.graph import (
 from deeplearning4j_tpu.nn.layers.registry import (
     LayerContext,
     forward_layer,
-    init_layer_params,
-    init_layer_state,
 )
 from deeplearning4j_tpu.nn.multilayer import (
     _OUTPUT_LAYER_TYPES,
@@ -291,21 +289,6 @@ class ComputationGraph(NetworkBase):
             self._block_runs_cache = _detect_block_runs(
                 self.conf, self.topo, self._pidx)
         return self._block_runs_cache
-
-    # -- init ----------------------------------------------------------------
-
-    def init(self) -> "ComputationGraph":
-        key = jax.random.PRNGKey(self.net_conf.seed)
-        dtype = self.policy.param_dtype
-        self.params_list = []
-        self.state_list = []
-        for i, lc in enumerate(self._layer_confs):
-            self.params_list.append(
-                init_layer_params(jax.random.fold_in(key, i), lc, dtype)
-            )
-            self.state_list.append(init_layer_state(lc, dtype))
-        self.upd_state = self.updater_def.init_tree(self.params_list)
-        return self
 
     # -- forward -------------------------------------------------------------
 

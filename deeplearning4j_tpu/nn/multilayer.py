@@ -43,8 +43,6 @@ from deeplearning4j_tpu.nn.layers.core import rnn_output_preout
 from deeplearning4j_tpu.nn.layers.registry import (
     LayerContext,
     forward_layer,
-    init_layer_params,
-    init_layer_state,
 )
 from deeplearning4j_tpu.nn.netbase import NetworkBase
 from deeplearning4j_tpu.nn.trainstep import _is_recurrent
@@ -134,21 +132,6 @@ class MultiLayerNetwork(NetworkBase):
 
     def _ordered_layer_confs(self):
         return self.layer_confs
-
-    # -- init ----------------------------------------------------------------
-
-    def init(self) -> "MultiLayerNetwork":
-        key = jax.random.PRNGKey(self.net_conf.seed)
-        dtype = self.policy.param_dtype
-        self.params_list = []
-        self.state_list = []
-        for i, conf in enumerate(self.layer_confs):
-            self.params_list.append(
-                init_layer_params(jax.random.fold_in(key, i), conf, dtype)
-            )
-            self.state_list.append(init_layer_state(conf, dtype))
-        self.upd_state = self.updater_def.init_tree(self.params_list)
-        return self
 
     # -- forward -------------------------------------------------------------
 

@@ -19,7 +19,11 @@ from typing import List, Optional
 import numpy as np
 
 from deeplearning4j_tpu.data.iterators import AsyncDataSetIterator
-from deeplearning4j_tpu.nn.layers.registry import publish_slots
+from deeplearning4j_tpu.nn.layers.registry import (
+    init_layer_params,
+    init_layer_state,
+    publish_slots,
+)
 from deeplearning4j_tpu.nn.params import (
     flat_to_params,
     num_params,
@@ -101,6 +105,9 @@ class NetworkBase(TrainStep):
         # (the epoch's start before the first): the next record's
         # `t_wait0`, so the timeline's phases tile with no hole
         self._fit_t_mark = 0
+        # ordinal of the current `fit()` call on this net (`fit/run`'s
+        # `fit_call`)
+        self._fit_calls = 0
         # donate_argnums the step builders actually used (recorded by
         # _step_donate_argnums) — the doctor's JX006 check audits THIS,
         # not a reconstruction of the policy
@@ -128,7 +135,34 @@ class NetworkBase(TrainStep):
     # -- to be provided by subclasses ----------------------------------------
 
     def init(self):
-        raise NotImplementedError
+        """Parameters and layer state from the configuration's seed, layer
+        by layer in flattening order, and the updater's state: one
+        always-on `net/init` span (and `net_init_seconds`), under which the
+        initialisers' own programs show as `compile/*` spans."""
+        import jax
+
+        _tracing.watch_compiles()
+        seconds = _metrics.get_registry().histogram(
+            "net_init_seconds",
+            "wall time of a net's init(): parameters, layer state and "
+            "updater state, with the compiles or cache loads of the "
+            "initialisers' programs").labels()
+        with _tracing.phase("net/init", observe=seconds,
+                            engine=type(self).__name__) as span:
+            key = jax.random.PRNGKey(self.net_conf.seed)
+            dtype = self.policy.param_dtype
+            self.params_list = []
+            self.state_list = []
+            for i, conf in enumerate(self._ordered_layer_confs()):
+                self.params_list.append(
+                    init_layer_params(jax.random.fold_in(key, i), conf, dtype))
+                self.state_list.append(init_layer_state(conf, dtype))
+            self.upd_state = self.updater_def.init_tree(self.params_list)
+            leaves = jax.tree_util.tree_leaves(self.params_list)
+            span.args.update(
+                n_leaves=len(leaves),
+                param_bytes=int(sum(leaf.nbytes for leaf in leaves)))
+        return self
 
     def _fit_dataset(self, ds):
         raise NotImplementedError
@@ -166,16 +200,20 @@ class NetworkBase(TrainStep):
             return fn
 
     def _note_compile(self, kind: str, key=None):
-        """Record a jit-cache insertion (a fresh trace/compile) as a
-        first-class event: `compile_total{kind}` in the shared registry
-        plus a trace instant carrying the shape signature — compile
-        storms become a scrape-able number with the shapes that caused
-        them, instead of mystery tail latency."""
+        """Record an insertion into this net's own program dicts (a step
+        program built, an `output()` shape first seen) as
+        `compile_total{kind}` and a flight-recorder event with the shape
+        signature. It counts what the net asked for, not what jax did: a
+        batch of another shape retraces inside the one `jax.jit` and leaves
+        it alone. Real traces, compiles and cache loads, with their seconds,
+        are `jit_compile_seconds{phase}` and the always-on `compile/*` spans
+        (utils/tracing.watch_compiles)."""
         _metrics.get_registry().counter(
-            "compile_total", "jit cache insertions (fresh traces)",
+            "compile_total",
+            "insertions into a net's own program dicts (a step program "
+            "built, an output() shape first seen); what jax really traced, "
+            "compiled or loaded is jit_compile_seconds{phase}",
             ("kind",)).labels(kind).inc()
-        _tracing.instant("compile", kind=kind,
-                         key=None if key is None else str(key))
         _blackbox.get_recorder().record_event(
             "compile", compile_kind=kind,
             key=None if key is None else str(key))
@@ -443,9 +481,12 @@ class NetworkBase(TrainStep):
                     "dispatch").labels(),
                 "dispatch": reg.histogram(
                     "fit_dispatch_seconds",
-                    "host time in the train-step call (trace + dispatch, "
-                    "and the wait for a free slot in the device's queue: "
-                    "on a busy chip it tracks the device step)").labels(),
+                    "host time in the train-step call: the dispatch and "
+                    "the wait for a free slot in the device's queue (on a "
+                    "busy chip it tracks the device step); a program's "
+                    "first dispatch also holds its trace and its compile "
+                    "or cache load, which jit_compile_seconds{phase} and "
+                    "the compile/* spans' `iteration` set apart").labels(),
                 "examples_unknown": reg.counter(
                     "fit_examples_unknown_total",
                     "fit batches whose example count could not be "
@@ -473,6 +514,15 @@ class NetworkBase(TrainStep):
                 "timeline": _tracing.get_step_timeline().append,
                 "devprof": _devprof.get_profiler(),
             }
+            phases = reg.histogram(
+                "fit_phase_seconds",
+                "wall time of a fit() call's own entry (setup: auto mesh, "
+                "resume, staging the input pipeline), exit (teardown: the "
+                "layers' books, the pipeline's close) and each blocking "
+                "read of the layers' books (publish_books: it drains the "
+                "steps in flight)", ("phase",))
+            for phase in ("setup", "teardown", "publish_books"):
+                ins["phase_" + phase] = phases.labels(phase)
         return ins
 
     def _timed_fit(self, fit_fn, data_wait: float, n_examples: int,
@@ -621,90 +671,129 @@ class NetworkBase(TrainStep):
                  hang_timeout: Optional[float] = None,
                  resume_from: Optional[str] = None,
                  run_ledger=None):
-        # run-ledger opt-in (ONE knob): a path builds a RunLedger there
-        # (closed when the fit ends — the per-run artifact), an instance
-        # is attached for the fit's duration and left open for its
-        # owner. Hooks stay a single flag check when this is None.
-        owned_ledger = attached_ledger = None
-        if run_ledger is not None:
-            if isinstance(run_ledger, str):
-                owned_ledger = _runledger.RunLedger(run_ledger)
-                attached_ledger = _runledger.attach(owned_ledger)
-            else:
-                attached_ledger = _runledger.attach(run_ledger)
-        # multi-device default: engage the sharded data-parallel step
-        # BEFORE restore/staging so the restored state lands on the mesh
-        # and the pipeline stages batches with the mesh sharding
-        self._maybe_auto_mesh()
-        if self._mesh_plan is not None:
-            self._mesh_plan.reset_pad_target()
-        skip_batches = 0
-        if resume_from is not None:
-            # restore BEFORE staging: the iterator state lands on the
-            # caller's iterator, not the pipeline wrappers about to be
-            # composed around it
-            skip_batches, epochs, _ = self._restore_for_resume(
-                resume_from, iterator, epochs)
+        """One `fit()` call: the always-on span `fit/run`, whose trace id
+        every span of the call shares, around `fit/setup` (from the entry
+        to just before the first epoch), the epochs and `fit/teardown`
+        (from the epochs' return, or their raise, to the end of the
+        clean-up). The listeners' `on_fit_end` hooks run last, once
+        `fit/run` is in the ring: a `TracingListener` writes it out."""
+        _tracing.watch_compiles()
+        self._fit_calls += 1
+        try:
+            with _tracing.phase("fit/run", fit_call=self._fit_calls,
+                                epochs=int(epochs)) as run:
+                self._run_fit_phases(run, iterator, epochs, async_prefetch,
+                                     prefetch_buffer, hang_timeout,
+                                     resume_from, run_ledger)
+        finally:
+            # fires even when an epoch raises: listeners that flipped
+            # process-global state for the run (TracingListener) restore
+            # it here instead of leaking it past a failed fit
+            for lst in self.listeners:
+                hook = getattr(lst, "on_fit_end", None)
+                if hook is not None:
+                    hook(self)
+        return self
+
+    def _run_fit_phases(self, run, iterator, epochs: int,
+                        async_prefetch: bool, prefetch_buffer: int,
+                        hang_timeout: Optional[float],
+                        resume_from: Optional[str], run_ledger):
+        ins = self._fit_obs()
+        with _tracing.phase("fit/setup", observe=ins["phase_setup"],
+                            async_prefetch=bool(async_prefetch)) as setup:
+            # run-ledger opt-in (ONE knob): a path builds a RunLedger there
+            # (closed when the fit ends — the per-run artifact), an instance
+            # is attached for the fit's duration and left open for its
+            # owner. Hooks stay a single flag check when this is None.
+            owned_ledger = attached_ledger = None
+            if run_ledger is not None:
+                if isinstance(run_ledger, str):
+                    owned_ledger = _runledger.RunLedger(run_ledger)
+                    attached_ledger = _runledger.attach(owned_ledger)
+                else:
+                    attached_ledger = _runledger.attach(run_ledger)
+            # multi-device default: engage the sharded data-parallel step
+            # BEFORE restore/staging so the restored state lands on the mesh
+            # and the pipeline stages batches with the mesh sharding
+            self._maybe_auto_mesh()
             if self._mesh_plan is not None:
-                # checkpoint arrays arrive as host numpy: re-commit them
-                # to the mesh so the sharded step's in-shardings match
-                self._mesh_plan.place_net(self)
-        owned = None
-        if async_prefetch:
-            staged = self._stage_input_pipeline(iterator, prefetch_buffer)
-            if staged is not iterator:
-                iterator = owned = staged
-        # a caller-installed batch transform disables fusion (per-batch
-        # hooks must see their own batch) — EXCEPT the mesh plan's own
-        # shard_batch: sharded batches stack fine, and the stacked fused
-        # programs shard batch dim 1 (stacked_data in _jit_step), so
-        # mesh-attached nets keep their dispatch-fusion opt-in. The
-        # divergence sentinel also disables fusion: quarantine must be
-        # able to discard ONE step's update, not a fused group's.
-        plan_shard = (None if self._mesh_plan is None
-                      else self._mesh_plan.shard_batch)
-        fuse_k = self._fused_k if (
-            self._fused_k > 1
-            and not self.listeners
-            and not self._collect_stats
-            and self._sentinel is None
-            and (self._batch_transform is None
-                 or self._batch_transform == plan_shard)
-            and self._fused_fit_supported()
-        ) else 1
-        # sentinel wiring: resolve the rollback directory (explicit >
-        # resume_from > an attached CheckpointListener) and reset the
-        # per-fit escalation counters
-        if self._sentinel is not None:
-            self._sentinel.bind(self, resume_dir=resume_from)
-        # the epoch target the rollback loop restores toward: `epochs`
-        # is already "remaining" here (the initial resume consumed the
-        # completed ones), so the absolute target is epoch + remaining
-        total_epoch_target = int(self.epoch) + int(epochs)
-        # liveness: the fit thread holds a busy slot on the "fit"
-        # heartbeat for the whole run and beats once per dispatch
-        # (_timed_fit). With hang_timeout the watchdog's stall action
-        # dumps the flight recorder and raises StepHangError here —
-        # a wedged step becomes a diagnosable exception, not a hang.
-        hb = _health.get_health().register(
-            "fit",
-            stall_after=hang_timeout if hang_timeout else 600.0,
-            on_stall=self._hang_action() if hang_timeout else None)
-        self._fit_heartbeat = hb
+                self._mesh_plan.reset_pad_target()
+            skip_batches = 0
+            if resume_from is not None:
+                # restore BEFORE staging: the iterator state lands on the
+                # caller's iterator, not the pipeline wrappers about to be
+                # composed around it
+                skip_batches, epochs, _ = self._restore_for_resume(
+                    resume_from, iterator, epochs)
+                if self._mesh_plan is not None:
+                    # checkpoint arrays arrive as host numpy: re-commit them
+                    # to the mesh so the sharded step's in-shardings match
+                    self._mesh_plan.place_net(self)
+            owned = None
+            if async_prefetch:
+                staged = self._stage_input_pipeline(iterator, prefetch_buffer)
+                if staged is not iterator:
+                    iterator = owned = staged
+            # a caller-installed batch transform disables fusion (per-batch
+            # hooks must see their own batch) — EXCEPT the mesh plan's own
+            # shard_batch: sharded batches stack fine, and the stacked fused
+            # programs shard batch dim 1 (stacked_data in _jit_step), so
+            # mesh-attached nets keep their dispatch-fusion opt-in. The
+            # divergence sentinel also disables fusion: quarantine must be
+            # able to discard ONE step's update, not a fused group's.
+            plan_shard = (None if self._mesh_plan is None
+                          else self._mesh_plan.shard_batch)
+            fuse_k = self._fused_k if (
+                self._fused_k > 1
+                and not self.listeners
+                and not self._collect_stats
+                and self._sentinel is None
+                and (self._batch_transform is None
+                     or self._batch_transform == plan_shard)
+                and self._fused_fit_supported()
+            ) else 1
+            # sentinel wiring: resolve the rollback directory (explicit >
+            # resume_from > an attached CheckpointListener) and reset the
+            # per-fit escalation counters
+            if self._sentinel is not None:
+                self._sentinel.bind(self, resume_dir=resume_from)
+            # the epoch target the rollback loop restores toward: `epochs`
+            # is already "remaining" here (the initial resume consumed the
+            # completed ones), so the absolute target is epoch + remaining
+            total_epoch_target = int(self.epoch) + int(epochs)
+            # liveness: the fit thread holds a busy slot on the "fit"
+            # heartbeat for the whole run and beats once per dispatch
+            # (_timed_fit). With hang_timeout the watchdog's stall action
+            # dumps the flight recorder and raises StepHangError here —
+            # a wedged step becomes a diagnosable exception, not a hang.
+            hb = _health.get_health().register(
+                "fit",
+                stall_after=hang_timeout if hang_timeout else 600.0,
+                on_stall=self._hang_action() if hang_timeout else None)
+            self._fit_heartbeat = hb
+            setup.args["mesh"] = (None if self._mesh_plan is None
+                                  else str(dict(self._mesh_plan.mesh.shape)))
+        # a resume moved the count: the first step this call runs
+        run.args["first_iteration"] = int(self.iteration)
+        teardown = _tracing.phase("fit/teardown",
+                                  observe=ins["phase_teardown"])
         try:
             with hb.busy():
-                while True:
-                    try:
-                        self._fit_epochs(iterator, epochs, fuse_k,
-                                         skip_batches)
-                        break
-                    except _sentinel.RollbackSignal:
-                        # the sentinel's escalation: restore the last-
-                        # good checkpoint and replay — bounded attempts
-                        # (note_rollback raises TrainingDivergedError
-                        # past the budget)
-                        skip_batches, epochs = self._rollback_restore(
-                            iterator, total_epoch_target)
+                with _tracing.steps_of(self):
+                    while True:
+                        try:
+                            self._fit_epochs(iterator, epochs, fuse_k,
+                                             skip_batches)
+                            break
+                        except _sentinel.RollbackSignal:
+                            # the sentinel's escalation: restore the last-
+                            # good checkpoint and replay — bounded attempts
+                            # (note_rollback raises TrainingDivergedError
+                            # past the budget)
+                            skip_batches, epochs = self._rollback_restore(
+                                iterator, total_epoch_target)
+                teardown.__enter__()
                 # counters that layers keep on the device reach the
                 # registry here and on devprof's sampled steps, never on a
                 # plain step
@@ -727,6 +816,10 @@ class NetworkBase(TrainStep):
                              "dump at %s", path)
             raise
         finally:
+            if not teardown.t0:
+                # the epochs raised: the clean-up is the teardown
+                teardown.__enter__()
+            run.args["last_iteration"] = int(self.iteration) - 1
             # the ledger scope ends with the fit: an owned (path-built)
             # ledger takes its final sample and closes; a caller-owned
             # one is only detached (its recording thread lives on)
@@ -750,14 +843,7 @@ class NetworkBase(TrainStep):
             # case; this covers anything still live after an exception)
             if owned is not None:
                 owned.close()
-            # fires even when an epoch raises: listeners that flipped
-            # process-global state for the run (TracingListener) restore
-            # it here instead of leaking it past a failed fit
-            for lst in self.listeners:
-                hook = getattr(lst, "on_fit_end", None)
-                if hook is not None:
-                    hook(self)
-        return self
+            teardown.__exit__(None, None, None)
 
     def _publish_layer_books(self):
         """Publish counters that layers carry as state on the device,
@@ -775,15 +861,20 @@ class NetworkBase(TrainStep):
 
         confs = self._ordered_layer_confs()
         flat = [i for idx in slots.values() for i in idx]
-        books = dict(zip(flat, jax.device_get(
-            [self.state_list[i] for i in flat])))
-        out = {}
-        for hook, idx in slots.items():
-            out.update(hook([confs[i] for i in idx],
-                            [books[i] for i in idx]) or {})
-        for i in flat:
-            self.state_list[i] = jax.tree_util.tree_map(
-                jax.numpy.zeros_like, self.state_list[i])
+        # the blocking read: it waits for every step in flight, so the
+        # always-on span is the drain at the end of a fit()
+        with _tracing.phase("fit/publish_books",
+                            observe=self._fit_obs()["phase_publish_books"],
+                            n_slots=len(flat)):
+            books = dict(zip(flat, jax.device_get(
+                [self.state_list[i] for i in flat])))
+            out = {}
+            for hook, idx in slots.items():
+                out.update(hook([confs[i] for i in idx],
+                                [books[i] for i in idx]) or {})
+            for i in flat:
+                self.state_list[i] = jax.tree_util.tree_map(
+                    jax.numpy.zeros_like, self.state_list[i])
         return out
 
     def _hang_action(self):

@@ -180,7 +180,10 @@ class TracingListener(IterationListener):
     `fit/dispatch` / `fit/observe` spans (nn/netbase.py); this
     listener adds an `iteration` instant per step (iteration number +
     batch size) and writes `jsonl_path` / `chrome_path` after each epoch
-    so a killed run still leaves a trace artifact behind.
+    so a killed run still leaves a trace artifact behind, and once more
+    when the fit ends: `on_fit_end` runs after the always-on `fit/run`
+    span closed, so the last file holds the whole call (`cli trace <file>`
+    roots its tree at `fit/run`).
 
     Tracing is enabled at each epoch start and restored to its prior
     state at each epoch end (pass restore_on_epoch_end=False to leave it
@@ -218,7 +221,7 @@ class TracingListener(IterationListener):
             self._tracing.enable(bool(self._was_enabled))
 
     def on_fit_end(self, model):
-        # runs in the fit loop's finally: a fit that raises mid-epoch
+        # runs in `_run_fit`'s finally: a fit that raises mid-epoch
         # must still restore the process-global flag (and leave the
         # artifacts covering what WAS captured) — otherwise every other
         # net in the process inherits per-step device syncs forever

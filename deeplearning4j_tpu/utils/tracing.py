@@ -47,19 +47,50 @@ The flight recorder (utils/blackbox) builds its "final steps" from it, and
 the benchmark attributes device idle gaps to the fit thread's phases
 with it.
 
+Always-on spans: boundaries that are crossed once a `fit()` or `init()`
+call, and never once a step, are recorded whether the tracer is enabled or
+not (`phase()`: two clock reads and one append into the same ring, on the
+same parent stack). They are what a slow start is made of, and a slow
+start is over before anybody thinks of `enable(True)`:
+
+* `net/init` (both engines' `init()`), `fit/run` > `fit/setup`,
+  `fit/teardown` and `fit/publish_books` (nn/netbase.py);
+* `compile/trace`, `compile/lower`, `compile/backend` >
+  `compile/cache_load`: every stage of every program jax compiles or loads
+  from its persistent cache, opened and closed by jax's own monitoring
+  events (`watch_compiles()`, installed once on the first `init()` or
+  `fit()`). Each names its program (`fun_name`), the optimizer step it fell
+  in (`iteration`, inside `steps_of(net)`; None elsewhere), its self time
+  (`self_s`: the duration less what nested compile spans cover) and, on
+  `compile/backend`, `cache` = "hit" / "miss" / "off" and `saved_s`. The
+  registry holds the sums: `jit_compile_seconds{phase}` (self times, so the
+  phases of one program add up to the wall it held the thread) and
+  `jit_cache_total{result}`.
+
+Everything else needs `enable(True)`: `fit/step` > `fit/dispatch`,
+`fit/observe` (they then nest under `fit/run` by the stack; with the tracer
+off, `fit/run`'s `first_iteration` / `last_iteration` tie it to the step
+timeline's records), the serving and parameter-server spans, instants.
+After a slow start: `get_tracer().write_jsonl(path)` (or a
+`TracingListener(jsonl_path=...)` on the net, which writes when the fit
+ends), then `cli trace <path>` for the tree and the critical path of
+`fit/run`.
+
 Overhead contract: span recording is OFF by default and every propagation entry
 point — `span()`, `instant()`, `attach()`/`detach()`,
 `current_context()`, `current_traceparent()`, `record_complete()` —
 degrades to one flag check on the disabled path: no allocation, no lock,
 no clock read, no id minting. The fit loop's phase timers and the
 serving/jsonhttp hot paths depend on this (the <10µs-per-call guard in
-tests covers span creation AND the context hooks).
+tests covers span creation AND the context hooks). `phase()` is never
+called once a step, and the compile listener runs only when jax compiles.
 """
 
 from __future__ import annotations
 
 import json
 import itertools
+import logging
 import os
 import threading
 import time
@@ -67,6 +98,8 @@ from collections import deque
 from typing import List, Optional
 
 from deeplearning4j_tpu.utils import tenancy as _tenancy
+
+logger = logging.getLogger("deeplearning4j_tpu")
 
 # span ids are ints, unique within a process and unlikely to collide
 # across processes: the counter starts at a random 60-bit offset so two
@@ -190,17 +223,21 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "args", "id", "parent", "trace",
-                 "t0", "_ann")
+    __slots__ = ("tracer", "name", "args", "observe", "id", "parent",
+                 "trace", "t0", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
+    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict],
+                 observe=None):
         self.tracer = tracer
         self.name = name
         self.args = args
+        # a histogram child that takes the duration in seconds on exit
+        # (`phase(..., observe=)`), or None
+        self.observe = observe
         self.id = next(_counter)
         self.parent = None
         self.trace = None
-        self.t0 = 0
+        self.t0 = 0  # 0 until entered
         self._ann = None
 
     @property
@@ -210,25 +247,14 @@ class _Span:
         return SpanContext(self.trace, self.id)
 
     def __enter__(self):
+        self.parent, self.trace = _ambient()
         stack = getattr(_tls, "stack", None)
         if stack is None:
             stack = _tls.stack = []
-        if stack:
-            top = stack[-1]
-            self.parent = top.id
-            self.trace = top.trace
-        else:
-            # thread-root span: an attach()ed context (the explicit
-            # cross-thread / cross-process handoff) parents it; with
-            # nothing attached this span is a trace root and mints the id
-            att = getattr(_tls, "attached", None)
-            if att is not None:
-                self.parent = att.span_id
-                self.trace = att.trace_id
-            else:
-                self.trace = _mint_trace_id()
         stack.append(self)
-        if self.tracer.annotate_device:
+        # an always-on phase enters the device annotation only when the
+        # tracer is on (`span()` makes no _Span when it is off)
+        if self.tracer.enabled and self.tracer.annotate_device:
             ann = _trace_annotation(self.name)
             if ann is not None:
                 self._ann = ann
@@ -243,9 +269,28 @@ class _Span:
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] is self:
             stack.pop()
+        self._book(t1 - self.t0, stack)
         self.tracer._record(self.name, self.t0, t1 - self.t0, self.id,
-                            self.parent, self.args, trace=self.trace)
+                            self.parent, self.args or None, trace=self.trace)
         return False
+
+    def _book(self, dur_ns: int, stack) -> None:
+        if self.observe is not None:
+            self.observe.observe(dur_ns * 1e-9)
+
+
+def _ambient():
+    """(parent span id, trace id) of a record made now on this thread: the
+    innermost open span's; for a thread-root record the attach()ed context
+    (the explicit cross-thread / cross-process handoff); with nothing
+    attached it is a trace root and mints the id."""
+    stack = getattr(_tls, "stack", None)
+    if stack:
+        return stack[-1].id, stack[-1].trace
+    att = getattr(_tls, "attached", None)
+    if att is not None:
+        return att.span_id, att.trace_id
+    return None, _mint_trace_id()
 
 
 def _trace_annotation(name: str):
@@ -287,21 +332,13 @@ class Tracer:
         return _Span(self, name, args or None)
 
     def instant(self, name: str, **args):
-        """Zero-duration marker event (compile-cache insertions, helper
-        auto-disables, injected faults, ...). Parents to the innermost
+        """Zero-duration marker event (helper auto-disables, injected
+        faults, ...). Parents to the innermost
         active span — or the attach()ed context on a worker thread — so
         markers land inside the trace that caused them."""
         if not self.enabled:
             return
-        stack = getattr(_tls, "stack", None)
-        if stack:
-            parent, trace = stack[-1].id, stack[-1].trace
-        else:
-            att = getattr(_tls, "attached", None)
-            if att is not None:
-                parent, trace = att.span_id, att.trace_id
-            else:
-                parent, trace = None, _mint_trace_id()
+        parent, trace = _ambient()
         self._record(name, now_ns(), 0, next(_counter),
                      parent, args or None, phase="i", trace=trace)
 
@@ -503,6 +540,226 @@ def record_complete(name: str, t0: float, t1: float,
                     parent: Optional[SpanContext] = None,
                     **args) -> Optional[SpanContext]:
     return _TRACER.record_complete(name, t0, t1, parent, **args)
+
+
+# -- always-on lifecycle phases ------------------------------------------------
+
+def phase(name: str, observe=None, **args) -> _Span:
+    """An always-on span for a boundary crossed once a `fit()` or `init()`
+    call, never once a step: `with tracing.phase("fit/setup"): ...` records
+    into the ring whether the tracer is enabled or not (two clock reads and
+    one append), nests on the thread's parent stack like any span, and
+    enters the device annotation only when the tracer is on. `observe` is a
+    histogram child that takes the duration in seconds on exit. The span's
+    `args` is a dict the caller may fill until the exit."""
+    return _Span(_TRACER, name, args, observe)
+
+
+class steps_of:
+    """`with tracing.steps_of(net): ...` — while it is open on a thread, a
+    compile span recorded there carries `iteration=net.iteration`: the
+    optimizer step whose data wait or dispatch the compile fell in (the
+    net's count moves on when the step program returns, so a compile among
+    the observers reads the next step's). One thread-local store a `fit()`
+    call, none a step."""
+
+    __slots__ = ("net", "_prev")
+
+    def __init__(self, net):
+        self.net = net
+
+    def __enter__(self):
+        self._prev = getattr(_tls, "stepper", None)
+        _tls.stepper = self.net
+        return self
+
+    def __exit__(self, *exc):
+        _tls.stepper = self._prev
+        return False
+
+
+# -- jax's compiles as spans ----------------------------------------------------
+
+# jax's monitoring events (jax/_src/dispatch.py, jax 0.9.0): each stage is
+# announced by a scalar event when it starts and a duration event when it
+# ends, both with `fun_name`, on the thread that compiles
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+# ... and inside the backend stage (jax/_src/compiler.py): a request that
+# goes through the persistent cache, a hit, and on a hit what the load took
+# and what it saved
+_CACHE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+
+
+def _compile_books():
+    """(`jit_compile_seconds`, `jit_cache_total`) of the shared registry,
+    both children of the second made: no miss reads 0, not an absent
+    series. Looked up at each compile and never kept, so a registry that a
+    test reset gets them back."""
+    from deeplearning4j_tpu.utils import metrics  # imports this module
+
+    reg = metrics.get_registry()
+    seconds = reg.histogram(
+        "jit_compile_seconds",
+        "self time of each stage of each program jax traced, lowered, "
+        "compiled or loaded from its persistent cache (backend: the "
+        "compile, or the look in the cache around a load; cache_load: the "
+        "load on a hit); the stages of one program add up to the wall it "
+        "held its thread", ("phase",))
+    cache = reg.counter(
+        "jit_cache_total",
+        "programs asked of jax's persistent compilation cache, by result",
+        ("result",))
+    cache.labels("hit"), cache.labels("miss")
+    return seconds, cache
+
+
+class _CompileSpan(_Span):
+    """One stage of one program's compilation: an always-on span that jax's
+    start event opens and its duration event closes (`watch_compiles`)."""
+
+    __slots__ = ("stage", "covered", "skipped", "cache")
+
+    def __init__(self, stage: str, fun_name):
+        super().__init__(_TRACER, "compile/" + stage, {"fun_name": fun_name})
+        self.stage = stage
+        self.covered = 0   # ns of this span that nested compile spans cover
+        self.skipped = 0   # nested traces open now that were given no span
+        self.cache = "off"  # backend: "hit" / "miss" once the cache was asked
+
+    def _book(self, dur_ns: int, stack) -> None:
+        # self time: an inner jit traced (or a constant compiled) while
+        # this stage ran has its own spans, and its seconds are booked there
+        self_ns = max(0, dur_ns - self.covered)
+        if stack and isinstance(stack[-1], _CompileSpan):
+            stack[-1].covered += dur_ns
+        self.args.update(iteration=_iteration(), self_s=self_ns * 1e-9)
+        seconds, cache = _compile_books()
+        seconds.labels(self.stage).observe(self_ns * 1e-9)
+        if self.stage == "backend":
+            self.args["cache"] = self.cache
+            if self.cache != "off":
+                cache.labels(self.cache).inc()
+
+
+def _iteration() -> Optional[int]:
+    stepper = getattr(_tls, "stepper", None)
+    return None if stepper is None else int(stepper.iteration)
+
+
+def _record_compile(stage: str, dur_ns: int, fun_name) -> None:
+    """A compile span known only when it ended: it ends now, under
+    whatever is open on the thread. Nothing nested in it was subtracted."""
+    end = now_ns()
+    args = {"fun_name": fun_name, "iteration": _iteration(),
+            "self_s": dur_ns * 1e-9}
+    parent, trace = _ambient()
+    _TRACER._record("compile/" + stage, end - dur_ns, dur_ns,
+                    next(_counter), parent, args, trace=trace)
+    _compile_books()[0].labels(stage).observe(dur_ns * 1e-9)
+
+
+def _open_compile_span():
+    stack = getattr(_tls, "stack", None)
+    top = stack[-1] if stack else None
+    return top if isinstance(top, _CompileSpan) else None
+
+
+def _on_jax_scalar(event: str, value, **kw) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    top = _open_compile_span()
+    if stage == "trace" and top is not None and not _TRACER.enabled:
+        # a jit called while an outer one is traced or lowered (jax.numpy's
+        # own are: two thousand a step program of four layers). With the
+        # tracer off its seconds stay the outer span's: the same union
+        top.skipped += 1
+        return
+    _CompileSpan(stage, kw.get("fun_name")).__enter__()
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    top = _open_compile_span()
+    dur_ns = int(duration * 1e9)
+    if stage is not None:
+        if stage == "trace" and top is not None and top.skipped:
+            top.skipped -= 1
+        elif top is not None and top.stage == stage:
+            top.__exit__(None, None, None)
+        else:
+            # no start event was seen (another jax, or the listener came
+            # between the two)
+            _record_compile(stage, dur_ns, kw.get("fun_name"))
+    elif top is not None and top.stage == "backend":
+        if event == _CACHE_LOAD:
+            top.covered += dur_ns
+            _record_compile("cache_load", dur_ns, top.args["fun_name"])
+        elif event == _CACHE_SAVED:
+            top.args["saved_s"] = duration
+
+
+def _on_jax_event(event: str, **kw) -> None:
+    if event != _CACHE_REQUEST and event != _CACHE_HIT:
+        return
+    top = _open_compile_span()
+    if top is None or top.stage != "backend":
+        return
+    if event == _CACHE_HIT:
+        top.cache = "hit"
+    else:
+        # jax announces the request whether or not a directory is set
+        import jax
+
+        if jax.config.jax_compilation_cache_dir is not None:
+            top.cache = "miss"
+
+
+def _never_raising(listener):
+    """jax calls its listeners from inside a compile: a fault in the
+    tracing must not become a fault of the program."""
+    def call(event, *a, **kw):
+        try:
+            listener(event, *a, **kw)
+        except Exception:
+            logger.exception("tracing: the listener of jax's event %s "
+                             "failed", event)
+    return call
+
+
+_watch_lock = threading.Lock()
+_watching = False
+
+
+def watch_compiles() -> None:
+    """Record every jit trace, lowering, compile and cache load of the
+    process as always-on spans `compile/trace`, `compile/lower`,
+    `compile/backend` > `compile/cache_load`, and book their self times
+    under `jit_compile_seconds{phase}` and the cache's answers under
+    `jit_cache_total{result}`. Idempotent: the first `init()` or `fit()`
+    installs the three listeners with `jax.monitoring`, which keeps them
+    for the life of the process; later calls are one flag check."""
+    global _watching
+    if _watching:
+        return
+    with _watch_lock:
+        if _watching:
+            return
+        from jax import monitoring
+
+        monitoring.register_scalar_listener(_never_raising(_on_jax_scalar))
+        monitoring.register_event_duration_secs_listener(
+            _never_raising(_on_jax_duration))
+        monitoring.register_event_listener(_never_raising(_on_jax_event))
+        _compile_books()
+        _watching = True
 
 
 # -- context propagation ------------------------------------------------------

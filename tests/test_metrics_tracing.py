@@ -188,6 +188,7 @@ def test_snapshot_is_strict_json():
 
 def test_span_disabled_is_free_singleton():
     tracing.enable(False)
+    tracing.get_tracer().clear()   # lifecycle spans of earlier fits
     s1, s2 = tracing.span("a"), tracing.span("b", k=1)
     assert s1 is s2 is tracing.NULL_SPAN
     with s1:
@@ -441,10 +442,10 @@ def test_fit_hot_path_no_registry_lookups_when_disabled(monkeypatch):
     monkeypatch.setattr(MetricsRegistry, "_get_or_create", counting)
     net.fit(x, y, epochs=1, batch_size=4, async_prefetch=False)  # 50 steps
     fit_lookups = [n for n in lookups if n.startswith("fit_")]
-    # instruments resolved at most once each (5 families: steps/
-    # examples/examples_unknown/data_wait/dispatch), NOT once per 50
-    # steps
-    assert len(fit_lookups) <= 5, fit_lookups
+    # instruments resolved at most once each (6 families: steps/
+    # examples/examples_unknown/data_wait/dispatch and the fit call's
+    # own phases), NOT once per 50 steps
+    assert len(fit_lookups) <= 6, fit_lookups
     # a second fit reuses the cached children: no new lookups at all
     lookups.clear()
     net.fit(x, y, epochs=1, batch_size=4, async_prefetch=False)
