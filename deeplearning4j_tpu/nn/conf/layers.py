@@ -547,7 +547,13 @@ class GroupedQueryAttentionLayer(BaseRecurrentLayerConf):
     `0 .. t - 1` over the whole `head_dim`, dimension `i` paired with `i +
     head_dim / 2` (the half-split pairing), at frequencies `rope_theta **
     (-2 i / head_dim)`. Both are a layer's own, so that one net may hold
-    layers of either kind."""
+    layers of either kind.
+
+    `qk_norm` normalises each query and key head over `head_dim` (an RMS
+    norm with statistics in float32, `eps`, weights `q_norm` / `k_norm`)
+    before the rotation. `output_gate` multiplies the attention's output by
+    `sigmoid(u Wg)` (`Wg`: `n_in -> n_heads * head_dim`, float32) before
+    `Wo`. Both are off by default, and the layer is then what it was."""
 
     n_heads: int = 4
     n_kv_heads: int = 1
@@ -555,6 +561,9 @@ class GroupedQueryAttentionLayer(BaseRecurrentLayerConf):
     causal: bool = True
     window: Optional[int] = None
     rope_theta: Optional[float] = None
+    qk_norm: bool = False
+    output_gate: bool = False
+    eps: float = 1e-6
 
 
 @register_config("layer.latent_attention")

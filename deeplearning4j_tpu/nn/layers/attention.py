@@ -9,7 +9,8 @@ unattendable) and zeroes masked outputs, matching the framework's RNN
 masking semantics.
 
 GroupedQueryAttentionLayer (causal, full or windowed, optional rotary
-positions, no time mask): projections and `rope` are XLA's; the inner part
+positions, optional per-head query/key norms and output gate, no time mask):
+projections, norms, `rope` and the gate are XLA's; the inner part
 (scores, band mask, softmax, mix: `grouped_query_attention`) is one
 algorithm with two lowerings, chosen by what the trace can observe. The
 fused kernel (ops/pallas_attention.py, helper slot "gqa_attention") serves
@@ -124,8 +125,19 @@ def gqa_init(key, conf: L.GroupedQueryAttentionLayer, dtype):
     ks = jax.random.split(key, 4)
     mk = lambda k, i, o: init_weights(k, (i, o), i, o, conf.weight_init,
                                       conf.dist, dtype)
-    return {"Wq": mk(ks[0], n_in, H * D), "Wk": mk(ks[1], n_in, KV * D),
-            "Wv": mk(ks[2], n_in, KV * D), "Wo": mk(ks[3], H * D, n_out)}
+    p = {"Wq": mk(ks[0], n_in, H * D), "Wk": mk(ks[1], n_in, KV * D),
+         "Wv": mk(ks[2], n_in, KV * D), "Wo": mk(ks[3], H * D, n_out)}
+    if conf.qk_norm:
+        p["q_norm"], p["k_norm"] = jnp.ones((D,), dtype), jnp.ones((D,), dtype)
+    if conf.output_gate:
+        p["Wg"] = mk(jax.random.fold_in(key, 4), n_in, H * D)
+    return p
+
+
+def gqa_order(conf):
+    return ("Wq", "Wk", "Wv", "Wo") \
+        + (("q_norm", "k_norm") if conf.qk_norm else ()) \
+        + (("Wg",) if conf.output_gate else ())
 
 
 # Keys above which a block's softmax takes its row maximum in a pass of its
@@ -220,6 +232,22 @@ def _count_lowering(t: int, *, window, positions: str,
     multiplied, skipped = key_block_pairs(t, window, causal=causal)
     pairs.labels("multiplied").inc(multiplied)
     pairs.labels("skipped").inc(skipped)
+
+
+def _count_parts(conf: L.GroupedQueryAttentionLayer) -> None:
+    """Trace-time: the layer's optional parts around the inner attention,
+    once a layer a trace."""
+    parts = [name for name, on in (("qk_norm", conf.qk_norm),
+                                   ("output_gate", conf.output_gate)) if on]
+    if not parts:
+        return
+    counter = _metrics.get_registry().counter(
+        "attention_parts_total",
+        "optional parts of the traced grouped-query attention layers: a "
+        "per-head RMS norm of queries and keys (qk_norm), a sigmoid gate on "
+        "the output before Wo (output_gate)", ("part",))
+    for name in parts:
+        counter.labels(name).inc()
 
 
 def grouped_query_attention(q, k, v, *, causal: bool, window=None):
@@ -336,8 +364,9 @@ def rope(x, theta: float, *, start: int = 0, interleaved: bool = False):
 def gqa_forward(conf: L.GroupedQueryAttentionLayer, params, x,
                 ctx: LayerContext):
     """x: [b, t, n_in] -> [b, t, n_out] in x's dtype; the products run in
-    the net's compute dtype with float32 accumulation, the rotation in
-    float32."""
+    the net's compute dtype with float32 accumulation, the rotation, the
+    query/key norms and the output gate in float32 (each rounded once to
+    the compute dtype, as the next product's operand)."""
     if ctx.mask is not None:
         raise NotImplementedError(
             "GroupedQueryAttentionLayer takes no time mask (packed or padded "
@@ -346,10 +375,18 @@ def gqa_forward(conf: L.GroupedQueryAttentionLayer, params, x,
     H, KV, D = int(conf.n_heads), int(conf.n_kv_heads), int(conf.head_dim)
     cd = ctx.compute_dtype or x.dtype
     u = x.astype(cd)
-    proj = lambda name, heads: jnp.matmul(
-        u, params[name].astype(cd), preferred_element_type=jnp.float32
-    ).astype(cd).reshape(B, T, heads, D)
-    q, k, v = proj("Wq", H), proj("Wk", KV), proj("Wv", KV)
+    mm = lambda name: jnp.matmul(u, params[name].astype(cd),
+                                 preferred_element_type=jnp.float32)
+    proj = lambda name, heads: mm(name).astype(cd).reshape(B, T, heads, D)
+    if conf.qk_norm:
+        # each head normed from the float32 product, then rounded once
+        q, k = mm("Wq").reshape(B, T, H, D), mm("Wk").reshape(B, T, KV, D)
+        with jax.named_scope("qk_norm"):
+            q = rms_normalize(q, params["q_norm"], conf.eps).astype(cd)
+            k = rms_normalize(k, params["k_norm"], conf.eps).astype(cd)
+        v = proj("Wv", KV)
+    else:
+        q, k, v = proj("Wq", H), proj("Wk", KV), proj("Wv", KV)
     if conf.rope_theta is not None:
         with jax.named_scope("rope"):
             q, k = rope(q, conf.rope_theta), rope(k, conf.rope_theta)
@@ -359,13 +396,17 @@ def gqa_forward(conf: L.GroupedQueryAttentionLayer, params, x,
                                     window=conf.window)
     _count_lowering(T, window=conf.window, causal=conf.causal,
                     positions="none" if conf.rope_theta is None else "rope")
+    _count_parts(conf)
+    if conf.output_gate:
+        with jax.named_scope("output_gate"):
+            o = o * jax.nn.sigmoid(mm("Wg")).reshape(B, T, H, D)
     y = jnp.matmul(o.astype(cd).reshape(B, T, H * D), params["Wo"].astype(cd),
                    preferred_element_type=jnp.float32)
     return y.astype(x.dtype), None
 
 
 register_layer(L.GroupedQueryAttentionLayer, gqa_init, gqa_forward,
-               order_fn=lambda conf: ("Wq", "Wk", "Wv", "Wo"))
+               order_fn=gqa_order)
 
 
 # -- latent (low-rank key-value) causal attention -------------------------------
