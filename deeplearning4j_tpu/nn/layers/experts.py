@@ -33,16 +33,17 @@ the buffers stay one per held expert: on the chip the same path outside the
 update into the weight-gradient products and lays the buffers out worse),
 and one buffer shared by the held experts under `lax.ragged_dot` won only at
 half the rows, which the fullest layer fills to 86% (PERF.md, PR 29). What
-multiplies the buffers is one algorithm with two lowerings, asked for once
-a layer a trace at the op slot `grouped_experts` (ops/helpers): on a
-one-device TPU program with bf16 products, `n_in` of whole lanes, `width`
-of whole 8-row blocks (the Nemotron cell's 1,856 is 14.5 lanes; PR 38) and
-one expert's matrices inside the kernels' VMEM, the fused kernels of
-ops/pallas_experts.py, which follow each held expert's load (`count`: row
-tiles past it are never multiplied, the float32 hidden arrays stay on the
-chip; PERF.md, PR 36); else the three einsums over every row. The probe
-declines any other shape or compute dtype, the CPU and a partitioned
-program;
+fills, multiplies and empties the buffers is one algorithm with two
+lowerings, asked for once a layer a trace at the op slot `grouped_experts`
+(ops/helpers): on a one-device TPU program with bf16 products, `n_in` of
+whole lanes, `width` of whole 8-row blocks (the Nemotron cell's 1,856 is
+14.5 lanes) and one expert's matrices inside the kernels' VMEM, the five
+kernels of ops/pallas_experts.py, which follow each held expert's load
+(`count`: rows past it are never moved and row tiles past it never
+multiplied, the float32 hidden arrays stay on the chip; PERF.md §6); else
+the gather, the three einsums and the scatter-add over every row, which
+only this fallback keeps. The probe declines any other shape or compute
+dtype, the CPU and a partitioned program;
 `helper_hit_total` / `helper_fallback_total{op="grouped_experts"}` say
 which ran. The fifth book, `tiles`, counts the buffers' tiles of 128 rows
 that a step's routing filled and left empty, whichever lowering runs
@@ -245,13 +246,6 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
                         activation=conf.activation)
 
     def products(rows):
-        if helper is not None:
-            try:
-                return helper(rows, w1, w3, w2, slot_w, count,
-                              activation=conf.activation).reshape(
-                                  n_held * cap, d)
-            except HelperError:
-                pass    # disabled and logged: the three einsums below
         hidden = act(jnp.einsum("ecd,edf->ecf", rows, w1,
                                 preferred_element_type=jnp.float32))
         if conf.gated:
@@ -263,6 +257,12 @@ def experts_forward(conf: L.SparseExpertsLayer, params, x, ctx: LayerContext):
         return out_rows.reshape(n_held * cap, d) * slot_w[:, None]
 
     def grouped():
+        if helper is not None:
+            try:
+                return helper(u, slot_token, count, w1, w3, w2, slot_w,
+                              activation=conf.activation)
+            except HelperError:
+                pass    # disabled and logged: gather, einsums, scatter-add
         out_rows = products(u[slot_token].reshape(n_held, cap, d))
         return jnp.zeros((tokens, d), jnp.float32).at[slot_token].add(
             out_rows)

@@ -1,6 +1,6 @@
-"""Fused grouped experts — the held experts' products, activation, gate,
-rounding and slot weights in Pallas kernels that follow each expert's load,
-forward and backward.
+"""Fused grouped experts — the held experts' rows moved in and out of their
+buffers, and their products, activation, gate, rounding and slot weights, in
+five Pallas kernels that follow each expert's load, forward and backward.
 
 Why: the built-in lowering (nn/layers/experts.grouped) multiplies every row
 of every held expert's buffer, and between its three einsums holds the
@@ -31,6 +31,23 @@ which reduces `rows^T d_pre` over the expert's multiplied tiles to `dW1`
 and `dW3`. One kernel for all of it would keep an expert's three matrices
 and their three float32 gradients resident: 59 MB at 2,560 x 768 before a
 tile is fetched.
+
+Around them, the buffers are filled from token order and summed back into
+it by two more kernels on the same walk (`held_experts`, the op slot's fn):
+`experts_dispatch` fetches each row below the load by a DMA of its own
+from `[tokens, 1, n_in]` float32 in HBM (`_rows_in_token_order`: the view in which Mosaic takes one row as a
+slice), and `experts_combine` reads, adds to and writes back each such
+row's token in float32 (every token starts at zero; the grid runs in
+order). Each is the other's transpose: the cotangent of the
+rows goes back through `experts_combine` and is rounded once to x's dtype,
+the cotangent of the output comes in through `experts_dispatch`. Tiles past
+a load are neither read nor written, so XLA's gather, its select pass over
+out-of-range indices and its scatter-adds over every padded row are gone.
+Dispatch leaves the tiles past a load undefined and writes zeros past the
+load inside the tile it ends in; that is safe because every consumer follows
+`count`: the product kernels pin their blocks to the last multiplied tile
+and mask the rows past the load by index, and combine reads only rows below
+it (tests/test_pallas_experts.py plants NaN there).
 """
 
 from __future__ import annotations
@@ -58,6 +75,7 @@ ROW_TILES = (256, 128)
 
 LANES = 128
 COLUMNS = 512   # of an `n_in`-wide product taken at a time (`_chunks`)
+SLOTS = 1024    # the slots' tokens in SMEM, a block at a time (`_slots`)
 VMEM_LIMIT = 64 * 1024 * 1024
 
 _NN = (((1,), (0,)), ((), ()))   # a @ b
@@ -345,6 +363,210 @@ def _experts_bwd(activation, tile, res, do):
 _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
+def _rows_in_token_order(x):
+    """`[tokens, n_in]` as float32 `[tokens, 1, n_in]`, the view a row of
+    which is one DMA: Mosaic slices an (8, 128)-tiled array by whole tiles
+    of 8 rows only, and tiles bf16 `[tokens, 1, n_in]` by 2 rows; float32
+    with a unit second-minor dimension is tiled (1, 128). The relayout is
+    one pass over the tokens, not over the buffers."""
+    return x.astype(jnp.float32).reshape(x.shape[0], 1, x.shape[1])
+
+
+def _slots(cap: int, tile: int, slot_token):
+    """(the slots' tokens padded to whole blocks, their spec): `SLOTS` of
+    them a block in SMEM, or the whole vector where it is shorter (XLA
+    tiles an int32 vector by 1,024, and a block must be whole tiles or the
+    whole vector), pinned like the row tiles; a row tile of `tile` dividing
+    `SLOTS` lies inside one block. The padding lies past every load and is
+    never read."""
+    n_slots = slot_token.shape[0]
+    block = min(SLOTS, n_slots)
+    spec = pl.BlockSpec((block,), lambda e, i, count, last: (
+        (e * cap + jnp.minimum(i, last[e]) * tile) // block,),
+        memory_space=pltpu.SMEM)
+    pad = -n_slots % block
+    return (jnp.pad(slot_token, (0, pad)) if pad else slot_token), spec
+
+
+def _move_rows(slot_ref, first, n, hbm_ref, stage_ref, sem, to_hbm: bool):
+    """The tile's rows below its load `n` between token order in HBM
+    (`[tokens, 1, n_in]` float32) and `stage_ref` `[tile, 1, n_in]`: one
+    DMA a row, all of them started before the first wait."""
+    rows = jnp.minimum(n, stage_ref.shape[0])
+
+    def copy(r):
+        token = hbm_ref.at[pl.ds(slot_ref[first + r], 1)]
+        here = stage_ref.at[pl.ds(r, 1)]
+        return pltpu.make_async_copy(*((here, token) if to_hbm
+                                       else (token, here)), sem)
+
+    @pl.loop(0, rows)
+    def _(r):
+        copy(r).start()
+
+    @pl.loop(0, rows)
+    def _(r):
+        copy(r).wait()
+
+
+def _tile_load(cap: int, count_ref, slot_ref, tile: int):
+    """(the rows of this grid step's tile below its expert's load, this
+    tile's first slot inside its block of `slot_ref`)."""
+    e, i = pl.program_id(0), pl.program_id(1)
+    return count_ref[e] - i * tile, (e * cap + i * tile) % slot_ref.shape[0]
+
+
+def _dispatch_kernel(count_ref, last_ref, slot_ref, x_ref, rows_ref, stage_ref,
+                     sem, *, cap):
+    tile = rows_ref.shape[0]
+    n, first = _tile_load(cap, count_ref, slot_ref, tile)
+
+    @pl.when(n > 0)
+    def _():
+        _move_rows(slot_ref, first, n, x_ref, stage_ref, sem, to_hbm=False)
+        # the rows past the load in the one partly filled tile are written
+        # as zeros: the products' kernels read them (and mask them by index)
+        keep = jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0) < n
+        rows_ref[...] = jnp.where(keep, stage_ref[:, 0, :], 0.0).astype(
+            rows_ref.dtype)
+
+
+def _combine_kernel(count_ref, last_ref, slot_ref, rows_ref, y_in_ref, y_ref,
+                    stage_ref, sem, *, cap):
+    del y_in_ref        # aliased with y_ref: the zeros the sums start from
+    n, first = _tile_load(cap, count_ref, slot_ref, rows_ref.shape[0])
+
+    @pl.when(n > 0)
+    def _():
+        # an expert meets a token once, so a tile's tokens are distinct;
+        # the grid runs in order, so no other tile's sums are in flight
+        _move_rows(slot_ref, first, n, y_ref, stage_ref, sem, to_hbm=False)
+        stage_ref[:, 0, :] = stage_ref[:, 0, :] + rows_ref[...].astype(
+            jnp.float32)
+        _move_rows(slot_ref, first, n, y_ref, stage_ref, sem, to_hbm=True)
+
+
+# The two calls are jitted: a step asks for each up to three times a layer
+# at the same shapes, and a pallas_call traces and lowers its kernel anew at
+# every call; under a jit that happens once a process for them all, and a
+# first `fit()` (two step programs) does not pay it again and again.
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _dispatch_call(x, slot_token, count, tile: int, dtype, interpret: bool):
+    """`x [tokens, n_in]` -> `[held, cap, n_in]` of `dtype`: row `r` of
+    expert `e` is `x[slot_token[e * cap + r]]` below the expert's load, 0
+    past it in the tile the load ends in, and undefined in the tiles past
+    that (nothing is fetched or written for them)."""
+    n_held = count.shape[0]
+    cap = slot_token.shape[0] // n_held
+    d = x.shape[1]
+    slot_token, slots_spec = _slots(cap, tile, slot_token)
+    return pl.pallas_call(
+        functools.partial(_dispatch_kernel, cap=cap),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n_held, cap // tile),
+            in_specs=[slots_spec, pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=_rows_spec(tile, d),
+            scratch_shapes=[pltpu.VMEM((tile, 1, d), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct((n_held, cap, d), dtype),
+        compiler_params=_params(), name="experts_dispatch",
+        interpret=interpret,
+    )(*_walk(count, cap // tile, tile)[:2], slot_token, _rows_in_token_order(x))
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _combine_call(rows, slot_token, count, tokens: int, tile: int,
+                  interpret: bool):
+    """`rows [held, cap, n_in]` -> `[tokens, n_in]` float32: each token the
+    float32 sum of the rows below their expert's load that hold it, and
+    exact zeros for a token that holds none. Nothing past a load is read."""
+    n_held, cap, d = rows.shape
+    slot_token, slots_spec = _slots(cap, tile, slot_token)
+    y = pl.pallas_call(
+        functools.partial(_combine_kernel, cap=cap),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n_held, cap // tile),
+            in_specs=[slots_spec, _rows_spec(tile, d),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((tile, 1, d), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct((tokens, 1, d), jnp.float32),
+        input_output_aliases={4: 0},
+        # a token's sum is read, added to and written back by one tile at a
+        # time: the grid runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        name="experts_combine", interpret=interpret,
+    )(*_walk(count, cap // tile, tile)[:2], slot_token, rows,
+      jnp.zeros((tokens, 1, d), jnp.float32))
+    return y.reshape(tokens, d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def experts_dispatch(x, slot_token, count, tile):
+    """The held experts' buffers from token order: `x [tokens, n_in]`,
+    `slot_token [held * cap]` int32 (each slot's token, whatever at a slot
+    past its expert's load), `count [held]` -> `[held, cap, n_in]` of x's
+    dtype, defined up to the end of the row tile each load ends in (0 past
+    the load there). Its transpose is `experts_combine`, summed in float32
+    and rounded once to x's dtype."""
+    return _dispatch_call(x, slot_token, count, tile, x.dtype, _interpret())
+
+
+def _dispatch_fwd(x, slot_token, count, tile):
+    # the residuals: the indices, and a zero-size array of x's shape and dtype
+    return (experts_dispatch(x, slot_token, count, tile),
+            (slot_token, count, jnp.zeros((x.shape[0], 0), x.dtype)))
+
+
+def _dispatch_bwd(tile, res, d_rows):
+    slot_token, count, like = res
+    dx = _combine_call(d_rows, slot_token, count, like.shape[0], tile,
+                       _interpret())
+    return dx.astype(like.dtype), None, None
+
+
+experts_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def experts_combine(rows, slot_token, count, tokens, tile):
+    """Back to token order: each token's float32 sum of the rows below
+    their expert's load that hold it, `[tokens, n_in]`; its transpose is
+    `experts_dispatch` of the cotangent, in the rows' dtype."""
+    return _combine_call(rows, slot_token, count, tokens, tile, _interpret())
+
+
+def _combine_fwd(rows, slot_token, count, tokens, tile):
+    return (experts_combine(rows, slot_token, count, tokens, tile),
+            (slot_token, count, jnp.zeros((0,), rows.dtype)))
+
+
+def _combine_bwd(tokens, tile, res, dy):
+    slot_token, count, like = res
+    return (_dispatch_call(dy, slot_token, count, tile, like.dtype,
+                           _interpret()), None, None)
+
+
+experts_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def held_experts(u, slot_token, count, w1, w3, w2, slot_w, *,
+                 activation: str):
+    """`experts.grouped()` on the five kernels: dispatch, the products of
+    `grouped_experts`, combine -> `[tokens, n_in]` float32."""
+    n_held = count.shape[0]
+    cap = slot_token.shape[0] // n_held
+    tile = _tile(cap, u.shape[1], w1.shape[2], w3 is not None)
+    rows = experts_dispatch(u, slot_token, count, tile)
+    out = grouped_experts(rows, w1, w3, w2, slot_w, count,
+                          activation=activation)
+    return experts_combine(out, slot_token, count, u.shape[0], tile)
+
+
 def grouped_experts(rows, w1, w3, w2, slot_w, count, *, activation: str):
     """`experts.grouped()`'s products: rows `[held, cap, n_in]`, w1 (and w3,
     or None) `[held, n_in, width]`, w2 `[held, width, n_in]`, all of the
@@ -398,7 +620,7 @@ def register():
     from deeplearning4j_tpu.ops.helpers import register_helper
 
     register_helper(
-        "grouped_experts", grouped_experts, supported,
+        "grouped_experts", held_experts, supported,
         name="pallas_grouped_experts",
         family=lambda *, gated, **_: "gated" if gated else "two_matrix")
 

@@ -3,11 +3,14 @@ mode on the CPU, at tiles of 128 rows: against the built-in three einsums
 and against a plain float32 form, values and every gradient leaf, with the
 experts' loads on a tile's edges and NaN planted in the rows nobody was sent
 to, at widths of whole lanes and at widths of whole 8-row blocks that are
-not; through the layer under every skew of the routing, against the built-in grouped path
+not; dispatch and combine against the gather and the scatter-add, value
+and gradient, with NaN where a kernel writes nothing and planted past every
+load; through the layer under every skew of the routing, against the built-in grouped path
 and the three families' plain references; the probe as a pure function of
-backend, shapes and dtype; the op slot's counters for one trace of the tiny
-nets; the layer's fifth book, `tiles`; and the kernels as lowered for a TPU
-at the whole-lane cells' shapes, pinned to the parent's."""
+backend, shapes and dtype; the op slot's counters and the five kernels'
+scopes for one trace of the tiny nets; the layer's fifth book, `tiles`; and
+the kernels as lowered for a TPU at the whole-lane cells' shapes, pinned to
+the parent's."""
 
 from __future__ import annotations
 
@@ -15,6 +18,7 @@ import contextlib
 import copy
 import functools
 import os
+import re
 import sys
 
 import jax
@@ -31,6 +35,7 @@ from benchmark.reference import nemotron_h as ref_nemotron  # noqa: E402
 from benchmark.reference import smallthinker as ref_small  # noqa: E402
 from deeplearning4j_tpu.data.dataset import DataSet  # noqa: E402
 from deeplearning4j_tpu.data.iterators import ListDataSetIterator  # noqa: E402
+from deeplearning4j_tpu.models.afmoe import tiny_afmoe_conf  # noqa: E402
 from deeplearning4j_tpu.models.deepseek_v3 import (  # noqa: E402
     tiny_deepseek_v3_conf,
 )
@@ -221,6 +226,121 @@ def test_rows_nobody_was_sent_to_come_out_exactly_zero(family, loads, shape):
             assert (got[name][idle] == 0).all(), name
 
 
+# -- rows in and out of the buffers: dispatch and combine ------------------------
+
+MOVE_TOKENS, MOVE_HELD = 300, 8
+# each held expert's load (eight experts but where said) in a buffer of two
+# tiles of `TILE` rows, and n_in
+MOVES = {
+    "an_idle_expert": ((0, 200, 128, 77, 0, 5, 256, 1), 256),
+    "partly_filled_tiles": ((1, 129, 255, 3, 127, 130, 64, 200), 256),
+    "full_buffers": ((2 * TILE,) * MOVE_HELD, 256),
+    "every_assignment_foreign": ((0,) * MOVE_HELD, 256),
+    # every held expert sent the same tokens: each token held eight times
+    "tokens_held_eight_times": ((150,) * MOVE_HELD, 256),
+    # runs of consecutive tokens
+    "tokens_in_runs": ((256, 200, 37, 9, 0, 130, 8, 16), 256),
+    # 5 x 256 slots, not whole blocks of 1,024 in SMEM: padded to two
+    "slots_not_whole_smem_blocks": ((256, 0, 129, 200, 1), 256),
+    # loads past the buffer: its rows are kept, the rest have no slot
+    "loads_past_the_buffer": ((300, 10, 0, 256, 999, 1, 2, 3), 256),
+    # the Nemotron cell's n_in, 21 lanes
+    "n_in_2688": ((200, 60, 0, 129, 1, 256, 31, 128), 2688),
+}
+
+
+def _moves(case: str, dtype):
+    """x in token order, the slots' tokens (sorted in each expert, as the
+    router's running sum fills them; NaN-free 0 past a load), the loads,
+    and what is defined past each load: the rest of the tile it ends in."""
+    loads, d = MOVES[case]
+    cap = 2 * TILE
+    rng = np.random.default_rng(sum(loads) + d)
+    shared = np.sort(rng.choice(MOVE_TOKENS, cap, replace=False))
+    slot_token = np.zeros((len(loads), cap), np.int32)
+    for e, n in enumerate(loads):
+        kept = min(n, cap)
+        if case == "tokens_in_runs":
+            start = rng.integers(0, MOVE_TOKENS - kept + 1)
+            slot_token[e, :kept] = np.arange(start, start + kept)
+        else:
+            slot_token[e, :kept] = shared[:kept] if case.startswith(
+                "tokens_held") else np.sort(rng.choice(
+                    MOVE_TOKENS, kept, replace=False))
+    ks = jax.random.split(jax.random.PRNGKey(d), 4)
+    kept = np.minimum(loads, cap)
+    row = np.arange(cap)[None, :]
+    filled = row < kept[:, None]
+    tile_end = -(-kept // TILE) * TILE
+    return {"x": jax.random.normal(ks[0], (MOVE_TOKENS, d)).astype(dtype),
+            "rows": jax.random.normal(ks[1], (len(loads), cap, d)).astype(
+                dtype),
+            "dy": jax.random.normal(ks[2], (MOVE_TOKENS, d), jnp.float32),
+            "slot_token": jnp.asarray(slot_token.reshape(-1)),
+            "count": jnp.asarray(loads, jnp.int32), "filled": filled,
+            "zeroed": ~filled & (row < tile_end[:, None]),
+            "undefined": row >= tile_end[:, None]}
+
+
+@pytest.mark.parametrize("dtype", [BF16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", sorted(MOVES))
+def test_rows_move_in_and_out_of_the_buffers(case, dtype, interpreted):
+    """`experts_dispatch` against `x[slot_token]` and `experts_combine`
+    against `zeros.at[slot_token].add(rows)`, value and gradient, each the
+    other's transpose. The interpreter leaves NaN in what a kernel does not
+    write: the tiles past a load come out NaN (never written), and NaN
+    planted past each load in the rows and cotangents handed in reaches no
+    output and no gradient."""
+    m = _moves(case, dtype)
+    x, st, count, filled = m["x"], m["slot_token"], m["count"], m["filled"]
+    held, cap, d = m["rows"].shape
+    flat = filled.reshape(-1)
+    st_np = np.asarray(st)
+    planted = lambda a: jnp.where(jnp.asarray(filled)[:, :, None], a, jnp.nan)
+
+    def into_tokens(rows):
+        """The plain sum in float32 of the kept rows into token order."""
+        rows = np.asarray(rows, np.float32).reshape(held * cap, d)
+        out = np.zeros((MOVE_TOKENS, d), np.float32)
+        np.add.at(out, st_np[flat], rows[flat])
+        return out
+
+    rows, pull = jax.vjp(
+        lambda a: P.experts_dispatch(a, st, count, tile=TILE), x)
+    rows = np.asarray(rows, np.float32)
+    want = np.asarray(x, np.float32)[st_np].reshape(held, cap, d)
+    assert rows.shape == (held, cap, d)
+    np.testing.assert_array_equal(rows[filled], want[filled])
+    assert (rows[m["zeroed"]] == 0).all()
+    assert np.isnan(rows[m["undefined"]]).all()
+    # the gradient: every slot's cotangent summed into its token, float32,
+    # rounded once to x's dtype
+    d_rows = planted(m["rows"])
+    (dx,) = pull(d_rows.astype(dtype))
+    assert dx.dtype == dtype and np.isfinite(np.asarray(dx, np.float32)).all()
+    want_dx = into_tokens(jnp.where(jnp.isnan(d_rows), 0.0, d_rows))
+    np.testing.assert_array_equal(np.asarray(dx, np.float32), np.asarray(
+        jnp.asarray(want_dx).astype(dtype), np.float32))
+
+    y, pull = jax.vjp(lambda r: P.experts_combine(
+        r, st, count, tokens=MOVE_TOKENS, tile=TILE), planted(m["rows"]))
+    y = np.asarray(y)
+    assert y.dtype == np.float32 and np.isfinite(y).all()
+    want_y = into_tokens(jnp.where(jnp.asarray(filled)[:, :, None],
+                                   m["rows"], 0))
+    assert _rel(y, want_y) < 1e-6 if want_y.any() else not y.any()
+    untouched = np.setdiff1d(np.arange(MOVE_TOKENS), st_np[flat])
+    assert (y[untouched] == 0).all()
+    # the gradient: each kept slot reads its token's cotangent
+    (d_rows,) = pull(m["dy"])
+    assert d_rows.dtype == dtype and d_rows.shape == (held, cap, d)
+    d_rows = np.asarray(d_rows, np.float32)
+    want = np.asarray(m["dy"].astype(dtype), np.float32)[st_np].reshape(
+        held, cap, d)
+    np.testing.assert_array_equal(d_rows[filled], want[filled])
+    assert (d_rows[m["zeroed"]] == 0).all()
+
+
 def test_the_walk_pins_skipped_tiles_and_idle_experts():
     """`last`: an expert's last multiplied tile, where a skipped tile's
     blocks stay; `wsel`: an idle expert keeps its predecessor's matrices;
@@ -394,11 +514,14 @@ def _ask(**ctx):
 @pytest.mark.parametrize("ctx,family,tile", [
     (KANANA, "gated", 256), (SMALLTHINKER, "gated", 256),
     (dict(NEMOTRON, width=1792), "two_matrix", 128),
-    (NEMOTRON, "two_matrix", 128)],
-    ids=["kanana", "smallthinker", "two_matrix_of_whole_lanes", "nemotron"])
+    (NEMOTRON, "two_matrix", 128),
+    (dict(KANANA, rows_shape=(3, 768, 2048)), "gated", 256)],
+    ids=["kanana", "smallthinker", "two_matrix_of_whole_lanes", "nemotron",
+         "slots_not_whole_smem_blocks"])
 def test_probe_takes_the_decoder_cells_shapes(ctx, family, tile, monkeypatch):
     """1,856 is 14.5 lanes: its blocks take the whole width, and VMEM is
-    reckoned at 15 lanes; 256 rows of it would not fit."""
+    reckoned at 15 lanes; 256 rows of it would not fit. 3 x 768 slots are
+    not whole blocks of 1,024: dispatch and combine pad them."""
     monkeypatch.setattr(P, "_INTERPRET", True)
     helper, moved = _ask(**ctx)
     assert helper is not None
@@ -476,6 +599,11 @@ NETS = {
     "nemotron": (lambda: tiny_nemotron_h_conf(
         precision="bf16", seq_len=64, hidden_size=128,
         moe_intermediate_size=192), "two_matrix", 1),
+    # one dense layer, then four expert layers, each gated silu beside a
+    # shared expert and summed with it before a post-norm
+    "trinity": (lambda: tiny_afmoe_conf(
+        precision="bf16", seq_len=64, hidden_size=128,
+        moe_intermediate_size=128), "gated", 4),
 }
 
 
@@ -520,6 +648,78 @@ def test_one_trace_counts_one_hit_a_layer(name, monkeypatch):
     assert kernel_tiles["filled"] > 0
     assert abs(kernel_score - builtin_score) < 2e-2 * abs(builtin_score)
     assert builtin_tiles["filled"] + builtin_tiles["empty"] == 2 * layers * 8
+
+
+FIVE_KERNELS = ("experts_dispatch", "experts_fwd", "experts_bwd_rows",
+                "experts_bwd_weights", "experts_combine")
+
+
+def _kernel_calls(jaxpr, stack: str = ""):
+    """(name, name stack) of every `pallas_call` in a jaxpr, its inner
+    jaxprs' stacks joined to their equation's."""
+    from jax.extend import core as jex
+
+    calls = []
+    for eqn in jaxpr.eqns:
+        here = "/".join(s for s in (stack, str(eqn.source_info.name_stack))
+                        if s)
+        if eqn.primitive.name == "pallas_call":
+            calls.append((eqn.params["name"], here))
+            continue
+        for p in eqn.params.values():
+            for inner in p if isinstance(p, (list, tuple)) else [p]:
+                if isinstance(inner, jex.ClosedJaxpr):
+                    inner = inner.jaxpr
+                if isinstance(inner, jex.Jaxpr):
+                    calls += _kernel_calls(inner, here)
+    return calls
+
+
+@pytest.mark.parametrize("name", sorted(NETS))
+def test_the_tiny_nets_move_rows_on_the_kernels(name, monkeypatch):
+    """Loss and every gradient leaf of a tiny decoder on the five kernels
+    within bf16 rounding of the gather, einsums and scatter-add; the slot
+    hit once an expert layer and no fallback; dispatch and combine inside
+    the layer's `experts` scope, forward, recomputed and backward. The plain
+    CPU keeps the built-in lowering (its jaxpr is pinned in
+    tests/test_afmoe.py)."""
+    conf, family, layers = NETS[name]
+    x = np.random.default_rng(0).integers(0, 128, (2, 64)).astype(np.int32)
+
+    def step_of(net):
+        """The loss and its gradients: a net of its own for each lowering,
+        since a recomputed block keeps the trace it made first."""
+        return jax.value_and_grad(lambda p: net._loss(
+            p, net.state_list, [jnp.asarray(x)],
+            [jnp.asarray(np.roll(x, -1, axis=1))], None, None,
+            jax.random.PRNGKey(0)), has_aux=True)
+
+    params = ComputationGraph(conf()).init().params_list
+    step = step_of(ComputationGraph(conf()).init())
+    (builtin, _), builtin_grads = step(params)
+    assert not _kernel_calls(jax.make_jaxpr(step)(params).jaxpr)
+    monkeypatch.setattr(P, "_INTERPRET", True)
+    monkeypatch.setattr(P, "ROW_TILES", (TILE,))
+    step = step_of(ComputationGraph(conf()).init())
+    before = helper_books()
+    (kernels, _), kernel_grads = step(params)
+    moved = helper_books(before)
+    assert moved["hits"][family] == layers
+    assert family not in moved["fallbacks"].get("unsupported", {})
+    assert moved["auto_disable"] == {}
+    assert abs(float(kernels) - float(builtin)) < 1e-3 * abs(float(builtin))
+    for got, want in zip(jax.tree_util.tree_leaves(kernel_grads),
+                         jax.tree_util.tree_leaves(builtin_grads)):
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        if np.asarray(want, np.float32).any():
+            assert _rel(got, want) < 2e-2
+    calls = _kernel_calls(jax.make_jaxpr(step)(params).jaxpr)
+    assert {n for n, _ in calls} >= set(FIVE_KERNELS)
+    for kernel in ("experts_dispatch", "experts_combine"):
+        stacks = [s for n, s in calls if n == kernel]
+        assert len(stacks) >= 2 * layers
+        assert all(re.search(r"L\w+_sparseexperts\)?/experts/", s)
+                   for s in stacks), stacks
 
 
 # -- the fifth book ------------------------------------------------------------------------

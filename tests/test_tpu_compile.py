@@ -638,3 +638,54 @@ def test_fused_experts_compile_for_v5e(case, one_chip):
     for name in ("experts_fwd", "experts_bwd_rows", "experts_bwd_weights"):
         assert name in hlo
     assert not re.search(rf"f32\[{held},{cap},{width}\]", hlo)
+
+
+HELD_EXPERTS = dict(FUSED_EXPERTS, trinity=(16, 8192, 2048, 1024, "silu", True),
+                    # 2,304 slots: not whole blocks of 1,024 in SMEM
+                    slots_padded=(3, 768, 2048, 768, "silu", True))
+
+
+@pytest.mark.parametrize("case", sorted(HELD_EXPERTS))
+def test_held_experts_compile_for_v5e(case, one_chip):
+    """`ops/pallas_experts.held_experts` (dispatch, the three products,
+    combine) as a recomputed block runs it, at the decoder cells' shapes and
+    16,384 tokens: the chip's compiler takes one DMA a row on the float32
+    `[tokens, 1, n_in]` view, no gather or scatter over the buffers' rows
+    is left in the program, and every kernel's HLO `op_name`, which the
+    benchmark's scoped trace reads, keeps the layer's `experts` scope
+    through the jitted dispatch and combine."""
+    from deeplearning4j_tpu.ops import pallas_experts
+
+    held, cap, d, width, activation, gated = HELD_EXPERTS[case]
+    tokens = 16384
+
+    def experts(x, slot_token, count, a, b, c, s):
+        with jax.named_scope("L1_sparseexperts"), jax.named_scope("experts"):
+            return pallas_experts.held_experts(
+                x, slot_token, count, a, b, c, s, activation=activation)
+
+    def grads(u, slot_token, count, w1, w3, w2, slot_w, ct):
+        run = jax.checkpoint(
+            lambda x, a, b, c, s: experts(x, slot_token, count, a, b, c, s))
+        out, pull = jax.vjp(run, u, w1, w3, w2, slot_w)
+        return (out,) + pull(ct)
+
+    arg = lambda shape, dtype=BF16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(grads).lower(
+        arg((tokens, d)), arg((held * cap,), jnp.int32),
+        arg((held,), jnp.int32), arg((held, d, width)),
+        arg((held, d, width)) if gated else None, arg((held, width, d)),
+        arg((held * cap,), jnp.float32), arg((tokens, d), jnp.float32),
+    ).compile()
+    hlo = compiled.as_text()
+    for name in ("experts_dispatch", "experts_fwd", "experts_bwd_rows",
+                 "experts_bwd_weights", "experts_combine"):
+        assert name in hlo
+    assert f"f32[{tokens},1,{d}]" in hlo
+    assert not re.search(r"= \S+ (gather|scatter)\(", hlo)
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert len(kernels) >= 7
+    assert all(re.search(r"L1_sparseexperts\)?/experts/", n)
+               for n in kernels), kernels
