@@ -10,9 +10,10 @@ The recurrence runs in its chunked (state-space dual) form, `ssd_chunked`:
 inside a chunk the masked, decay-weighted `C B^T` product, across chunks the
 carried state; the tests hold it to the literal scan over time of the plain
 reference (`benchmark/reference/nemotron_h.recurrence`). Decays, `dt` and the
-carried state
-are float32; the products take operands of the net's compute dtype and
-accumulate in float32.
+carried state are float32; the products take operands of the net's compute
+dtype and accumulate in float32. On a one-device TPU program the kernels of
+`ops/pallas_ssd.py` run it (op slot "ssd_scan"); the XLA body
+`ssd_chunked_xla` is the fallback everywhere else.
 
 The two elementwise stages around the scan, `conv_silu` (taps + bias + SiLU)
 and `gate_norm` (skip `D x`, gate `silu(z)`, group RMS norm), have
@@ -39,6 +40,7 @@ from jax import lax
 from deeplearning4j_tpu.nn.conf import layers as L
 from deeplearning4j_tpu.nn.layers.registry import LayerContext, register_layer
 from deeplearning4j_tpu.nn.weights import init_weights
+from deeplearning4j_tpu.ops.helpers import HelperError, get_helper
 from deeplearning4j_tpu.utils import metrics as _metrics
 
 
@@ -103,10 +105,33 @@ def ssd_chunked(x, dt, A, B, C, chunk: int):
     A length that is no multiple of `chunk` is padded with `dt = 0`
     (decay 1, nothing added), and the padding is cut off again.
 
-    The groups of heads that share a `B` and a `C` are taken one after the
-    other (`lax.map`), each under `jax.checkpoint`: the masked decay
-    matrices and the chunks' states of one group live at a time, a
-    `1 / groups` of what the whole layer's would take."""
+    One algorithm with two lowerings, chosen by what the trace can
+    observe. On a one-device TPU program whose shapes the probe takes
+    (`ops/pallas_ssd.supported`: bf16 or float32, a chunk of whole 8-row
+    blocks, a state and a group's heads of whole lanes, a head of whole
+    16-row blocks) the kernels `ssd_fwd` / `ssd_bwd` of the op slot
+    "ssd_scan" run it, and no `[Q, Q]` matrix reaches HBM. The XLA
+    body `ssd_chunked_xla` is the fallback: the CPU, a program partitioned
+    over a mesh, other shapes or dtypes, a kernel that raised.
+    `helper_hit_total` / `helper_fallback_total{op="ssd_scan"}` say which
+    ran, once a mixer a trace."""
+    helper = get_helper("ssd_scan", x_shape=tuple(x.shape), dtype=x.dtype,
+                        n_groups=int(B.shape[2]), state_size=int(B.shape[3]),
+                        chunk=int(chunk))
+    if helper is not None:
+        try:
+            return helper(x, dt, A, B, C, int(chunk))
+        except HelperError:
+            pass    # disabled and logged: the XLA body below
+    return ssd_chunked_xla(x, dt, A, B, C, chunk)
+
+
+def ssd_chunked_xla(x, dt, A, B, C, chunk: int):
+    """`ssd_chunked`'s XLA body, its fallback. The groups of heads that
+    share a `B` and a `C` are taken one after the other (`lax.map`), each
+    under `jax.checkpoint`: the masked decay matrices and the chunks'
+    states of one group live at a time, a `1 / groups` of what the whole
+    layer's would take."""
     b, t, H, P = x.shape
     G, N = B.shape[2:]
     r, Q = H // G, int(chunk)

@@ -22,4 +22,5 @@ from deeplearning4j_tpu.ops import (  # noqa: F401,E402
     pallas_conv_bn,
     pallas_experts,
     pallas_lstm,
+    pallas_ssd,
 )

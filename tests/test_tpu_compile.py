@@ -689,3 +689,52 @@ def test_held_experts_compile_for_v5e(case, one_chip):
     assert len(kernels) >= 7
     assert all(re.search(r"L1_sparseexperts\)?/experts/", n)
                for n in kernels), kernels
+
+
+# -- the Mamba-2 scan's kernels at the Nemotron cell's mixer ---------------------
+
+def test_scan_kernels_compile_for_v5e_inside_the_scans_scope(one_chip,
+                                                              monkeypatch):
+    """One mixer at the cell's shapes as a block of the step runs it, with
+    the op slot "ssd_scan" taking the kernels (the probe sees the CPU here,
+    so the test steers the slot): the chip's compiler takes `ssd_fwd` (the
+    forward, the forward again for `gate_norm`'s `y`, and the backward
+    pass's sweep that makes the states) and `ssd_bwd`; each kernel's HLO
+    `op_name`, which the benchmark's scoped trace reads, lies inside the
+    layer's `ssd_scan` scope; and no chunk's `[Q, Q]` matrix is left in the
+    program around them."""
+    from benchmark import scope_reduce
+    from deeplearning4j_tpu.nn.conf import layers as L
+    from deeplearning4j_tpu.nn.layers import ssm
+    from deeplearning4j_tpu.nn.layers.registry import LayerContext
+    from deeplearning4j_tpu.ops import helpers, pallas_ssd
+
+    monkeypatch.setitem(helpers._HELPERS, "ssd_scan", helpers.Helper(
+        name="pallas_ssd_scan", fn=pallas_ssd.ssd_scan))
+    conf = L.Mamba2Layer(n_in=2688, n_out=2688, n_heads=64, head_dim=64,
+                         state_size=128, n_groups=8, conv_kernel=4,
+                         chunk_size=128, norm_eps=1e-5, weight_init="xavier")
+    ctx = LayerContext(training=True, compute_dtype=BF16)
+
+    def loss(params, x):
+        mixer = jax.checkpoint(
+            lambda p, x: ssm.mamba2_forward(conf, p, x, ctx)[0])
+        with jax.named_scope("Lb0_mixer_mamba2"):
+            return jnp.sum(jnp.square(x + mixer(params, x)))
+
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: ssm.mamba2_init(jax.random.PRNGKey(0), conf, jnp.float32)))
+    x = jax.ShapeDtypeStruct((4, 4096, 2688), jnp.float32, sharding=one_chip)
+    hlo = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in hlo.splitlines() if "tpu_custom_call" in line]
+    names = [re.search(r"/(ssd_\w+)/pallas_call", n).group(1)
+             for n in kernels]
+    assert sorted(names) == ["ssd_bwd"] + ["ssd_fwd"] * 3, kernels
+    in_layer = scope_reduce.of_layer_kinds("mamba2")
+    in_scan = scope_reduce.of_component("ssd_scan")
+    assert all(in_layer(n) and in_scan(n) for n in kernels), kernels
+    assert not re.search(r"f32\[[\d,]*128,128\]", hlo)
